@@ -24,12 +24,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
-
-# Some environments pre-import jax at interpreter startup, which makes the
-# JAX_PLATFORMS env var alone too late — honor it through the config too.
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import rocket_tpu as rt

@@ -12,8 +12,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("ROCKET_TPU_CACHE", "1")
-
 import jax.numpy as jnp
 
 import rocket_tpu as rt

@@ -115,10 +115,8 @@ def _eqn_where(eqn) -> str:
     """``file:line (function)`` of the user code that emitted the eqn —
     the name_stack is empty under ``make_jaxpr``, so source provenance
     is what makes RKT901/902 sites recognizable and allow-listable."""
-    try:
-        from jax.extend import source_info_util
-    except ImportError:  # pragma: no cover - older jax layout
-        from jax._src import source_info_util
+    from jax.extend import source_info_util
+
     try:
         return str(source_info_util.summarize(eqn.source_info))
     except Exception:

@@ -786,40 +786,33 @@ class PallasFact:
     vmem_bytes_est: int
 
 
-def _pallas_fact(eqn) -> Optional[PallasFact]:
-    gm = eqn.params.get("grid_mapping")
-    if gm is None:
-        return None
-    name = str(eqn.params.get("name_and_src_info", "pallas_call"))
-    name = name.split(" ")[0] or "pallas_call"
+def _pallas_fact(eqn) -> PallasFact:
+    gm = eqn.params["grid_mapping"]
+    # The call's explicit ``name=``, else the kernel function's own name
+    # (the kernel jaxpr's debug info carries it).
+    name = eqn.params["name"] \
+        or eqn.params["jaxpr"].debug_info.func_name or "pallas_call"
     blocks = []
     full_shapes = {}
     vmem = 0
-    for bm in getattr(gm, "block_mappings", ()) or ():
-        shape = tuple(getattr(bm, "block_shape", ()) or ())
-        asd = getattr(bm, "array_shape_dtype", None)
-        dtype = str(getattr(asd, "dtype", "float32"))
-        dims = tuple(1 if d is None else int(d) for d in shape)
-        try:
-            itemsize = np.dtype(dtype).itemsize
-        except TypeError:
-            itemsize = 4
-        memspace = str(getattr(
-            getattr(bm, "block_aval", None), "memory_space", ""
-        )).lower()
+    for bm in gm.block_mappings:
+        # Block-shape entries are pallas BlockDim objects: Blocked /
+        # Element / BoundedSlice carry ``block_size``; a Squeezed axis
+        # holds one row.
+        shape = tuple(int(getattr(d, "block_size", 1)) for d in bm.block_shape)
+        dtype = str(bm.array_aval.dtype)
+        memspace = str(bm.block_aval.memory_space).lower()
         if not (memspace.endswith("any") or memspace.endswith("hbm")):
             # ANY/HBM operands are NOT pipelined into VMEM — the kernel
             # DMAs the slices it needs (e.g. gather_gmm's token array);
             # counting their full shape as a double-buffered block would
             # flag every HBM-resident operand as a VMEM overflow.
-            vmem += 2 * _numel(dims) * itemsize  # double-buffered pipeline
+            vmem += 2 * _numel(shape) * np.dtype(dtype).itemsize
         key = (shape, dtype)
         blocks.append(key)
-        if asd is not None:
-            full_shapes[key] = tuple(asd.shape)
-    grid = tuple(int(g) for g in getattr(gm, "grid", ()) or ())
+        full_shapes[key] = tuple(bm.array_aval.shape)
     return PallasFact(
-        name=name, grid=grid, blocks=tuple(blocks),
+        name=name, grid=tuple(int(g) for g in gm.grid), blocks=tuple(blocks),
         full_shapes=full_shapes, vmem_bytes_est=int(vmem),
     )
 
@@ -834,9 +827,7 @@ def collect_pallas_facts(step_fn: Callable, variables, batch) -> list:
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                fact = _pallas_fact(eqn)
-                if fact is not None:
-                    facts.append(fact)
+                facts.append(_pallas_fact(eqn))
             for value in eqn.params.values():
                 for sub in _subjaxprs(value):
                     walk(sub)
@@ -1241,11 +1232,10 @@ def _badsched_parts():
     intensity ~0 that dominates the step (RKT503). The target also sets
     an unreachable MFU floor (RKT505)."""
     import jax.numpy as jnp
-
-    from rocket_tpu.utils.compat import shard_map
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
     mesh = _mesh_from_shape({"data": 8})
-    from jax.sharding import PartitionSpec as P
 
     variables = {
         "params": {"w": jax.ShapeDtypeStruct((1024, 1024), jnp.float32)},
@@ -1293,11 +1283,10 @@ def _badoverlap_parts():
     the budget gates; this demo proves the RULES would also still name
     it."""
     import jax.numpy as jnp
-
-    from rocket_tpu.utils.compat import shard_map
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
     mesh = _mesh_from_shape({"data": 8})
-    from jax.sharding import PartitionSpec as P
 
     n_leaves = 12
     variables = {

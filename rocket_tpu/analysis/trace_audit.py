@@ -12,8 +12,8 @@ relies on:
 * **RKT202 donation-duplicate** — one concrete buffer appears at two
   leaves of a donated argument: double-donation is undefined.
 * **RKT203 host-callback-in-step** — a ``pure_callback`` / ``io_callback``
-  / ``debug_callback`` primitive traced into the step forces a
-  device->host round trip every iteration.
+  / ``debug_callback`` / ``debug_print`` primitive traced into the step
+  forces a device->host round trip every iteration.
 * **RKT204 weak-type-input** — an input traced with ``weak_type=True``
   (a Python scalar leaked into the step signature): promotion drift plus
   a retrace the first time a strongly-typed value arrives instead.
@@ -187,7 +187,10 @@ def audit_step(fn: Callable, *example_args,
     wide: set[str] = set()
     for sub in _walk_jaxprs(jaxpr):
         for eqn in sub.eqns:
-            if "callback" in eqn.primitive.name:
+            # jax.debug.print traces to its own ``debug_print`` primitive
+            # (a host round trip like the *_callback family).
+            if "callback" in eqn.primitive.name \
+                    or eqn.primitive.name == "debug_print":
                 callbacks += 1
                 findings.append(Finding(
                     "RKT203", path, 0,

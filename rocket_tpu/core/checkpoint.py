@@ -358,8 +358,8 @@ class Checkpointer(Capsule):
                     model_path, template=prepared.state
                 )
                 # Host-side step mirror (PreparedModule.host_step): read from
-                # the index, NOT the device — a device fetch here degrades
-                # H2D pipelining on tunneled transports. load_pytree above
+                # the index, NOT the device — no host sync on the restored
+                # state. load_pytree above
                 # already validated the "step" leaf exists.
                 prepared.host_step = int(
                     np.asarray(checkpoint_io.load_leaf(model_path, "step"))

@@ -546,9 +546,8 @@ def flash_attention_qkv_sharded(
     module.py:47``); this seam is the TPU-native equivalent for a pallas
     custom call, which GSPMD would otherwise fully replicate.
     """
+    from jax import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
-
-    from rocket_tpu.utils.compat import shard_map as _shard_map
 
     _, b, h, t, d = qkv.shape
     baxes, haxis = shardable_axes(mesh, b, h, batch_axes, head_axis)

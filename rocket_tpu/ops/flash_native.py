@@ -773,10 +773,10 @@ def flash_fused_sharded(
     otherwise the fused zero-copy op runs under shard_map with only the
     batch dim sharded.
     """
+    from jax import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
 
     from rocket_tpu.ops.flash_attention import shardable_axes
-    from rocket_tpu.utils.compat import shard_map as _shard_map
 
     b, t, f = fused.shape
     if f % (3 * num_heads):
@@ -837,10 +837,10 @@ def flash_bthd_sharded(
     ``ops.flash_attention.flash_attention_qkv_sharded`` for the seam
     rationale; this is its native-layout sibling.
     """
+    from jax import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
 
     from rocket_tpu.ops.flash_attention import shardable_axes
-    from rocket_tpu.utils.compat import shard_map as _shard_map
 
     if num_kv_heads is None:
         num_kv_heads = num_heads

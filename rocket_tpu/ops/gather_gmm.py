@@ -165,7 +165,7 @@ def _run_gather_gmm(x, rhs, row_ids, expert_per_tile, *, tile_m, tile_n,
         num_scalar_prefetch=2,
         grid=(m // tile_m, n_out // tile_n),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),       # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),          # x stays in HBM
             pl.BlockSpec((1, k, tile_n), rhs_map),
         ],
         out_specs=pl.BlockSpec(

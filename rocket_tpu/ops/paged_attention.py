@@ -21,8 +21,9 @@ Two device-side implementations share one signature:
   floor.
 * **pallas paged-decode kernel** (TPU, C=1 decode waves): the same
   scatter, then gather and attend are FUSED per block-table page —
-  each grid step streams one ``(block_kv, D)`` tile of one mapped page
-  straight into VMEM and folds it into a flash-style running softmax,
+  each grid step streams one ``(block_kv, Hkv*D)`` tile of one mapped
+  page (all kv heads side by side on the lane axis) straight into VMEM
+  and folds each head's lane slice into a flash-style running softmax,
   so only the slot's ACTIVE pages ever leave HBM and no transient
   context materializes. Inactive table entries point at the reserved
   trash block 0; Mosaic's pipeline skips re-fetching a repeated block
@@ -33,14 +34,18 @@ Two device-side implementations share one signature:
 Implementation choice and the ``block_kv`` tile height resolve through
 the ``paged_decode`` tune table (``rocket_tpu.tune``) — ``impl`` is a
 real structural search axis (the tuner can measure the XLA path beating
-the kernel on a shape and pin it). With no table entry the kernel is the
-TPU default and **CPU falls back to the XLA path** (bitwise identical to
-an untuned checkout — asserted in tests); ``ROCKET_TPU_PAGED_DECODE``
-(``pallas``/``xla``) force-overrides both for operational escape.
+the kernel on a shape and pin it). With nothing pinned the choice is a
+function of what the call can observe: the kernel for C=1 decode on a
+TPU wherever :func:`paged_decode_supported` holds, the XLA path for
+prefill chunks, unsupported pool geometries and on the CPU (bitwise
+identical to an untuned checkout — asserted in tests).
+``ROCKET_TPU_PAGED_DECODE`` (``pallas``/``xla``) force-overrides the
+table. A PINNED ``pallas`` (argument, table or environment) that cannot
+run raises — it never silently becomes the other path.
 
-Layout notes for TPU: D stays the minor (lane) dimension end-to-end and
-``block_len`` should be a multiple of the dtype's sublane tile (8 f32 /
-16 bf16) — shapes that violate this fall back to the XLA path.
+Layout notes for TPU: the pool's ``(Hkv, D)`` minor axes are viewed as
+one ``Hkv*D`` lane axis inside the kernel and ``block_len`` must be a
+multiple of the dtype's sublane tile (8 f32 / 16 bf16).
 
 Inference only (no custom VJP — serving never differentiates).
 """
@@ -114,11 +119,12 @@ def paged_gather(pages, block_table):
 
 
 def paged_decode_supported(block_len: int, head_dim: int, itemsize: int = 4) -> bool:
-    """Shape gate for the fused kernel: pool pages must tile as
-    ``(block_len, D)`` VMEM blocks — block_len a multiple of the dtype's
-    sublane minimum and D a multiple of 8 (D is the whole minor dim, so
-    any such D is Mosaic-legal, same reasoning as
-    ``ops/decode_attention.py``)."""
+    """Shape gate for the fused kernel: a page streams as
+    ``(block_kv, Hkv*D)`` VMEM tiles (the whole lane axis, so any head
+    count is Mosaic-legal) — block_len must be a multiple of the dtype's
+    sublane minimum so such a tile divides the page, and D a multiple
+    of 8 (the per-head lane slice). ``tests/test_tpu_compile.py``
+    compiles the kernel for a v5e chip across this gate's edge."""
     sub = _SUBLANE.get(itemsize, 8)
     return block_len % sub == 0 and head_dim % 8 == 0 and head_dim >= 8
 
@@ -135,17 +141,21 @@ def _default_block_kv(block_len: int, itemsize: int = 4) -> int:
 
 
 def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_kv, sub, mb, scale):
-    """One (slot, kv-head, kv-tile) grid step of the fused paged decode.
+                   m_ref, l_ref, acc_ref, *, block_kv, sub, mb, scale,
+                   h_kv, g, d):
+    """One (slot, kv-tile) grid step of the fused paged decode.
 
-    Streams a ``(block_kv, D)`` tile of the mapped page and folds it
-    into the flash-style running softmax held in f32 scratch; the
-    normalized output is written once, after the last tile. The new
-    K/V row was scattered into the pool BEFORE the kernel, so key
-    positions ``<= pos`` (the query's own row included) are all read
-    from the pool — exact prefix semantics, one code path."""
+    Streams a ``(block_kv, Hkv*D)`` tile of the mapped page — every kv
+    head's rows side by side on the lane axis — and folds each head's
+    ``(block_kv, D)`` lane slice into the flash-style running softmax
+    held in f32 scratch (one row group of ``g`` query heads per kv
+    head); the normalized output is written once, after the last tile.
+    The new K/V row was scattered into the pool BEFORE the kernel, so
+    key positions ``<= pos`` (the query's own row included) are all
+    read from the pool — exact prefix semantics, one code path. All ops
+    stay 2D per head (Mosaic rejects 3D shape casts)."""
     i = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     pos = pos_ref[i]
     n_ctx = pos + 1                       # visible keys: positions [0, pos]
 
@@ -159,29 +169,34 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(base < n_ctx)
     def _tile():
-        q = q_ref[0]                      # (g, D)
-        k = k_ref[0, :, 0, :]             # (block_kv, D)
-        v = v_ref[0, :, 0, :]
-        s_ij = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                         # (g, block_kv) f32
-        idx = base + jax.lax.broadcasted_iota(jnp.int32, s_ij.shape, 1)
-        s_ij = jnp.where(idx < n_ctx, s_ij, _NEG_INF)
+        for h in range(h_kv):
+            rows = slice(h * g, (h + 1) * g)
+            q = q_ref[0, rows, :]                      # (g, D)
+            k = k_ref[0, :, h * d:(h + 1) * d]         # (block_kv, D)
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            s_ij = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                  # (g, block_kv) f32
+            idx = base + jax.lax.broadcasted_iota(jnp.int32, s_ij.shape, 1)
+            s_ij = jnp.where(idx < n_ctx, s_ij, _NEG_INF)
 
-        m_prev = m_ref[:, 0:1]            # (g, 1)
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_ij, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)   # (g, 1)
-        p = jnp.exp(s_ij - m_new)         # (g, block_kv)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_ref.shape
-        )
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            m_prev = m_ref[rows, 0:1]                  # (g, 1)
+            l_prev = l_ref[rows, 0:1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s_ij, axis=1, keepdims=True)
+            )
+            alpha = jnp.exp(m_prev - m_new)            # (g, 1)
+            p = jnp.exp(s_ij - m_new)                  # (g, block_kv)
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
+            l_ref[rows, :] = jnp.broadcast_to(
+                l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                (g, l_ref.shape[1]),
+            )
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(j == sub * mb - 1)
     def _emit():
@@ -192,7 +207,16 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
                          *, block_kv: int, interpret: bool):
     """The fused gather+attend for one decode wave: ``q`` (S, Hq, D),
     pool/table/positions as in :func:`paged_attention` (new rows already
-    scattered). Returns ``out`` (S, Hq, D)."""
+    scattered). Returns ``out`` (S, Hq, D).
+
+    Mosaic wants the last two dims of every block divisible by the
+    (sublane, 128) tile or equal to the array's own: the pool is viewed
+    as ``(NB, BL, Hkv*D)`` (a free reshape — the head and feature axes
+    are adjacent and minor) so a page tile is ``(block_kv, Hkv*D)`` with
+    the whole lane axis, and q/out blocks carry the whole ``(Hq, D)``
+    head axis; the kernel selects each kv head by a static lane slice.
+    A per-head block ``(1, block_kv, 1, D)`` / ``(1, g, D)`` is refused
+    by the TPU lowering whenever Hkv > 1 or g < 8."""
     s, hq, d = q.shape
     nb, bl, h_kv, _ = k_pages.shape
     mb = block_table.shape[1]
@@ -200,45 +224,46 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
     sub = bl // block_kv
     scale = 1.0 / math.sqrt(d)
 
-    def q_map(i, h, j, table_ref, pos_ref):
+    def q_map(i, j, table_ref, pos_ref):
         del j, table_ref, pos_ref
-        return (i, h, 0)
+        return (i, 0, 0)
 
-    def page_map(i, h, j, table_ref, pos_ref):
+    def page_map(i, j, table_ref, pos_ref):
         del pos_ref
-        # Block units: dim 1 is tiled at block_kv rows, so a page's
-        # tile t sits at block index (block_id * sub + t) — except dim 0
-        # is blocked at 1 whole page, so the page id IS the dim-0 index
-        # and the within-page tile is the dim-1 index.
-        return (table_ref[i * mb + j // sub], j % sub, h, 0)
+        # Dim 0 is blocked at one whole page, so the page id IS the
+        # dim-0 block index; dim 1 is tiled at block_kv rows, so the
+        # within-page tile is the dim-1 block index.
+        return (table_ref[i * mb + j // sub], j % sub, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, h_kv, mb * sub),
+        grid=(s, mb * sub),
         in_specs=[
-            pl.BlockSpec((1, g, d), q_map),
-            pl.BlockSpec((1, block_kv, 1, d), page_map),
-            pl.BlockSpec((1, block_kv, 1, d), page_map),
+            pl.BlockSpec((1, hq, d), q_map),
+            pl.BlockSpec((1, block_kv, h_kv * d), page_map),
+            pl.BlockSpec((1, block_kv, h_kv * d), page_map),
         ],
-        out_specs=pl.BlockSpec((1, g, d), q_map),
+        out_specs=pl.BlockSpec((1, hq, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),   # running max (lane-bcast)
-            pltpu.VMEM((g, 128), jnp.float32),   # running denom
-            pltpu.VMEM((g, d), jnp.float32),     # unnormalized accumulator
+            pltpu.VMEM((hq, 128), jnp.float32),   # running max (lane-bcast)
+            pltpu.VMEM((hq, 128), jnp.float32),   # running denom
+            pltpu.VMEM((hq, d), jnp.float32),     # unnormalized accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(
-            _decode_kernel, block_kv=block_kv, sub=sub, mb=mb, scale=scale
+            _decode_kernel, block_kv=block_kv, sub=sub, mb=mb, scale=scale,
+            h_kv=h_kv, g=g, d=d,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hq, d), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(positions, jnp.int32), q, k_pages, v_pages)
+      jnp.asarray(positions, jnp.int32), q,
+      k_pages.reshape(nb, bl, h_kv * d), v_pages.reshape(nb, bl, h_kv * d))
 
 
 def _attend_xla(q, k_pages, v_pages, block_table, positions, valid):
@@ -286,8 +311,11 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
     ``impl``/``block_kv`` pin the implementation explicitly (the tuner's
     candidate runs); left ``None`` they resolve through the
     ``paged_decode`` tune table, defaulting to the fused pallas kernel
-    for C=1 decode on TPU and the XLA path everywhere else.
-    ``interpret=True`` runs the kernel interpreted (CPU parity tests).
+    for C=1 decode on TPU where :func:`paged_decode_supported` holds and
+    the XLA path everywhere else. A pinned ``"pallas"`` that cannot run
+    (C > 1, unsupported pool geometry) raises ``ValueError``; on a CPU
+    host it runs interpreted. ``interpret=True`` runs the kernel
+    interpreted on any backend (CPU parity tests).
 
     Returns ``(out (S, C, Hq*D), k_pages', v_pages')``. Padded query rows
     (``i >= valid[s]``) produce well-defined garbage (position 0 is always
@@ -301,14 +329,15 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
     if hq % h_kv:
         raise ValueError(f"paged_attention: Hq {hq} not a multiple of Hkv {h_kv}")
     itemsize = jnp.dtype(k_pages.dtype).itemsize
+    on_cpu = jax.devices()[0].platform == "cpu"
+    kernel_can_run = c == 1 and paged_decode_supported(bl, d, itemsize)
     if (impl is None or block_kv is None) and c == 1:
         # Tunable surface (tune kernel "paged_decode"): impl is a REAL
         # structural axis (fused pallas kernel vs XLA gather) and
         # block_kv the streamed tile height; the lookup also records
-        # serving-path config provenance for BENCH_DETAIL. Prefill
-        # chunks (C > 1) skip it entirely — the axes cannot affect them
-        # (always the XLA path), so they must not pollute the
-        # provenance log with inert rows.
+        # serving-path config provenance. Prefill chunks (C > 1) skip it
+        # entirely — the axes cannot affect them (always the XLA path),
+        # so they must not pollute the provenance log with inert rows.
         from rocket_tpu.tune import get_config
 
         config = get_config(
@@ -319,30 +348,37 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         ) or {}
         if impl is None:
             impl = os.environ.get("ROCKET_TPU_PAGED_DECODE") \
-                or config.get("impl", "pallas")
+                or config.get("impl")
         if block_kv is None:
-            block_kv = config.get("block_kv") \
-                or _default_block_kv(bl, itemsize)
-    impl = impl or "xla"
+            block_kv = config.get("block_kv")
+    if impl is None:
+        # Nobody pinned a path: the choice is a function of what the call
+        # can observe — the kernel wherever it can run compiled (or was
+        # asked to run interpreted), the XLA gather everywhere else.
+        impl = "pallas" if kernel_can_run and (not on_cpu or interpret) \
+            else "xla"
     block_kv = block_kv or _default_block_kv(bl, itemsize)
     if impl not in ("pallas", "xla"):
         raise ValueError(
             f"paged_attention: unknown impl {impl!r} — the table is "
             "ahead of the implementation (expected 'pallas' or 'xla')"
         )
+    if impl == "pallas" and not kernel_can_run:
+        # A pinned kernel that cannot run is an error, never a silent
+        # switch to the other path.
+        raise ValueError(
+            f"paged_attention: impl='pallas' cannot run here (C={c}, "
+            f"block_len={bl}, head_dim={d}, itemsize={itemsize}) — the "
+            "fused kernel is C=1 decode only and needs "
+            "paged_decode_supported(block_len, head_dim, itemsize); "
+            "pin impl='xla' for this shape"
+        )
 
     k_pages, v_pages = write_kv_pages(
         k_pages, v_pages, block_table, positions, valid, k_new, v_new
     )
 
-    on_cpu = jax.devices()[0].platform == "cpu"
-    use_pallas = (
-        impl == "pallas"
-        and c == 1
-        and paged_decode_supported(bl, d, itemsize)
-        and (not on_cpu or bool(interpret))
-    )
-    if use_pallas:
+    if impl == "pallas":
         if block_kv % _SUBLANE.get(itemsize, 8) or bl % block_kv:
             raise ValueError(
                 f"paged_attention: block_kv={block_kv} must be a "

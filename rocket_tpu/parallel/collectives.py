@@ -55,9 +55,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from rocket_tpu.ops import ring as ring_lib
-from rocket_tpu.utils.compat import shard_map
 
 __all__ = [
     "pvary_compat",
@@ -79,19 +79,12 @@ P = jax.sharding.PartitionSpec
 def pvary_compat(x, axes):
     """Mark ``x`` as device-varying over ``axes`` (vma typing for scan
     carries inside shard_map). Idempotent: axes the value already varies
-    over are skipped (pcast rejects varying->varying). jax renamed
-    pvary -> pcast(..., to='varying'); older versions only have pvary."""
-    if hasattr(jax.lax, "pcast"):
-        aval = jax.typeof(x)
-        current = set(getattr(aval, "vma", ()) or ())
-        for axis in axes:
-            if axis in current:
-                continue
+    over are skipped (pcast rejects varying->varying)."""
+    current = set(jax.typeof(x).vma)
+    for axis in axes:
+        if axis not in current:
             x = jax.lax.pcast(x, axis, to="varying")
-        return x
-    if hasattr(jax.lax, "pvary"):  # pragma: no cover — older jax
-        return jax.lax.pvary(x, tuple(axes))
-    return x  # pragma: no cover — very old jax has no vma typing
+    return x
 
 
 # -- the overlap context -----------------------------------------------------

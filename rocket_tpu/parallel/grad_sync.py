@@ -50,8 +50,8 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
-from rocket_tpu.utils.compat import shard_map
 
 __all__ = ["bucket_plan", "value_and_grad_sharded"]
 

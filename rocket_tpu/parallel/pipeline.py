@@ -34,11 +34,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from rocket_tpu.parallel.collectives import pvary_compat
 
-from rocket_tpu.utils.compat import shard_map
 
 __all__ = ["pipeline_blocks", "pipeline_train_1f1b"]
 

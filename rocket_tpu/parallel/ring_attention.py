@@ -21,9 +21,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from rocket_tpu.utils.compat import shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 

@@ -47,7 +47,7 @@ class ServeConfig:
     #: Pool dtype. Default: the model's activation dtype (or f32).
     dtype: Optional[str] = None
     #: Decode waves per device dispatch (k): one compiled ``lax.scan``
-    #: of k waves amortizes the host→device dispatch tunnel and the one
+    #: of k waves amortizes the host→device dispatch and the one
     #: ``jax.device_get`` over k tokens per slot. Raising k multiplies
     #: steady-state tokens-per-dispatch but adds up to k-1 wave times to
     #: TTFT and makes the scheduler react to EOS/admission every k
@@ -534,7 +534,7 @@ class ServeEngine:
         # The compiled-once proof, surfaced where telemetry.json lands it.
         reg.gauge("serve/decode_traces").set(self.engine.decode_traces)
         reg.gauge("serve/prefill_traces").set(self.engine.prefill_traces)
-        # Tunnel amortization: host syncs vs waves (ISSUE 11 k-wave scan).
+        # Dispatch amortization: host syncs vs waves (the k-wave scan).
         reg.gauge("serve/decode_dispatches").set(
             self.engine.decode_dispatches
         )
@@ -582,7 +582,7 @@ class ServeEngine:
             return self._report_locked()
 
     def _dispatch_stats_locked(self) -> dict:
-        """Tunnel-amortization accounting since the last
+        """Dispatch-amortization accounting since the last
         ``reset_metrics()``: decoded tokens per device dispatch, host
         syncs, and the fraction of host step time that OVERLAPPED the
         in-flight dispatch (1 - harvest-blocked / step wall)."""

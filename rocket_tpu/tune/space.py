@@ -281,11 +281,12 @@ def _paged_legal(config, shape, spec, dtype) -> list:
         problems.append(f"block_kv={block_kv} does not divide "
                         f"block_len={bl}")
     if spec is not None:
-        # Double-buffered K+V tiles + the q/out/accumulator residents.
+        # Double-buffered K+V tiles (every kv head rides one tile) + the
+        # q/out/accumulator residents (every query head).
         itemsize = _DTYPE_ITEMSIZE.get(dtype, 4)
-        g = max(1, shape["hq"] // max(shape["hkv"], 1))
-        need = 2 * 2 * block_kv * d * itemsize + 2 * g * d * itemsize \
-            + g * (d + 256) * 4
+        hq, hkv = shape["hq"], shape["hkv"]
+        need = 2 * 2 * block_kv * hkv * d * itemsize \
+            + 2 * hq * d * itemsize + hq * (d + 256) * 4
         if need > spec.vmem_bytes:
             problems.append(
                 f"VMEM estimate {need >> 20} MiB over the {spec.kind} "

@@ -12,25 +12,13 @@ import jax
 __all__ = ["PEAK_FLOPS", "peak_flops", "DeviceSpec", "DEVICE_SPECS",
            "device_spec"]
 
-#: bf16 peak by device kind — MFU denominators. Matching is longest
-#: prefix, so "TPU v5 lite" (v5e) wins over "TPU v5" (v5p) and future
-#: suffixed kinds fall back to their family entry.
-PEAK_FLOPS = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,   # v5e
-    "TPU v5": 459e12,        # v5p
-    "TPU v6 lite": 918e12,   # v6e (Trillium)
-    "TPU v6": 918e12,        # Trillium family (v6e is the only SKU)
-    "TPU v7": 2307e12,       # v7 (Ironwood): 4614 TFLOP/s fp8, half at bf16
-}
-
 
 @dataclass(frozen=True)
 class DeviceSpec:
     """Per-device-kind roofline constants.
 
-    ``flops_bf16`` matches :data:`PEAK_FLOPS`. ``hbm_bw`` and ``ici_bw``
-    are bytes/second — HBM read+write bandwidth and aggregate one-way
+    ``flops_bf16`` is the bf16 peak (:data:`PEAK_FLOPS` reads it).
+    ``hbm_bw`` and ``ici_bw`` are bytes/second — HBM read+write bandwidth and aggregate one-way
     inter-chip bandwidth per chip (all links). ``ici_link_bw`` is ONE
     link's one-way bandwidth (aggregate / link count): a bulk collective
     (XLA's multi-dimensional rings) drives every link at once and is
@@ -67,8 +55,8 @@ class DeviceSpec:
         return self.flops_bf16 / self.hbm_bw
 
 
-#: Roofline constants by device kind (same longest-prefix matching as
-#: PEAK_FLOPS). Bandwidths are the published per-chip figures; treat
+#: Roofline constants by device kind (longest-prefix matching).
+#: Bandwidths are the published per-chip figures; treat
 #: them as ranking constants for the static cost model, not measured
 #: achievable bandwidth. Link counts: v4/v5p/v7 are 3D tori (6 links),
 #: v5e/v6e 2D (4 links); DCN is the per-chip share of the published
@@ -92,6 +80,13 @@ DEVICE_SPECS = {
 }
 
 
+#: bf16 peak by device kind — MFU denominators, read off the one table
+#: above. Matching is longest prefix, so "TPU v5 lite" (v5e) wins over
+#: "TPU v5" (v5p) and future suffixed kinds fall back to their family
+#: entry.
+PEAK_FLOPS = {kind: spec.flops_bf16 for kind, spec in DEVICE_SPECS.items()}
+
+
 def _longest_prefix(table: dict, kind: str):
     best = None
     for prefix, value in table.items():
@@ -101,8 +96,9 @@ def _longest_prefix(table: dict, kind: str):
 
 
 def peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """bf16 peak for the device kind, or None when unknown (callers should
-    omit MFU rather than compute it against the wrong peak)."""
+    """bf16 peak for the device kind, or None when the kind is not in the
+    table. A measurement path (``bench.py``) treats None as an error; the
+    live Profiler postfix omits MFU."""
     kind = (device or jax.devices()[0]).device_kind
     # Longest prefix wins ("TPU v5 lite" before "TPU v5").
     return _longest_prefix(PEAK_FLOPS, kind)

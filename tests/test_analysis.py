@@ -165,7 +165,7 @@ def test_audit_weak_type_input():
 
 
 def test_audit_wide_dtype():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings = audit_step(lambda x: x * 2,
                               jnp.ones((3,), jnp.float64))
     assert "RKT206" in rules_in(findings)

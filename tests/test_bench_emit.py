@@ -22,8 +22,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import bench  # noqa: E402
 
 
-def _full_result(name, rounds=8):
-    """A maximal per-config result: every field populated, long history."""
+DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+
+def _full_result(name):
+    """A maximal per-config result: every field populated."""
     return {
         "metric": bench.METRIC_NAMES.get(
             name, f"{name}_tok_per_sec_per_chip"
@@ -34,8 +37,8 @@ def _full_result(name, rounds=8):
         "mfu": 0.5678,
         "best_value": 1345678.9,
         "best_mfu": 0.6123,
-        "history": {f"r{i:02d}": 1234567.8 + i for i in range(1, rounds)}
-        | {"now": 1234567.8},
+        "device": dict(DEVICE),
+        "kernel_configs": [{"kernel": "flash_fwd", "source": "default"}] * 4,
     }
 
 
@@ -75,14 +78,15 @@ def test_line_fits_when_everything_errors():
     assert parsed["error"].startswith("XlaRuntimeError")
 
 
-def test_normal_sweep_keeps_summary_and_history():
+def test_normal_sweep_keeps_summary_and_device():
     """At today's config count nothing should be degraded away: the line
-    carries the headline history AND one value per other config."""
+    names the device it was measured on AND carries one value per other
+    config."""
     results = {name: _full_result(name) for name in bench.BENCHES}
     line = bench.format_line(results)
     assert len(line) <= bench.MAX_LINE_BYTES
     parsed = json.loads(line)
-    assert "history" in parsed
+    assert parsed["device"] == DEVICE
     others = parsed["others"]
     for name in bench.BENCHES:
         if name == "gpt2":
@@ -99,7 +103,7 @@ def test_write_detail_round_trips(tmp_path):
     assert detail["headline_metric"] == bench.METRIC_NAMES["gpt2"]
     assert set(detail["configs"]) == set(bench.BENCHES)
     # Full fidelity: the detail file keeps what the line drops.
-    assert detail["configs"]["llama"]["history"]["r01"] == 1234568.8
+    assert detail["configs"]["llama"]["device"] == DEVICE
 
 
 def test_write_detail_merges_partial_runs(tmp_path):
@@ -511,3 +515,75 @@ def test_overlap_summary_shapes_real_targets():
     # The overlapped eval forward moves no MORE than the GSPMD baseline.
     assert rec["bytes_ratio"] >= 1.0
     assert "exposed_comm_drop_frac" in rec
+
+
+# -- no fallback that hides a failure or the device -------------------------
+
+
+def _run_main(monkeypatch, capsys, tmp_path, benches, serve_probe=None):
+    """bench.main() over stub configs with the detail probes stubbed out
+    (``serve_probe`` replaces the serving one); returns (exit code, the
+    parsed stdout line)."""
+    monkeypatch.setattr(bench, "BENCHES", benches)
+    monkeypatch.setattr(
+        bench, "METRIC_NAMES", {n: f"{n}_metric" for n in benches}
+    )
+    for probe in ("health_summary", "serve_summary", "resilience_summary",
+                  "overlap_summary", "calib_summary"):
+        monkeypatch.setattr(bench, probe, lambda: None)
+    if serve_probe is not None:
+        monkeypatch.setattr(bench, "serve_summary", serve_probe)
+    monkeypatch.setattr(bench, "write_detail", lambda results, **kw: None)
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--config", next(iter(benches))])
+    code = 0
+    try:
+        bench.main()
+    except SystemExit as exc:
+        code = exc.code
+    return code, json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def _one_number():
+    return {"metric": "a_metric", "value": 1.0, "unit": "x"}
+
+
+def test_main_names_the_device_and_exits_zero(monkeypatch, capsys, tmp_path):
+    import jax
+
+    code, line = _run_main(monkeypatch, capsys, tmp_path, {"a": _one_number})
+    assert code == 0
+    assert line["device"] == {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices()),
+    }
+
+
+def test_main_exits_nonzero_when_a_config_raises(monkeypatch, capsys,
+                                                 tmp_path):
+    def boom():
+        raise RuntimeError("kernel refused")
+
+    code, line = _run_main(monkeypatch, capsys, tmp_path, {"a": boom})
+    assert code == 1
+    assert line["error"] == "kernel refused"  # the line still went out
+    assert line["device"]["platform"] == "cpu"
+
+
+def test_main_exits_nonzero_when_a_probe_raises(monkeypatch, capsys,
+                                                tmp_path):
+    def bad_serve():
+        raise RuntimeError("engine died")
+
+    code, line = _run_main(
+        monkeypatch, capsys, tmp_path, {"a": _one_number},
+        serve_probe=bad_serve,
+    )
+    assert code == 1
+    assert line["value"] == 1.0
+
+
+def test_peak_flops_unknown_kind_is_an_error():
+    """On the CPU backend (a kind the peak table does not hold) the bench
+    refuses to compute MFU instead of dropping the field."""
+    with pytest.raises(RuntimeError, match="not in"):
+        bench.peak_flops()

@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rocket_tpu.ops import ring as ring_lib
@@ -104,7 +105,7 @@ def test_all_gather_matmul_fp32_bitwise(mesh_shape, mode):
 
 
 @pytest.mark.parametrize("mesh_shape", MESH_SHAPES, ids=["1x8", "2x4"])
-def test_matmul_reduce_scatter_bulk_bitwise_vs_psum(mesh_shape):
+def test_matmul_reduce_scatter_bulk_matches_psum(mesh_shape):
     mesh = _mesh(mesh_shape)
     key = jax.random.key(1)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, 48))
@@ -112,8 +113,6 @@ def test_matmul_reduce_scatter_bulk_bitwise_vs_psum(mesh_shape):
     x_sh = jax.device_put(x, NamedSharding(mesh, P(None, None, "model")))
     w_sh = jax.device_put(w, NamedSharding(mesh, P("model", None)))
     spec = _spec(mesh, "bulk")
-
-    from rocket_tpu.utils.compat import shard_map
 
     psum_ref = shard_map(
         lambda xl, wl: jax.lax.psum(xl @ wl, "model"), mesh=mesh,
@@ -125,9 +124,13 @@ def test_matmul_reduce_scatter_bulk_bitwise_vs_psum(mesh_shape):
             x_sh, w_sh
         )
         ref = jax.jit(psum_ref)(x_sh, w_sh)
-    # XLA's reduce-scatter and all-reduce share the reduction order:
-    # the bulk path is the einsum+psum program, re-laid-out.
-    assert jnp.array_equal(np.asarray(got), np.asarray(ref))
+    # The bulk path is the einsum+psum program, re-laid-out — but the
+    # order in which XLA's reduce-scatter and its all-reduce sum the
+    # per-device partials is XLA's business (jax 0.9.0: identical on the
+    # 1x8 mesh, one ulp apart on 2x4), so the contract is fp32 rounding.
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=1e-6, atol=1e-5
+    )
 
 
 def test_matmul_reduce_scatter_ring_allclose():
@@ -371,6 +374,26 @@ def test_grad_wire_dtype_env(monkeypatch):
 # -- overlap-off step identity ----------------------------------------------
 
 
+def _without_locations(hlo_text):
+    """Compiled HLO text minus where it was traced from: the FileNames /
+    FunctionNames / FileLocations / StackFrames tables and every op's
+    ``stack_frame_id``. They follow the Python call stack of the trace
+    (two builds of one program from two call sites differ there), not
+    the program."""
+    import re
+
+    out, in_table = [], False
+    for line in hlo_text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            in_table = True
+        elif in_table and not line.strip():
+            in_table = False
+        elif not in_table:
+            out.append(re.sub(r" ?stack_frame_id=\d+", "", line))
+    return "\n".join(out)
+
+
 def test_overlap_off_restores_plain_program(monkeypatch):
     """ROCKET_TPU_OVERLAP=0 must rebuild the EXACT pre-overlap GSPMD
     program: the compiled HLO of the tp_1x8 audit step with the kill
@@ -407,7 +430,8 @@ def test_overlap_off_restores_plain_program(monkeypatch):
     compiled, _ = sa.aot_compile_step(
         step_fn, abs_v, abs_b, mesh=mesh, donate_argnums=donate
     )
-    assert off_text == compiled.as_text()
+    assert _without_locations(off_text) == \
+        _without_locations(compiled.as_text())
 
 
 def test_overlap_on_step_allclose_to_off():
@@ -668,7 +692,13 @@ def test_dense_tp_roles_under_context():
         h, y = jax.jit(fwd)(x)
     h_ref = x @ pc["w"] + pc["b"]
     y_ref = h_ref @ pr["w"] + pr["b"]
-    assert jnp.array_equal(h, h_ref)
+    # Column role = gather-then-matmul: every output element is one
+    # device's whole dot product, but a per-shard matmul may run a
+    # different dot kernel than the full-size one — fp32 rounding, not
+    # bit identity (what IS bit-identical is the overlap-OFF program,
+    # test_overlap_off_restores_plain_program).
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                               rtol=0, atol=1e-5)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=0, atol=1e-4)
     with pytest.raises(ValueError):

@@ -183,17 +183,13 @@ def test_mha_flash_on_multidevice_mesh(tmp_path):
 
 
 def test_in_manual_axes_detection():
-    from jax.sharding import Mesh, PartitionSpec as P
     import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
 
     from rocket_tpu.ops.flash_attention import in_manual_axes
 
     assert not in_manual_axes(("data", "model"))
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()), ("data",))
     seen = []

@@ -294,8 +294,23 @@ def test_block_attn_half_bf16_parity():
                                    interpret=True),
         *args,
     )
-    ok, err = _space_parity("block_attn", ref, got, "bfloat16")
+    # Forward: the kernel and the per-op composition agree at the bf16
+    # sweep bound (measured: one bf16 ulp apart).
+    ok, err = _space_parity("block_attn", ref[0], got[0], "bfloat16")
     assert ok, err
+    # Backward: both are bf16 computations of the same math, and a one-ulp
+    # difference in the forward moves gradient entries that are small
+    # sums of large terms by more than an elementwise 2e-2 bound — the
+    # per-op reference ITSELF sits ~40x that bound away from f32 math.
+    # So the kernel is held to what matters: it is no further from the
+    # f32 result (same bf16-rounded input) than the reference is.
+    truth = _value_and_grads(
+        lambda *a: reference_block_attn(*a, num_heads=2),
+        *(a.astype(jnp.float32) for a in args),
+    )
+    _, ref_err = check_parity(truth, ref, "bfloat16")
+    _, got_err = check_parity(truth, got, "bfloat16")
+    assert got_err <= 1.05 * ref_err, (got_err, ref_err)
 
 
 def test_block_attn_half_rejects_bad_config():
@@ -568,7 +583,6 @@ def test_pallas_fact_excludes_any_space_operands():
     estimate — it would flag every HBM-resident operand as an
     overflow."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     from rocket_tpu.analysis.sched_audit import collect_pallas_facts
 
@@ -580,7 +594,7 @@ def test_pallas_fact_excludes_any_space_operands():
     def step(variables, batch):
         out = pl.pallas_call(
             kernel,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((8, 128), lambda: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
             interpret=True,

@@ -171,9 +171,8 @@ def test_state_narrowed_on_exit_fires():
 
 
 def test_collective_operand_narrowed_from_param_fires():
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from rocket_tpu.utils.compat import shard_map
 
     mesh = jax.sharding.Mesh(jax.devices()[:8], ("d",))
     vs = variables(w=sds((8, 8), jnp.float32))
@@ -313,9 +312,8 @@ def test_cond_narrowing_survives_identity_branch():
     ONE branch (master erosion) must not hide behind an identity branch.
     The eroding branch is the FALSE one — first in the branches tuple —
     so a last-branch-wins walk would drop exactly this narrowing."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from rocket_tpu.utils.compat import shard_map
 
     mesh = jax.sharding.Mesh(jax.devices()[:8], ("d",))
     vs = variables(w=sds((8, 8), jnp.float32))
@@ -444,9 +442,8 @@ def test_collect_dtype_flow_exposes_facts():
 # -- RKT403 certification: deliberate low-precision collectives --------------
 
 def _lowprec_collective_parts():
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from rocket_tpu.utils.compat import shard_map
 
     mesh = jax.sharding.Mesh(jax.devices()[:8], ("d",))
     vs = variables(w=sds((8, 8), jnp.float32))

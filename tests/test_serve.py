@@ -596,13 +596,15 @@ def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32):
     return q, k_new, v_new, k_pages, v_pages, table, positions, valid
 
 
-def test_paged_decode_pallas_matches_xla_on_cpu_interpret():
-    """Fused-kernel vs XLA-gather parity on CPU-interpretable shapes:
-    outputs allclose at every legal block_kv and the scattered pool
-    bitwise identical (the scatter is shared)."""
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 4), (6, 2)])
+def test_paged_decode_pallas_matches_xla_on_cpu_interpret(hq, hkv):
+    """Fused-kernel vs XLA-gather parity on CPU-interpretable shapes
+    (GQA g=2, MHA g=1 and g=3 — the kernel picks each kv head's lane
+    slice itself): outputs allclose at every legal block_kv and the
+    scattered pool bitwise identical (the scatter is shared)."""
     from rocket_tpu.ops.paged_attention import paged_attention
 
-    ops = _paged_operands()
+    ops = _paged_operands(hq=hq, hkv=hkv)
     ref, kx, vx = paged_attention(*ops, impl="xla")
     for block_kv in (8, 16):
         out, kp, vp = paged_attention(
@@ -631,12 +633,15 @@ def test_paged_decode_cpu_default_is_xla_bitwise():
     out, kp, vp = paged_attention(*ops)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(kp), np.asarray(kx))
-    # Unsupported page geometry (block_len % sublane != 0) must also
-    # fall back rather than die, even when pallas is pinned.
+    # Unsupported page geometry (block_len % sublane != 0): an unpinned
+    # call takes the XLA path, a PINNED kernel raises — it never
+    # silently becomes the other path.
     small = _paged_operands(bl=4, mb=2)
-    a, _, _ = paged_attention(*small, impl="pallas", interpret=True)
+    a, _, _ = paged_attention(*small, interpret=True)
     b, _, _ = paged_attention(*small, impl="xla")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="cannot run here"):
+        paged_attention(*small, impl="pallas", interpret=True)
 
 
 def test_paged_decode_supported_gate():
