@@ -1360,12 +1360,12 @@ def serve_summary(requests=64, warmup_requests=8):
         "decode_traces": report["compiled"]["decode_traces"],
         "prefill_traces": report["compiled"]["prefill_traces"],
         # Dispatch amortization (k-wave scan): decoded tokens per
-        # device dispatch, host syncs actually paid, and the fraction
-        # of host loop time overlapped with the in-flight dispatch.
+        # device dispatch, host syncs actually paid, and the host time
+        # spent blocked on them.
         "waves_per_dispatch": dispatch["waves_per_dispatch"],
         "tokens_per_dispatch": dispatch["tokens_per_dispatch"],
         "device_get_count": dispatch["device_get_count"],
-        "host_overlap_fraction": dispatch["host_overlap_fraction"],
+        "harvest_wait_s": dispatch["harvest_wait_s"],
         "occupancy_mean": round(report["slots"]["occupancy_mean"], 2),
         "kv_pool_mib": round(
             report["pool"]["kv_pool_bytes"] / 2**20, 1

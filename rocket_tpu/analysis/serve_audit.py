@@ -46,6 +46,7 @@ docs/analysis.md has the rule table and the capacity-frontier math.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Optional, Sequence, Tuple
 
@@ -209,8 +210,12 @@ class RecordingEngine:
 
     def decode_dispatch(self, block_table, lengths, last_tok, run_mask,
                         limits, temp, top_k, top_p, eos, seeds):
+        from rocket_tpu.serve.engine import WaveHandle
+
+        seq = self.decode_dispatches
         self.decode_dispatches += 1
         self.decode_waves += self.waves_per_dispatch
+        self.last_dispatch_at = time.perf_counter()
         args = (block_table, lengths, last_tok, run_mask, limits,
                 temp, top_k, top_p, eos, seeds)
         assert len(args) == len(SCHEDULER_WAVE_ARGS)
@@ -234,11 +239,13 @@ class RecordingEngine:
             lengths = lengths + valid
             last = nxt
             run = run & ~d
-        return np.stack(toks), np.stack(done), np.stack(emitted)
+        return WaveHandle(np.stack(toks), np.stack(done), np.stack(emitted),
+                          seq=seq)
 
     def harvest(self, handle):
         self.device_gets += 1
-        return handle
+        self.last_harvest_at = time.perf_counter()
+        return handle.tokens, handle.done, handle.emitted
 
     def decode(self, *args):
         """Dispatch-and-wait convenience, mirroring SlotEngine."""

@@ -34,6 +34,7 @@ import jax
 from rocket_tpu.core.attributes import Attributes
 from rocket_tpu.core.capsule import Capsule
 from rocket_tpu.core.dispatcher import Dispatcher
+from rocket_tpu.obs import spans
 
 __all__ = ["Looper"]
 
@@ -153,13 +154,15 @@ class Looper(Dispatcher):
         bar = self._progress_bar()
         start = self._batch_idx  # >0 only on mid-epoch resume
 
-        # Run telemetry (rocket_tpu.obs): each iteration wave gets a host
-        # span (category "compile" for the first wave this process drives —
-        # that wave traces+compiles the step — "step" after) paired with a
-        # jax.profiler.StepTraceAnnotation so a concurrent device trace
-        # shares the step boundaries, and the hang watchdog is armed for
-        # exactly the duration of the loop with a beat per completed wave.
-        # All of it is host bookkeeping — nothing touches the device.
+        # Each iteration wave is the span `<tag>/wave` (rocket_tpu.obs.spans;
+        # category "compile" for the first wave this process drives — that
+        # wave traces+compiles the step — "step" after) with a
+        # jax.profiler.StepTraceAnnotation inside it, so a device trace
+        # shares the step boundaries: on under a profiler session or run
+        # telemetry, the shared no-op otherwise. With telemetry the hang
+        # watchdog is armed for exactly the duration of the loop with a
+        # beat per completed wave. All of it is host bookkeeping — nothing
+        # touches the device.
         telemetry = getattr(self._runtime, "telemetry", None)
         obs_on = telemetry is not None and telemetry.enabled
         # Resilience (rocket_tpu.resilience): the drain flag is polled at
@@ -189,20 +192,16 @@ class Looper(Dispatcher):
                 # constants (an implicit H2D by design); from the second
                 # wave on the shapes are stable — wrap padding guarantees
                 # it — and everything implicit is a genuine leak.
-                step_span = (
+                wave = (
                     telemetry.step_span(
                         self._tag, self._batch_idx,
                         cat=("step" if self._warmed else "compile"),
                     )
-                    if obs_on
-                    else None
+                    if telemetry is not None
+                    else spans.OFF
                 )
-                with self._iteration_guard(warmup=(it == start)):
-                    if step_span is not None:
-                        with step_span:
-                            Dispatcher.launch(self, attrs)
-                    else:
-                        Dispatcher.launch(self, attrs)
+                with self._iteration_guard(warmup=(it == start)), wave:
+                    Dispatcher.launch(self, attrs)
                 self._warmed = True
                 if obs_on:
                     telemetry.beat()

@@ -911,17 +911,22 @@ class Module(Dispatcher):
             # PreparedModule.host_step).
             if self._host_step is None:
                 self._host_step = int(self._prepared.host_step)
+            # The host's side of the step: the first call traces, lowers
+            # and compiles (and keeps its compile/ name), every later one
+            # only enqueues. A host timer, no device op, strict-guard safe.
+            telemetry = self._runtime.telemetry
             if not self._stepped["train"]:
-                # First call = trace+lower+compile on the host; the span is
-                # a host timer only (no device op, strict-guard safe).
-                with self._runtime.telemetry.span(
+                dispatch = telemetry.span(
                     f"compile/train_step[{type(self._model).__name__}]",
                     cat="compile",
-                ):
-                    new_state, metrics = self._train_step(state, dynamic)
-                self._stepped["train"] = True
+                )
             else:
+                dispatch = telemetry.span(
+                    "train/step_dispatch", step=self._host_step
+                )
+            with dispatch:
                 new_state, metrics = self._train_step(state, dynamic)
+            self._stepped["train"] = True
             self._prepared.state = new_state
             self._host_step += 1
             self._prepared.host_step = self._host_step

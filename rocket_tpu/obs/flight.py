@@ -218,9 +218,10 @@ class FlightRecorder:
             manifest["metrics"] = telemetry.registry.snapshot()
             events = telemetry.spans.events()[-self._spans_tail:]
             manifest["spans_tail"] = [
-                {"name": name, "cat": cat, "t": round(t - telemetry.spans.t0, 6),
-                 "dur": round(dur, 6), "tid": tid}
-                for name, cat, t, dur, tid in events
+                {"name": ev.name, "cat": ev.cat,
+                 "t": round(ev.start - telemetry.spans.t0, 6),
+                 "dur": round(ev.end - ev.start, 6), "tid": ev.tid}
+                for ev in events
             ]
             if telemetry.health is not None:
                 manifest["health"] = telemetry.health.summary()

@@ -12,8 +12,8 @@ Cost model — O(waves + requests), never O(waves × slots):
 
 * one :func:`shared wave record <RequestTracer.on_dispatch>` per k-wave
   dispatch carries the dispatch/harvest timestamps, the batch occupancy
-  and (when a ``capture_trace`` window is armed) the
-  ``StepTraceAnnotation`` step id, shared by every slot that ran it —
+  and the dispatch's ``seq`` (the ``seq=`` of the ``serve/dispatch`` and
+  ``serve/harvest_wait`` spans), shared by every slot that ran it —
   per-request wave events are (seq, n) participation stubs joined
   against it at record time;
 * per-request phase/ITL accounting is *incremental* (O(1) per harvest),
@@ -22,7 +22,22 @@ Cost model — O(waves + requests), never O(waves × slots):
   attribution;
 * all timestamps are ``time.perf_counter()`` values already taken at
   existing tick boundaries — no device syncs, no shape changes, nothing
-  the compiled-once contract can see.
+  the compiled-once contract can see. They are HOST instants of enqueue
+  and harvest, never device completions: a leg ends when the host
+  enqueued the chunk or fetched the wave, not when the device finished.
+
+Time to first token is partitioned further: a residency's ``prefill_s``
+(admit → first harvested wave) is the sum of three legs,
+``prefill_wait_s`` (admit → the request's first chunk enqueued: the wait
+in the prefill FIFO behind other requests' chunks), ``prefill_run_s``
+(first chunk → last chunk enqueued) and ``first_token_s`` (last chunk
+enqueued → first harvested wave: the dispatch-then-harvest pipeline). The
+record keeps them under ``prefill_legs``; ``phases`` stays the four that
+partition ``[submit, finish]``, so its values still sum to the wall time. When
+a request finishes its legs also go to the span sink
+(:mod:`rocket_tpu.obs.spans`) as ``req/queue``, ``req/prefill_wait``,
+``req/prefill_run``, ``req/first_token`` and ``req/decode``, each with its
+``rid``.
 
 Persistence follows the shard discipline of ``obs/export.py``: finished
 timelines append to ``<run dir>/telemetry/reqtrace.jsonl`` and the per
@@ -33,8 +48,9 @@ crash-readable JSONL bounded by the RKT114 temp+rename compaction.
 an SLO violation carries ``last_window`` exemplar request ids into its
 flight anomaly (``TelemetryExporter._evaluate_slos``).
 
-Stdlib-only and jax-free (like export.py/slo.py): the contract tests
-drive the tracer with synthetic clocks and no backend.
+Plain Python that calls nothing of jax (like export.py/slo.py; the span
+sink it appends to is a list): the contract tests drive the tracer with
+synthetic clocks and no backend.
 """
 
 from __future__ import annotations
@@ -45,9 +61,12 @@ import threading
 import time
 from typing import Optional
 
+from rocket_tpu.obs import spans
+
 __all__ = [
     "RequestTracer",
     "EXEMPLARS_FILE",
+    "PREFILL_LEGS",
     "REQTRACE_FILE",
     "TIMELINE_VERSION",
     "aggregate_phases",
@@ -65,6 +84,11 @@ EXEMPLARS_FILE = "exemplars.jsonl"
 
 #: Timeline record schema version.
 TIMELINE_VERSION = 1
+
+#: The legs that partition ``prefill_s`` (they sum to it to the float):
+#: the record's ``prefill_legs``, beside the four ``phases`` that
+#: partition ``[submit, finish]``.
+PREFILL_LEGS = ("prefill_wait_s", "prefill_run_s", "first_token_s")
 
 #: Events that may be coalesced when a timeline hits its event cap.
 _COALESCIBLE = ("wave", "wave_span", "prefill", "prefill_span")
@@ -122,13 +146,17 @@ class _Timeline:
     and per residency ``prefill`` (admit → first harvested wave) and
     ``decode`` (first wave → evict/finish) — so the rendered waterfall's
     durations sum to the request's measured wall time by construction.
+    ``prefill_s`` IS the sum of its three legs (:data:`PREFILL_LEGS`), so
+    they partition it to the float, across residencies too.
     """
 
     __slots__ = (
         "rid", "t_submit", "prompt_len", "max_new_tokens", "max_events",
         "events", "dropped", "tokens", "preemptions",
-        "_admit_t", "_first_wave_t", "_evict_t", "_last_emit_t",
-        "_desched", "queue_s", "prefill_s", "decode_s", "preempted_s",
+        "_admit_t", "_first_chunk_t", "_last_chunk_t", "_first_wave_t",
+        "_evict_t", "_last_emit_t", "_desched", "queue_s",
+        "prefill_wait_s", "prefill_run_s", "first_token_s", "decode_s",
+        "preempted_s",
         "ttft_s", "worst_gap_s", "worst_gap_kind", "gap_desched_s",
         "gap_wait_s",
     )
@@ -145,12 +173,16 @@ class _Timeline:
         self.tokens = 0
         self.preemptions = 0
         self._admit_t: Optional[float] = None
+        self._first_chunk_t: Optional[float] = None
+        self._last_chunk_t: Optional[float] = None
         self._first_wave_t: Optional[float] = None
         self._evict_t: Optional[float] = None
         self._last_emit_t: Optional[float] = None
         self._desched = False
         self.queue_s = 0.0
-        self.prefill_s = 0.0
+        self.prefill_wait_s = 0.0
+        self.prefill_run_s = 0.0
+        self.first_token_s = 0.0
         self.decode_s = 0.0
         self.preempted_s = 0.0
         self.ttft_s: Optional[float] = None
@@ -177,6 +209,21 @@ class _Timeline:
 
     # -- incremental phase accounting --------------------------------------
 
+    @property
+    def prefill_s(self) -> float:
+        return self.prefill_wait_s + self.prefill_run_s + self.first_token_s
+
+    def _end_prefill(self, t: float) -> None:
+        """Close the residency's prefill at ``t`` (its first harvested
+        wave, or an eviction or finish before one) into the three legs. A
+        context that needed no chunk waited for its first token only."""
+        first, last = self._first_chunk_t, self._last_chunk_t
+        if first is None:
+            first = last = self._admit_t
+        self.prefill_wait_s += max(0.0, first - self._admit_t)
+        self.prefill_run_s += max(0.0, last - first)
+        self.first_token_s += max(0.0, t - last)
+
     def admit(self, t: float) -> None:
         if self._admit_t is None and self._evict_t is None \
                 and self.queue_s == 0.0:
@@ -185,12 +232,19 @@ class _Timeline:
             self.preempted_s += max(0.0, t - self._evict_t)
             self._evict_t = None
         self._admit_t = t
+        self._first_chunk_t = self._last_chunk_t = None
         self._first_wave_t = None
+
+    def chunk(self, t: float) -> None:
+        """A prefill chunk of this request was enqueued at ``t``."""
+        if self._first_chunk_t is None:
+            self._first_chunk_t = t
+        self._last_chunk_t = t
 
     def wave(self, t: float, n: int) -> None:
         if self._first_wave_t is None and self._admit_t is not None:
             self._first_wave_t = t
-            self.prefill_s += max(0.0, t - self._admit_t)
+            self._end_prefill(t)
         if self.ttft_s is None:
             self.ttft_s = max(0.0, t - self.t_submit)
         elif self._last_emit_t is not None:
@@ -211,7 +265,7 @@ class _Timeline:
         if self._first_wave_t is not None:
             self.decode_s += max(0.0, t - self._first_wave_t)
         elif self._admit_t is not None:
-            self.prefill_s += max(0.0, t - self._admit_t)
+            self._end_prefill(t)
         self._admit_t = None
         self._first_wave_t = None
 
@@ -259,6 +313,9 @@ class _Timeline:
                 "decode_s": round(self.decode_s, 6),
                 "preempted_s": round(self.preempted_s, 6),
             },
+            "prefill_legs": {
+                leg: round(getattr(self, leg), 6) for leg in PREFILL_LEGS
+            },
             "itl": {
                 "worst_gap_s": (
                     None if self.worst_gap_s is None
@@ -278,7 +335,7 @@ class RequestTracer:
 
     Hooked by ``serve/scheduler.py`` (submit/admit/prefill/harvest/
     evict/finish), ``serve/engine.py`` (dispatch/harvest timestamps) and
-    ``serve/api.py`` (release/detokenize, trace-step id). All methods
+    ``serve/api.py`` (release/detokenize). All methods
     are O(1) host dict/list work under the tracer's own lock — safe from
     the engine lock or from stream() reader threads.
 
@@ -311,10 +368,6 @@ class RequestTracer:
             collections.OrderedDict()
         self._wave_ring = int(wave_ring)
         self._seq = 0
-        #: Set by ``ServeEngine.step()`` before each tick while a
-        #: ``capture_trace`` window is open — the StepTraceAnnotation
-        #: step id joining a wave record to its measured device window.
-        self.trace_step: Optional[int] = None
         #: The last flushed window's exemplar request ids — what an SLO
         #: violation carries into its flight anomaly.
         self.last_window: dict = {"ttft": [], "itl_gap": []}
@@ -351,19 +404,23 @@ class RequestTracer:
             tl = self._live.get(rid)
             if tl is None:
                 return
+            tl.chunk(t)
             tl.add({"ev": "prefill", "t": t, "start": int(start),
                     "n": int(valid)})
 
-    def on_dispatch(self, occupancy: int, t: float, waves: int = 1) -> int:
-        """One shared wave record per k-wave dispatch; returns its seq
-        (the scheduler pairs it with the pending handle)."""
+    def on_dispatch(self, occupancy: int, t: float, waves: int = 1,
+                    seq: Optional[int] = None) -> int:
+        """One shared wave record per k-wave dispatch; returns its seq:
+        the engine's number of the dispatch (``WaveHandle.seq``, which
+        the ``serve/dispatch`` and ``serve/harvest_wait`` spans carry
+        too), or the tracer's own count where none is given."""
         with self._lock:
-            seq = self._seq
-            self._seq += 1
+            if seq is None:
+                seq = self._seq
+            self._seq = seq + 1
             self._waves[seq] = {
                 "seq": seq, "t_dispatch": t, "t_harvest": None,
                 "occ": int(occupancy), "waves": int(waves),
-                "step": self.trace_step,
             }
             while len(self._waves) > self._wave_ring:
                 self._waves.popitem(last=False)
@@ -390,8 +447,6 @@ class RequestTracer:
                 ev["seq"] = wave["seq"]
                 ev["occ"] = wave["occ"]
                 ev["lat"] = round(t - wave["t_dispatch"], 6)
-                if wave["step"] is not None:
-                    ev["step"] = wave["step"]
             tl.wave(t, int(n))
             tl.add(ev)
 
@@ -410,12 +465,31 @@ class RequestTracer:
                 return
             tl.add({"ev": "finish", "t": t})
             record = tl.finish(t)
+            self._emit_legs(tl)
             self._done[rid] = record
             while len(self._done) > self._max_records:
                 self._done.popitem(last=False)
             self._pending.append(record)
             self._window.append(record)
             self.finished_total += 1
+
+    @staticmethod
+    def _emit_legs(tl: _Timeline) -> None:
+        """The finished request's legs as ``req/*`` spans in the span
+        sink, laid end to end from its submit instant. For a request that
+        was never preempted these are the instants themselves; after a
+        preemption each leg is a sum over residencies, so its length
+        holds and its position does not."""
+        t = tl.t_submit
+        for name, seconds in (
+            ("req/queue", tl.queue_s),
+            ("req/prefill_wait", tl.prefill_wait_s),
+            ("req/prefill_run", tl.prefill_run_s),
+            ("req/first_token", tl.first_token_s),
+            ("req/decode", tl.decode_s),
+        ):
+            spans.add_span(name, t, t + seconds, rid=tl.rid)
+            t += seconds
 
     def on_detokenize(self, rid: int, t: float) -> None:
         """Best effort: annotate a retained finished record with the
@@ -641,11 +715,16 @@ def render_waterfall(record: dict, width: int = 60) -> str:
                 bar[i] = glyph
         lines.append("  |" + "".join(bar) + "|")
     phases = record.get("phases") or {}
+    legs = record.get("prefill_legs")
     lines.append(
         "  queue " + _ms(phases.get("queue_s"))
         + "  prefill " + _ms(phases.get("prefill_s"))
         + "  decode " + _ms(phases.get("decode_s"))
         + "  preempted " + _ms(phases.get("preempted_s"))
+        + ("  (prefill = wait " + _ms(legs.get("prefill_wait_s"))
+           + " + run " + _ms(legs.get("prefill_run_s"))
+           + " + first token " + _ms(legs.get("first_token_s")) + ")"
+           if legs else "")
         + (f"  ({record['dropped']} event(s) compacted away)"
            if record.get("dropped") else "")
     )
@@ -661,6 +740,7 @@ def aggregate_phases(records: list[dict]) -> Optional[dict]:
         return None
     sums = {"queue_s": 0.0, "prefill_s": 0.0, "decode_s": 0.0,
             "preempted_s": 0.0}
+    legs = dict.fromkeys(PREFILL_LEGS, 0.0)
     total = 0.0
     desched = waiting = 0.0
     worst: Optional[tuple[float, str, int]] = None
@@ -669,6 +749,8 @@ def aggregate_phases(records: list[dict]) -> Optional[dict]:
         phases = record.get("phases") or {}
         for key in sums:
             sums[key] += phases.get(key) or 0.0
+        for key in legs:
+            legs[key] += (record.get("prefill_legs") or {}).get(key) or 0.0
         itl = record.get("itl") or {}
         desched += itl.get("descheduled_s") or 0.0
         waiting += itl.get("waiting_s") or 0.0
@@ -681,7 +763,7 @@ def aggregate_phases(records: list[dict]) -> Optional[dict]:
         "itl_descheduled_s": round(desched, 6),
         "itl_waiting_s": round(waiting, 6),
     }
-    for key, value in sums.items():
+    for key, value in (sums | legs).items():
         out[key.replace("_s", "_frac")] = (
             round(value / total, 4) if total > 0 else 0.0
         )
@@ -703,6 +785,12 @@ def render_aggregate(records: list[dict]) -> str:
         f"  decode {agg['decode_frac']:.1%}"
         f"  preempted {agg['preempted_frac']:.1%}"
     ]
+    if "first_token_frac" in agg:
+        lines.append(
+            f"prefill legs: wait {agg['prefill_wait_frac']:.1%}"
+            f"  run {agg['prefill_run_frac']:.1%}"
+            f"  first token {agg['first_token_frac']:.1%}"
+        )
     gap_total = agg["itl_descheduled_s"] + agg["itl_waiting_s"]
     if gap_total > 0:
         lines.append(

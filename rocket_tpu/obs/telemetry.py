@@ -11,11 +11,14 @@ instrumented layer reaches it through ``runtime.telemetry``:
 * Dataset/PrefetchIterator account data waits, Checkpointer accounts
   saves, the Tracker accounts flushes and snapshots the registry.
 
-Disabled (the default) it is inert: ``span()`` hands back a shared
-no-op context and nothing else runs — the step path pays one attribute
-check. Enabled, all bookkeeping is host-side arithmetic; the files
-(``telemetry.json`` + ``spans.trace.json``) are written once, at
-DESTROY, by ``Runtime.end_training``.
+Spans go through the one primitive of :mod:`rocket_tpu.obs.spans`: an
+enabled Telemetry records them in its own ``SpanRecorder`` (installed as
+the process's sink from ``start()`` to ``close()``) and adds goodput and
+the watchdog's beat; a disabled one hands ``span()`` to the bare
+primitive, which is on only while a profiler session is open. Enabled,
+all bookkeeping is host-side arithmetic; the files (``telemetry.json`` +
+``spans.trace.json``) are written once, at DESTROY, by
+``Runtime.end_training``.
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ import os
 import time
 from typing import Optional
 
+from rocket_tpu.obs import spans as span_lib
 from rocket_tpu.obs.goodput import CATEGORIES, Goodput
 from rocket_tpu.obs.registry import MetricsRegistry
 from rocket_tpu.obs.spans import SpanRecorder
@@ -34,37 +38,6 @@ from rocket_tpu.obs.watchdog import Watchdog
 __all__ = ["Telemetry"]
 
 _GOODPUT_CATEGORIES = frozenset(cat for cat in CATEGORIES if cat != "other")
-
-#: jax.monitoring duration events counted as compile work.
-_COMPILE_EVENT_PREFIX = "/jax/core/compile/"
-
-
-class _Span:
-    """One span: trace event + open-stack entry + (categorized) goodput."""
-
-    __slots__ = ("_telemetry", "_name", "_cat", "_t0")
-
-    def __init__(self, telemetry: "Telemetry", name: str,
-                 cat: Optional[str]) -> None:
-        self._telemetry = telemetry
-        self._name = name
-        self._cat = cat
-
-    def __enter__(self) -> "_Span":
-        tel = self._telemetry
-        self._t0 = time.perf_counter()
-        tel.spans.push_open(self._name, self._cat, self._t0)
-        if self._cat in _GOODPUT_CATEGORIES:
-            tel.goodput.push(self._cat, self._t0)
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        tel = self._telemetry
-        now = time.perf_counter()
-        if self._cat in _GOODPUT_CATEGORIES:
-            tel.goodput.pop(now)
-        tel.spans.pop_open()
-        tel.spans.add(self._name, self._cat, self._t0, now - self._t0)
 
 
 def _json_safe(obj):
@@ -146,20 +119,23 @@ class Telemetry:
                 logger=logger,
             )
         self._t0 = time.perf_counter()
-        self._monitoring_listener = None
         self._stall_reports: list[str] = []
         self._closed = False
 
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
-        """Begin the run clock, the compile-event listener and the
-        watchdog thread. No-op when disabled."""
+        """Begin the run clock, make this run's recorder the span sink
+        (every span of the process is on until :meth:`close`), count the
+        events of the process-wide compile listener (which the first
+        ``Runtime`` or ``ServeEngine`` registers) and start the watchdog
+        thread. No-op when disabled."""
         if not self.enabled:
             return
         self._t0 = time.perf_counter()
         self.spans.t0 = self._t0
-        self._register_compile_listener()
+        self.spans.on_compile = self._count_compile
+        span_lib.install(self.spans)
         if self.watchdog is not None:
             self.watchdog.identity = self.identity
             self.watchdog.start()
@@ -183,60 +159,35 @@ class Telemetry:
         )
         self.exporter.start()
 
-    def _register_compile_listener(self) -> None:
-        if self._monitoring_listener is not None:
-            return
-        try:
-            import jax.monitoring as monitoring
-
-            registry = self.registry
-
-            def on_duration(event, duration, **kwargs):
-                if event.startswith(_COMPILE_EVENT_PREFIX):
-                    registry.counter("compile/events").inc()
-                    registry.histogram("compile/secs", base=1e-3).observe(
-                        duration
-                    )
-
-            monitoring.register_event_duration_secs_listener(on_duration)
-            self._monitoring_listener = on_duration
-        except Exception:  # jax.monitoring moved — telemetry stays partial
-            self._monitoring_listener = None
-
-    def _unregister_compile_listener(self) -> None:
-        listener, self._monitoring_listener = self._monitoring_listener, None
-        if listener is None:
-            return
-        try:
-            from jax._src import monitoring as monitoring_impl
-
-            monitoring_impl._unregister_event_duration_listener_by_callback(
-                listener
-            )
-        except Exception:  # private API moved — a stale listener is harmless
-            pass
+    def _count_compile(self, duration: float) -> None:
+        self.registry.counter("compile/events").inc()
+        self.registry.histogram("compile/secs", base=1e-3).observe(duration)
 
     # -- spans -------------------------------------------------------------
 
-    _NULL = contextlib.nullcontext()
-
-    def span(self, name: str, cat: Optional[str] = None):
-        """Context manager recording one host span; goodput-categorized
-        when ``cat`` names a phase. A shared no-op when disabled."""
+    def span(self, name: str, cat: Optional[str] = None, **ids):
+        """One span through :func:`rocket_tpu.obs.spans.span`. Enabled:
+        recorded in this run's recorder, goodput-categorized when ``cat``
+        names a phase. Disabled: the bare primitive (on only under a
+        profiler session, else the shared ``OFF``)."""
         if not self.enabled:
-            return self._NULL
-        return _Span(self, name, cat)
+            return span_lib.span(name, **ids)
+        return span_lib.Span(
+            name, ids, self.spans, cat,
+            self.goodput if cat in _GOODPUT_CATEGORIES else None,
+        )
 
     def step_span(self, tag: str, step_num: int, cat: str = "step"):
-        """One Looper iteration wave: host span + XLA StepTraceAnnotation
-        (so a concurrent ``jax.profiler`` device trace shares the step
-        boundaries)."""
-        if not self.enabled:
-            return self._NULL
+        """One Looper iteration wave: the span ``<tag>/wave`` and, inside
+        it, the ``StepTraceAnnotation`` that gives a device trace the
+        step boundaries."""
+        wave = self.span(f"{tag}/wave", cat=cat, step=step_num)
+        if wave is span_lib.OFF:
+            return wave
         import jax
 
         stack = contextlib.ExitStack()
-        stack.enter_context(self.span(f"{tag}/step", cat=cat))
+        stack.enter_context(wave)
         stack.enter_context(
             jax.profiler.StepTraceAnnotation(tag, step_num=step_num)
         )
@@ -425,4 +376,5 @@ class Telemetry:
             self.flush(default_dir)
         if self.watchdog is not None:
             self.watchdog.stop()
-        self._unregister_compile_listener()
+        span_lib.uninstall(self.spans)
+        self.spans.on_compile = None

@@ -258,6 +258,7 @@ def _fwd(q_arr, k_arr, v_arr, *, h, h_kv, d, kb, q_off, k_off, v_off,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q_arr, k_arr, v_arr)
     return out, lse
 
@@ -499,6 +500,7 @@ def _bwd_arrays(q_arr, k_arr, v_arr, out, lse, dout, *, h, h_kv, d, kb,
         ],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q_arr, k_arr, v_arr, dout, lse, delta)
 
     if dq_split:
@@ -547,6 +549,7 @@ def _bwd_arrays(q_arr, k_arr, v_arr, out, lse, dout, *, h, h_kv, d, kb,
             scratch_shapes=[pltpu.VMEM((block_q, qw), jnp.float32)],
             compiler_params=compiler_params,
             interpret=interpret,
+            name="flash_bwd_dq",
         )(q_arr, k_arr, v_arr, dout, lse, delta)
         return dq, dk, dv
 

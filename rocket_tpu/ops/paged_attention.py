@@ -261,6 +261,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_decode",
     )(block_table.reshape(-1).astype(jnp.int32),
       jnp.asarray(positions, jnp.int32), q,
       k_pages.reshape(nb, bl, h_kv * d), v_pages.reshape(nb, bl, h_kv * d))

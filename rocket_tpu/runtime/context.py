@@ -461,6 +461,7 @@ class Runtime:
             HealthConfig,
             HealthMonitor,
         )
+        from rocket_tpu.obs.spans import install_compile_listener
 
         # Training-health sentinels + flight recorder. Default: off;
         # ROCKET_TPU_HEALTH opts a run in without touching code — "1"
@@ -554,6 +555,9 @@ class Runtime:
         # Replace the env-guessed rank with the real one before start()
         # hands identity to the watchdog and the exporter stamps shards.
         self.telemetry.identity = host_identity(self.process_index)
+        # Compile events become compile/* spans in every run, telemetry
+        # or not (process-wide, registered once).
+        install_compile_listener()
         self.telemetry.start()
         self.telemetry.start_export(
             export_cfg,

@@ -21,8 +21,10 @@ import time
 from dataclasses import dataclass
 from typing import Iterator, Optional, Union
 
+import jax
 import numpy as np
 
+from rocket_tpu.obs import spans
 from rocket_tpu.serve.engine import SlotEngine
 from rocket_tpu.serve.kv_pool import BlockAllocator, KVPoolSpec
 from rocket_tpu.serve.scheduler import Request, Scheduler, TickEvent
@@ -151,6 +153,8 @@ class ServeEngine:
         cfg = config or ServeConfig()
         spec, mb, num_blocks, waves = cfg.resolve(model.config)
         self.config = cfg
+        # Compile events become compile/* spans (process-wide, once).
+        spans.install_compile_listener()
         self.engine = SlotEngine(
             model, params, spec,
             max_slots=cfg.max_slots,
@@ -194,12 +198,8 @@ class ServeEngine:
         self._last_event_at: Optional[float] = None
         self._occupancy_sum = 0
         self._ticks = 0
-        # Host-overlap accounting: wall-clock inside step() vs the slice
-        # of it spent blocked on the device fetch (engine.harvest_wait_s)
-        # — the difference is host work that OVERLAPPED the in-flight
-        # dispatch. Baselines let reset_metrics() window the engine-side
+        # Baselines let reset_metrics() window the engine-side
         # cumulative counters to the steady state.
-        self._step_wall_s = 0.0
         self._base_harvest_wait_s = 0.0
         self._base_device_gets = 0
         self._base_dispatches = 0
@@ -287,79 +287,61 @@ class ServeEngine:
         contributes only its TTFT (there is no previous emit to span)."""
         with self._lock:
             self._trace_poll_locked()
-            t0 = time.perf_counter()
-            gets_before = self.engine.device_gets
-            if self.tracer is not None:
-                # Device-trace join: while a capture window is open this
-                # tick's wave record carries the StepTraceAnnotation
-                # step id, so a slow wave joins to its measured device
-                # window via the obs.prof parser.
-                self.tracer.trace_step = (
-                    self._ticks
-                    if self._trace_session is not None
-                    and self._trace_session.active
-                    else None
-                )
-            if self._trace_session is not None and self._trace_session.active:
-                import jax
-
-                # Step-annotated so the prof parser gets per-tick
-                # windows (measured wave attribution per tick).
+            tick = self.scheduler.ticks
+            with spans.span("serve/tick", tick=tick) as sp:
+                # Step-annotated so a device trace gets per-tick windows
+                # (measured wave attribution per tick, obs.prof).
                 with jax.profiler.StepTraceAnnotation(
-                    "serve_tick", step_num=self._ticks
-                ):
+                    "serve_tick", step_num=tick
+                ) if sp.on else spans.OFF:
                     events = self.scheduler.tick()
-            else:
-                events = self.scheduler.tick()
-            self._ticks += 1
-            self._occupancy_sum += self.scheduler.active_slots
-            now = time.perf_counter()
-            if self.engine.device_gets > gets_before:
-                # Overlap accounting only for ticks that actually
-                # harvested a dispatch — idle polling and the fringe
-                # ticks around a burst would otherwise inflate
-                # host_overlap_fraction toward 1.0 with no dispatch in
-                # flight to overlap.
-                self._step_wall_s += now - t0
-            if events:
-                if self._first_wave_at is None:
-                    self._first_wave_at = now
-                self._last_event_at = now
-            batch: dict[int, int] = {}
-            for ev in events:
-                batch[ev.request.id] = batch.get(ev.request.id, 0) + 1
-            seen: dict[int, int] = {}
-            for ev in events:
-                req = ev.request
-                prev = self._last_emit.get(req.id)
-                first_of_batch = req.id not in seen
-                seen[req.id] = seen.get(req.id, 0) + 1
-                if prev is None:
-                    if first_of_batch:
-                        self._ttft.append(
-                            req.first_token_at - req.submitted_at
-                        )
-                else:
-                    # Amortized inter-token latency for this batch.
-                    itl = (now - prev) / batch[req.id]
-                    self._itl.append(itl)
-                    if self.telemetry is not None and self.telemetry.enabled:
-                        # Registry-side distribution: what /metrics and
-                        # the ITL-p99 SLO watch live, across resets of
-                        # the host-list aggregates.
-                        self.telemetry.registry.histogram(
-                            "serve/itl_s", base=1e-6
-                        ).observe(itl)
-                if ev.finished:
-                    self._last_emit.pop(req.id, None)
-                    self._finish_span(req)
-                    self._retire_locked(req.id)
-                elif seen[req.id] == batch[req.id]:
-                    self._last_emit[req.id] = now
-            del self._ttft[:-self._latency_cap]
-            del self._itl[:-self._latency_cap]
-            self._publish()
+                self._record_tick_locked(events)
             return events
+
+    def _record_tick_locked(self, events: list[TickEvent]) -> None:
+        """The latency bookkeeping and gauge publishing at the end of a
+        tick: ``serve/tick``'s self time."""
+        self._ticks += 1
+        self._occupancy_sum += self.scheduler.active_slots
+        now = time.perf_counter()
+        if events:
+            if self._first_wave_at is None:
+                self._first_wave_at = now
+            self._last_event_at = now
+        batch: dict[int, int] = {}
+        for ev in events:
+            batch[ev.request.id] = batch.get(ev.request.id, 0) + 1
+        seen: dict[int, int] = {}
+        for ev in events:
+            req = ev.request
+            prev = self._last_emit.get(req.id)
+            first_of_batch = req.id not in seen
+            seen[req.id] = seen.get(req.id, 0) + 1
+            if prev is None:
+                if first_of_batch:
+                    self._ttft.append(
+                        req.first_token_at - req.submitted_at
+                    )
+            else:
+                # Amortized inter-token latency for this batch.
+                itl = (now - prev) / batch[req.id]
+                self._itl.append(itl)
+                if self.telemetry is not None and self.telemetry.enabled:
+                    # Registry-side distribution: what /metrics and
+                    # the ITL-p99 SLO watch live, across resets of
+                    # the host-list aggregates.
+                    self.telemetry.registry.histogram(
+                        "serve/itl_s", base=1e-6
+                    ).observe(itl)
+            if ev.finished:
+                self._last_emit.pop(req.id, None)
+                self._finish_span(req)
+                self._retire_locked(req.id)
+            elif seen[req.id] == batch[req.id]:
+                self._last_emit[req.id] = now
+        del self._ttft[:-self._latency_cap]
+        del self._itl[:-self._latency_cap]
+        self._publish()
 
     def _retire_locked(self, rid: int) -> None:
         """Bound the completed-request record: keep the newest
@@ -561,7 +543,6 @@ class ServeEngine:
             self._last_event_at = None
             self._occupancy_sum = 0
             self._ticks = 0
-            self._step_wall_s = 0.0
             self._base_harvest_wait_s = self.engine.harvest_wait_s
             self._base_device_gets = self.engine.device_gets
             self._base_dispatches = self.engine.decode_dispatches
@@ -584,8 +565,8 @@ class ServeEngine:
     def _dispatch_stats_locked(self) -> dict:
         """Dispatch-amortization accounting since the last
         ``reset_metrics()``: decoded tokens per device dispatch, host
-        syncs, and the fraction of host step time that OVERLAPPED the
-        in-flight dispatch (1 - harvest-blocked / step wall)."""
+        syncs, and the host time spent blocked on them (per tick: the
+        ``serve/harvest_wait`` span beside its ``serve/tick``)."""
         eng = self.engine
         gets = eng.device_gets - self._base_device_gets
         dispatches = eng.decode_dispatches - self._base_dispatches
@@ -599,10 +580,6 @@ class ServeEngine:
                 round(tokens / dispatches, 3) if dispatches else None
             ),
             "harvest_wait_s": round(wait, 6),
-            "host_overlap_fraction": (
-                round(max(0.0, 1.0 - wait / self._step_wall_s), 4)
-                if self._step_wall_s > 0 else None
-            ),
         }
 
     def _report_locked(self) -> dict:

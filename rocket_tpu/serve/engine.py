@@ -47,13 +47,13 @@ wave N−1's results while N runs (dispatch-then-harvest pipelining).
 
 from __future__ import annotations
 
-import time
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from rocket_tpu.models.sampling import freeze_after_eos, sample_tokens
+from rocket_tpu.obs.spans import timed
 from rocket_tpu.serve.kv_pool import KVPoolSpec
 
 __all__ = [
@@ -78,11 +78,14 @@ class WaveHandle(NamedTuple):
     ``jax.device_get``) by :meth:`SlotEngine.harvest`. All are
     ``(waves_per_dispatch, max_slots)``: the sampled token per wave, the
     finished flag the wave raised, and whether the slot actually ran
-    that wave (a slot frozen mid-scan stops emitting)."""
+    that wave (a slot frozen mid-scan stops emitting). ``seq`` numbers
+    the dispatch: the ``serve/dispatch`` and ``serve/harvest_wait`` spans
+    and the request tracer's wave record carry it."""
 
     tokens: jax.Array    # (k, S) int32
     done: jax.Array      # (k, S) bool
     emitted: jax.Array   # (k, S) bool
+    seq: int = -1
 
 
 def build_decode_wave(model, on_trace: Optional[Callable] = None,
@@ -326,25 +329,33 @@ class SlotEngine:
         (the scheduler's mirrors); returns a :class:`WaveHandle` of
         device arrays WITHOUT synchronizing — the host keeps scheduling
         while the device runs, and :meth:`harvest` fetches the results."""
+        seq = self.decode_dispatches
         self.decode_dispatches += 1
         self.decode_waves += self.waves_per_dispatch
-        self.last_dispatch_at = time.perf_counter()
-        self.k_pages, self.v_pages, toks, done, emitted = self._decode(
-            self._params, self.k_pages, self.v_pages, block_table, lengths,
-            last_tok, run_mask, limits, temp, top_k, top_p, eos, seeds,
-            self._key,
-        )
-        return WaveHandle(tokens=toks, done=done, emitted=emitted)
+        with timed("serve/dispatch", seq=seq) as sp:
+            if sp.on:
+                sp.set(occupancy=int(run_mask.sum()))
+            self.last_dispatch_at = sp.start
+            self.k_pages, self.v_pages, toks, done, emitted = self._decode(
+                self._params, self.k_pages, self.v_pages, block_table,
+                lengths, last_tok, run_mask, limits, temp, top_k, top_p,
+                eos, seeds, self._key,
+            )
+        return WaveHandle(tokens=toks, done=done, emitted=emitted, seq=seq)
 
     def harvest(self, handle: WaveHandle):
         """Fetch one dispatch's results to host numpy — the single
         explicit device sync per k decoded tokens. Returns
-        ``(tokens, done, emitted)`` as ``(k, S)`` numpy arrays."""
+        ``(tokens, done, emitted)`` as ``(k, S)`` numpy arrays. The span
+        around the fetch is the time the host waited for the device; its
+        two instants feed ``harvest_wait_s`` and ``last_harvest_at``."""
         self.device_gets += 1
-        t0 = time.perf_counter()
-        out = jax.device_get(tuple(handle))
-        self.last_harvest_at = time.perf_counter()
-        self.harvest_wait_s += self.last_harvest_at - t0
+        with timed("serve/harvest_wait", seq=handle.seq) as sp:
+            out = jax.device_get(
+                (handle.tokens, handle.done, handle.emitted)
+            )
+        self.last_harvest_at = sp.end
+        self.harvest_wait_s += sp.end - sp.start
         return out
 
     def decode(self, block_table, lengths, last_tok, run_mask, limits,

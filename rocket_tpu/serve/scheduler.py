@@ -50,6 +50,7 @@ from typing import Optional
 
 import numpy as np
 
+from rocket_tpu.obs.spans import span, timed
 from rocket_tpu.serve.engine import SlotEngine
 from rocket_tpu.serve.kv_pool import BlockAllocator
 
@@ -139,9 +140,8 @@ class Scheduler:
         #: every hook below is guarded, so a bare scheduler (tests,
         #: audits) pays nothing.
         self.tracer = None
-        #: The tracer's wave-record seq paired with ``pending`` — it
-        #: rides the same dispatch-then-harvest pipeline.
-        self._pending_seq = None
+        #: Ticks taken so far: the ``tick=`` of this tick's spans.
+        self.ticks = 0
         self._next_id = 0
         self._admit_seq = 0
         # Aggregates for the report / gauges.
@@ -207,10 +207,15 @@ class Scheduler:
         dispatch the next k waves. Returns the tokens the HARVESTED
         dispatch emitted (one tick behind the device — the pipelining);
         an idle engine returns []."""
-        self._admit()
+        if self.queue:
+            with span("serve/admit", tick=self.ticks) as sp:
+                sp.set(admitted=self._admit())
         self._prefill_one()
         events = self._harvest_pending()
-        run = self._grow_tables()
+        with span("serve/grow") as sp:
+            evicted = self.preemptions
+            run = self._grow_tables()
+            sp.set(evicted=self.preemptions - evicted)
         if run.any():
             self.pending = self.engine.decode_dispatch(
                 self.block_table, self.lengths, self.last_tok, run,
@@ -220,13 +225,15 @@ class Scheduler:
             if self.tracer is not None:
                 # One shared wave record per dispatch (O(waves), not
                 # O(waves x slots)) — harvested with `pending` next tick.
-                self._pending_seq = self.tracer.on_dispatch(
+                self.tracer.on_dispatch(
                     occupancy=int(run.sum()),
                     t=self.engine.last_dispatch_at,
                     waves=self.engine.waves_per_dispatch,
+                    seq=self.pending.seq,
                 )
         elif self.pending is None and not events:
             self.waves_idle += 1
+        self.ticks += 1
         return events
 
     @property
@@ -249,7 +256,10 @@ class Scheduler:
 
     # -- phases ------------------------------------------------------------
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Queued requests into free slots while the pool covers them;
+        returns how many."""
+        admitted = 0
         free = [i for i, s in enumerate(self.slots) if s is None]
         while self.queue and free:
             req = self.queue[0]
@@ -259,7 +269,7 @@ class Scheduler:
             need = -(-len(ctx) // self.block_len)
             blocks = self.allocator.alloc(need)
             if blocks is None:
-                return  # back-pressure: wait for running requests to free
+                break  # back-pressure: wait for running requests to free
             self.queue.popleft()
             slot = free.pop(0)
             st = _Slot(req, blocks, ctx, self._admit_seq)
@@ -277,11 +287,15 @@ class Scheduler:
             self.top_p[slot] = 1.0 if req.top_p is None else req.top_p
             self.eos[slot] = -1 if req.eos_token_id is None else req.eos_token_id
             self.seeds[slot] = req.id % (2**31 - 1)
+            admitted += 1
             if self.tracer is not None:
+                # Read here whether or not spans are on: a traced and an
+                # untraced run date an admission at the same place.
                 self.tracer.on_admit(
                     req.id, time.perf_counter(), slot, ctx_len=len(ctx),
                     resumed=req.preemptions > 0,
                 )
+        return admitted
 
     def _prefill_one(self) -> None:
         """One chunk for the OLDEST still-prefilling slot (FIFO keeps TTFT
@@ -300,18 +314,21 @@ class Scheduler:
         valid = len(chunk)
         if valid < c:
             chunk = np.pad(chunk, (0, c - valid))
-        self.engine.prefill(
-            self.block_table[slot:slot + 1],
-            chunk[None, :].astype(np.int32),
-            np.asarray([start], np.int32),
-            np.asarray([valid], np.int32),
-        )
+        with timed("serve/prefill_enqueue", rid=st.req.id, start=start,
+                   valid=valid) as sp:
+            self.engine.prefill(
+                self.block_table[slot:slot + 1],
+                chunk[None, :].astype(np.int32),
+                np.asarray([start], np.int32),
+                np.asarray([valid], np.int32),
+            )
         st.prefill_pos = start + valid
         self.lengths[slot] = st.prefill_pos
         if self.tracer is not None:
-            self.tracer.on_prefill(
-                st.req.id, time.perf_counter(), start, valid
-            )
+            # The instant the chunk was ENQUEUED (never a device
+            # completion): the span's end, read at the one place whether
+            # or not spans are on.
+            self.tracer.on_prefill(st.req.id, sp.end, start, valid)
 
     def _grow_tables(self) -> np.ndarray:
         """Cover every position the next dispatch may write — up to
@@ -372,17 +389,26 @@ class Scheduler:
 
     def _harvest_pending(self) -> list[TickEvent]:
         """Fetch the in-flight dispatch (ONE ``jax.device_get`` for its
-        k waves) and replay the device's per-wave bookkeeping onto the
-        host mirrors: every emitted token appends to its request and
-        advances the slot's length; a slot whose ``done`` flag rose
-        frees its blocks and is refillable next tick."""
+        k waves) and replay it onto the host mirrors."""
         if self.pending is None:
             return []
         handle, self.pending = self.pending, None
-        seq, self._pending_seq = self._pending_seq, None
         toks, done, emitted = self.engine.harvest(handle)
-        now = time.perf_counter()
-        if self.tracer is not None and seq is not None:
+        with span("serve/replay", seq=handle.seq) as sp:
+            # `now` is the instant the fetch returned, as harvest read it.
+            events = self._replay(
+                handle.seq, toks, done, emitted, self.engine.last_harvest_at
+            )
+            sp.set(tokens=len(events))
+        return events
+
+    def _replay(self, seq: int, toks, done, emitted,
+                now: float) -> list[TickEvent]:
+        """The device's per-wave bookkeeping replayed onto the host
+        mirrors: every emitted token appends to its request and advances
+        the slot's length; a slot whose ``done`` flag rose frees its
+        blocks and is refillable next tick."""
+        if self.tracer is not None:
             self.tracer.on_harvest(seq, now)
         emitted_by: dict[int, int] = {}
         finished_ids: list[int] = []

@@ -110,6 +110,8 @@ def test_span_recorder_bounded_buffer():
     for i in range(5):
         rec.add(f"s{i}", None, 0.0, 0.1)
     assert len(rec) == 2 and rec.dropped == 3
+    # A ring: the NEWEST events are the ones kept.
+    assert [ev.name for ev in rec.events()] == ["s3", "s4"]
     assert rec.to_chrome_trace()["otherData"]["dropped"] == 3
 
 
@@ -147,7 +149,7 @@ def test_watchdog_fires_on_stall_and_dumps_stacks():
     dog.start()
     try:
         dog.arm()
-        rec.push_open("train/step", "step", time.perf_counter())
+        rec.push_open("train/step")
         deadline = time.time() + 5.0
         while not reports and time.time() < deadline:
             time.sleep(0.02)
@@ -233,7 +235,7 @@ def test_watchdog_fires_on_artificially_stalled_step(tmp_path):
     # The main thread's stack shows the stalled capsule's launch frame,
     # and the open-span stack names the wave it was inside.
     assert "launch" in dump
-    assert "train/step" in dump
+    assert "train/wave" in dump
 
 
 # -- end-to-end ------------------------------------------------------------

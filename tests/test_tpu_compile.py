@@ -136,3 +136,72 @@ def test_paged_decode_kernel(sds, hq, hkv, d, block_len, dtype):
         sds((slots, hq, d), dtype), pool, pool,
         sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
     )
+
+
+# -- the names the device trace finds the kernels by -------------------------
+
+
+def _kernel_instructions(text):
+    """The names of the Mosaic custom-call instructions of a compiled
+    program: what the profiler's ``XLA Ops`` line calls their events."""
+    import re
+
+    return [
+        m.group(1) for m in re.finditer(
+            r"^\s*%?(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text, re.M,
+        )
+    ]
+
+
+def test_flash_kernels_carry_their_names_at_the_train_cell_width(sds):
+    """GPT-2 medium's step shape (B=8, T=1024, 16 heads x 64): forward and
+    backward kernels are named in the compiled program, so the benchmark's
+    roofline metrics find them by name and not by ``jvp__.N``."""
+    from rocket_tpu.ops.flash_native import flash_fused
+
+    def op(fused):
+        return flash_fused(fused, 16, causal=True, interpret=False)
+
+    text = _compile(_fwd_bwd(op, 1), sds((8, 1024, 3 * 1024), jnp.bfloat16))
+    names = _kernel_instructions(text)
+    assert any("flash_fwd" in n for n in names), names
+    assert any("flash_bwd_dkv" in n for n in names), names
+
+
+def test_flash_dq_kernel_carries_its_name(sds):
+    """The split-dq backward (long sequences) names its second kernel."""
+    from rocket_tpu.ops.flash_native import flash_bthd
+
+    def op(q2, k2, v2):
+        return flash_bthd(q2, k2, v2, 12, 4, causal=True, interpret=False,
+                          dq_split=True)
+
+    kv = sds((2, 4096, 4 * 64), jnp.bfloat16)
+    text = _compile(_fwd_bwd(op, 3), sds((2, 4096, 12 * 64), jnp.bfloat16),
+                    kv, kv)
+    names = _kernel_instructions(text)
+    assert any("flash_bwd_dq" in n for n in names), names
+    assert any("flash_bwd_dkv" in n for n in names), names
+
+
+def test_paged_decode_kernel_carries_its_name_at_the_chat_cell_width(sds):
+    """GPT-2 large's pool (20 heads x 64, 32 slots x 1024 of block 16)."""
+    from rocket_tpu.ops.paged_attention import (
+        _default_block_kv,
+        _paged_decode_pallas,
+    )
+
+    slots, ctx, bl, h, d = 32, 1024, 16, 20, 64
+    mb = ctx // bl
+    pool = sds((1 + slots * mb, bl, h, d), jnp.bfloat16)
+    text = _compile(
+        functools.partial(
+            _paged_decode_pallas, block_kv=_default_block_kv(bl, 2),
+            interpret=False,
+        ),
+        sds((slots, h, d), jnp.bfloat16), pool, pool,
+        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+    )
+    names = _kernel_instructions(text)
+    assert any("paged_decode" in n for n in names), names
