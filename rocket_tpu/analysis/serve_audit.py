@@ -617,7 +617,7 @@ def fused_decode_bytes(
     """The fused-kernel byte model of ONE decode wave: the analytic
     floor (params + active-pages-only gather + per-slot scatter — the
     pallas paged-decode kernel streams exactly the mapped pages, no
-    transient ``(S, MB*BL, Hkv, D)`` context) plus the wave's real
+    transient ``(S, MB*BL, Hkv*D)`` context) plus the wave's real
     activation traffic: the ``(S, V)`` logits written by the head and
     re-read (several times — sort-based top-k/top-p filtering is always
     compiled in, the knobs being runtime arrays) by the sampling core,

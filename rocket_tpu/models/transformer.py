@@ -500,14 +500,16 @@ class Block(Layer):
         return x + h, cache
 
     def apply_paged(self, params, x, k_pages, v_pages, block_table,
-                    positions, valid):
+                    positions, valid, layer=0):
         """Decode/prefill chunk through the block against an EXTERNAL
         paged KV pool (``rocket_tpu.serve``): ``x`` (S, C, D) at per-slot
-        global positions (eval semantics — no dropout). Returns
+        global positions (eval semantics — no dropout); ``layer`` is this
+        block's coordinate in the whole pool. Returns
         ``(y, k_pages', v_pages')``."""
         h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
         h, k_pages, v_pages = self.attn.apply_paged(
-            params["attn"], h, k_pages, v_pages, block_table, positions, valid
+            params["attn"], h, k_pages, v_pages, block_table, positions,
+            valid, layer=layer,
         )
         x = x + h
         h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
@@ -695,11 +697,13 @@ class TransformerLM(Model):
 
         ``tokens`` (S, C) int32 — slot ``s``'s chunk occupies global
         positions ``[positions[s], positions[s]+C)`` with the first
-        ``valid[s]`` rows real; ``k_pages``/``v_pages`` are the per-layer
-        stacked pool ``(L, NB, BL, Hkv, D)``; ``block_table`` (S, MB) maps
-        slot positions onto pool blocks. Returns ``(logits (S, V) of the
-        chunk's LAST position, k_pages', v_pages')`` — C=1 is the decode
-        wave, C=chunk the prefill step, one code path for both.
+        ``valid[s]`` rows real; ``k_pages``/``v_pages`` are the whole pool
+        ``(L, NB, BL, Hkv*D)``; ``block_table`` (S, MB) maps slot positions
+        onto pool blocks. Returns ``(logits (S, V) of the chunk's LAST
+        position, k_pages', v_pages')`` — C=1 is the decode wave, C=chunk
+        the prefill step, one code path for both. Every layer reads and
+        writes the whole pool at its own layer coordinate: no layer is
+        sliced out or put back, so a donated pool is updated in place.
         """
         p = params
         s, c = tokens.shape
@@ -716,24 +720,22 @@ class TransformerLM(Model):
         if self.config.scan_layers:
             block = self.blocks[0]
 
-            def body(h, xs):
-                params_i, kp, vp = xs
-                h, kp, vp = block.apply_paged(
-                    params_i, h, kp, vp, block_table, positions, valid
-                )
-                return h, (kp, vp)
+            def body(carry, xs):
+                params_i, i = xs
+                return block.apply_paged(
+                    params_i, *carry, block_table, positions, valid, layer=i
+                ), None
 
-            x, (k_pages, v_pages) = jax.lax.scan(
-                body, x, (p["blocks_stacked"], k_pages, v_pages)
+            layers = jnp.arange(self.config.num_layers, dtype=jnp.int32)
+            (x, k_pages, v_pages), _ = jax.lax.scan(
+                body, (x, k_pages, v_pages), (p["blocks_stacked"], layers)
             )
         else:
             for i, block in enumerate(self.blocks):
-                x, kp, vp = block.apply_paged(
-                    p["blocks"][str(i)], x, k_pages[i], v_pages[i],
-                    block_table, positions, valid,
+                x, k_pages, v_pages = block.apply_paged(
+                    p["blocks"][str(i)], x, k_pages, v_pages,
+                    block_table, positions, valid, layer=i,
                 )
-                k_pages = k_pages.at[i].set(kp)
-                v_pages = v_pages.at[i].set(vp)
 
         x = x[:, -1:]  # only the last position's logits are consumed
         x, _ = self.ln_f.apply({"params": p["ln_f"], "state": {}}, x)

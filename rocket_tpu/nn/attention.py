@@ -661,14 +661,16 @@ class MultiHeadAttention(Layer):
         return out, {"k": k_cache, "v": v_cache}
 
     def apply_paged(self, params, x, k_pages, v_pages, block_table,
-                    positions, valid):
+                    positions, valid, layer=0):
         """Paged-pool decode/prefill chunk: ``x`` (S, C, D) — slot ``s``'s
         chunk sits at global positions ``[positions[s], positions[s]+C)``
         and only its first ``valid[s]`` rows are real (padding rows write
         to the pool's trash block and their outputs are garbage the caller
-        ignores). K/V rows are scattered into the shared block pool via
-        ``block_table`` and attention runs causally over the gathered
-        prefix (``ops/paged_attention.py``). Eval semantics — no dropout.
+        ignores). K/V rows are scattered into layer ``layer`` of the shared
+        ``(L, NB, BL, Hkv*D)`` block pool via ``block_table`` and attention
+        runs causally over the gathered prefix (``ops/paged_attention.py``);
+        the whole pool goes in and comes out, updated in place. Eval
+        semantics — no dropout.
         Returns ``(out (S, C, D), k_pages', v_pages')``.
 
         Stays feature-major end to end (no (B, H, T, D) transposes), and
@@ -693,7 +695,8 @@ class MultiHeadAttention(Layer):
             q2 = apply_rope_offsets(q2, positions, self.rope_base)
             k2 = apply_rope_offsets(k2, positions, self.rope_base)
         out, k_pages, v_pages = paged_attention(
-            q2, k2, v2, k_pages, v_pages, block_table, positions, valid
+            q2, k2, v2, k_pages, v_pages, block_table, positions, valid,
+            layer=layer,
         )
         out, _ = self.proj.apply({"params": params["proj"], "state": {}}, out)
         return out, k_pages, v_pages

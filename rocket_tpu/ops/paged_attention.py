@@ -2,23 +2,34 @@
 
 The serving engine (``rocket_tpu.serve``) keeps every sequence's KV cache
 in a FIXED pool of HBM blocks instead of a per-call ``(B, T_max)`` dense
-cache: ``k_pages``/``v_pages`` are ``(num_blocks, block_len, Hkv, D)``
-arrays shared by every live request, and a per-slot ``block_table`` maps a
-sequence's logical positions onto pool blocks (vLLM's PagedAttention
-layout, arXiv 2309.06180). Thousands of concurrent sequences then share
-``num_blocks * block_bytes`` of HBM regardless of how many are admitted —
-the pool is allocated once and only the tables change.
+cache: ``k_pages``/``v_pages`` are ``(L, num_blocks, block_len, Hkv*D)``
+arrays shared by every live request and every layer, and a per-slot
+``block_table`` maps a sequence's logical positions onto pool blocks
+(vLLM's PagedAttention layout, arXiv 2309.06180). Thousands of concurrent
+sequences then share ``num_blocks * block_bytes`` of HBM regardless of how
+many are admitted — the pool is allocated once and only the tables change.
+
+ONE layout, addressed in place. A page is stored as the ``(block_len,
+Hkv*D)`` tile the kernel streams: every kv head's row side by side on the
+lane axis, the page's rows on the sublane axis, so the scatter writes
+whole rows, the kernel's block IS the array as stored and the XLA path
+splits heads only on the small context it gathered. Every function here
+takes the WHOLE pool and a ``layer`` coordinate (a Python int or a traced
+scalar): nothing slices a layer out or puts one back, so under donation
+the only thing a program does to the pool is the in-place row scatter. (A
+``(…, Hkv, D)`` pool made every one of those views a relayout copy of a
+whole layer on a TPU, 63 % of the chat cell's device time; PERF.md, PR 28.)
 
 Two device-side implementations share one signature:
 
 * **XLA path** (portable — every backend): :func:`write_kv_pages`
   scatters the chunk's new K/V rows into the pool, then the mapped
-  blocks are gathered back to a contiguous ``(S, T, Hkv, D)`` context
+  blocks are gathered back to a contiguous ``(S, T, Hkv*D)`` context
   and causally-masked GQA attention runs over it in the feature-major
   layout. The gather materializes a transient
-  ``(max_slots, max_blocks_per_seq * block_len, Hkv, D)`` context per
-  wave — the 4.6x decode overfetch RKT602 measured against the analytic
-  floor.
+  ``(max_slots, max_blocks_per_seq * block_len, Hkv*D)`` context per
+  wave (RKT602's CPU cost model prices it at 4.6x the analytic floor
+  for a decode wave: a prediction, not a measurement).
 * **pallas paged-decode kernel** (TPU, C=1 decode waves): the same
   scatter, then gather and attend are FUSED per block-table page —
   each grid step streams one ``(block_kv, Hkv*D)`` tile of one mapped
@@ -43,9 +54,10 @@ identical to an untuned checkout — asserted in tests).
 table. A PINNED ``pallas`` (argument, table or environment) that cannot
 run raises — it never silently becomes the other path.
 
-Layout notes for TPU: the pool's ``(Hkv, D)`` minor axes are viewed as
-one ``Hkv*D`` lane axis inside the kernel and ``block_len`` must be a
-multiple of the dtype's sublane tile (8 f32 / 16 bf16).
+Layout notes for TPU: the kernel's block spans the pool's whole
+``Hkv*D`` lane axis (so any head count and width is Mosaic-legal) and
+``block_len`` must be a multiple of the dtype's sublane tile (8 f32 / 16
+bf16).
 
 Inference only (no custom VJP — serving never differentiates).
 """
@@ -75,47 +87,49 @@ _NEG_INF = -1e30
 _SUBLANE = {4: 8, 2: 16, 1: 32}
 
 
-def write_kv_pages(k_pages, v_pages, block_table, positions, valid, k_new, v_new):
-    """Scatter one chunk's K/V rows into the paged pool.
+def write_kv_pages(k_pages, v_pages, block_table, positions, valid,
+                   k_new, v_new, *, layer=0):
+    """Scatter one chunk's K/V rows into layer ``layer`` of the paged pool.
 
-    ``k_pages``/``v_pages`` ``(NB, BL, Hkv, D)``; ``block_table`` ``(S, MB)``
+    ``k_pages``/``v_pages`` ``(L, NB, BL, Hkv*D)``; ``block_table`` ``(S, MB)``
     int32 block ids (0 = the reserved trash block); ``positions`` ``(S,)``
     int32 — slot ``s``'s chunk occupies global positions
     ``[positions[s], positions[s] + C)``; ``valid`` ``(S,)`` int32 — only the
     first ``valid[s]`` rows of the chunk are real (the rest are padding and
     land in the trash block); ``k_new``/``v_new`` ``(S, C, Hkv, D)``.
-    Returns the updated ``(k_pages, v_pages)``.
+    Returns the updated ``(k_pages, v_pages)``: one scatter of whole
+    ``Hkv*D`` rows at ``(layer, block, row)`` each, in place where the
+    pool is donated.
     """
-    nb, bl = k_pages.shape[0], k_pages.shape[1]
+    bl = k_pages.shape[2]
     s, c = k_new.shape[0], k_new.shape[1]
     pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]  # (S, C)
     slot = jnp.clip(pos // bl, 0, block_table.shape[1] - 1)
     block = jnp.take_along_axis(block_table, slot, axis=1)              # (S, C)
     ok = jnp.arange(c, dtype=jnp.int32)[None, :] < valid[:, None]
-    # Flattened (block, row) target; masked rows collapse onto trash row 0
-    # (block 0 is never allocated, so collisions there are harmless).
-    flat = jnp.where(ok, block * bl + pos % bl, 0)                      # (S, C)
-    kf = k_pages.reshape((nb * bl,) + k_pages.shape[2:])
-    vf = v_pages.reshape((nb * bl,) + v_pages.shape[2:])
-    kf = kf.at[flat.reshape(-1)].set(
-        k_new.astype(kf.dtype).reshape((s * c,) + k_new.shape[2:])
+    # Masked rows collapse onto row 0 of the trash block (block 0 is
+    # never allocated, so collisions there are harmless).
+    block = jnp.where(ok, block, 0).reshape(-1)
+    row = jnp.where(ok, pos % bl, 0).reshape(-1)
+    k_pages = k_pages.at[layer, block, row].set(
+        k_new.astype(k_pages.dtype).reshape(s * c, -1)
     )
-    vf = vf.at[flat.reshape(-1)].set(
-        v_new.astype(vf.dtype).reshape((s * c,) + v_new.shape[2:])
+    v_pages = v_pages.at[layer, block, row].set(
+        v_new.astype(v_pages.dtype).reshape(s * c, -1)
     )
-    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
+    return k_pages, v_pages
 
 
-def paged_gather(pages, block_table):
-    """Gather a slot batch's mapped blocks to a contiguous context:
-    ``(NB, BL, Hkv, D)`` pages + ``(S, MB)`` table -> ``(S, MB*BL, Hkv, D)``.
-    Row ``t`` of the result is the slot's global position ``t`` (table slot
-    ``j`` covers positions ``[j*BL, (j+1)*BL)``); unmapped entries gather
-    the trash block and must be masked off by position."""
+def paged_gather(pages, block_table, *, layer=0):
+    """Gather a slot batch's mapped blocks of layer ``layer`` to a
+    contiguous context: ``(L, NB, BL, Hkv*D)`` pages + ``(S, MB)`` table
+    -> ``(S, MB*BL, Hkv*D)``. Row ``t`` of the result is the slot's global
+    position ``t`` (table slot ``j`` covers positions ``[j*BL, (j+1)*BL)``);
+    unmapped entries gather the trash block and must be masked off by
+    position."""
     s, mb = block_table.shape
-    bl = pages.shape[1]
-    ctx = jnp.take(pages, block_table, axis=0)          # (S, MB, BL, Hkv, D)
-    return ctx.reshape((s, mb * bl) + pages.shape[2:])
+    ctx = pages[layer, block_table]                     # (S, MB, BL, Hkv*D)
+    return ctx.reshape(s, mb * pages.shape[2], pages.shape[3])
 
 
 def paged_decode_supported(block_len: int, head_dim: int, itemsize: int = 4) -> bool:
@@ -129,6 +143,12 @@ def paged_decode_supported(block_len: int, head_dim: int, itemsize: int = 4) -> 
     return block_len % sub == 0 and head_dim % 8 == 0 and head_dim >= 8
 
 
+def _on_cpu() -> bool:
+    """Whether this process's default backend is the CPU, where the
+    kernel can only run interpreted."""
+    return jax.devices()[0].platform == "cpu"
+
+
 def _default_block_kv(block_len: int, itemsize: int = 4) -> int:
     """The hand-picked tile height: the largest power-of-two row count
     (<= 128) that divides the page — one page per grid step when the
@@ -140,7 +160,7 @@ def _default_block_kv(block_len: int, itemsize: int = 4) -> int:
     return block_len
 
 
-def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, block_kv, sub, mb, scale,
                    h_kv, g, d):
     """One (slot, kv-tile) grid step of the fused paged decode.
@@ -154,6 +174,7 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     key positions ``<= pos`` (the query's own row included) are all
     read from the pool — exact prefix semantics, one code path. All ops
     stay 2D per head (Mosaic rejects 3D shape casts)."""
+    del layer_ref, table_ref  # consumed by the index maps
     i = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[i]
@@ -172,8 +193,8 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         for h in range(h_kv):
             rows = slice(h * g, (h + 1) * g)
             q = q_ref[0, rows, :]                      # (g, D)
-            k = k_ref[0, :, h * d:(h + 1) * d]         # (block_kv, D)
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            k = k_ref[0, 0, :, h * d:(h + 1) * d]      # (block_kv, D)
+            v = v_ref[0, 0, :, h * d:(h + 1) * d]
             s_ij = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -204,44 +225,47 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
-                         *, block_kv: int, interpret: bool):
+                         *, layer=0, block_kv: int, interpret: bool):
     """The fused gather+attend for one decode wave: ``q`` (S, Hq, D),
-    pool/table/positions as in :func:`paged_attention` (new rows already
-    scattered). Returns ``out`` (S, Hq, D).
+    pool/table/positions/layer as in :func:`paged_attention` (new rows
+    already scattered). Returns ``out`` (S, Hq, D).
 
     Mosaic wants the last two dims of every block divisible by the
-    (sublane, 128) tile or equal to the array's own: the pool is viewed
-    as ``(NB, BL, Hkv*D)`` (a free reshape — the head and feature axes
-    are adjacent and minor) so a page tile is ``(block_kv, Hkv*D)`` with
-    the whole lane axis, and q/out blocks carry the whole ``(Hq, D)``
-    head axis; the kernel selects each kv head by a static lane slice.
-    A per-head block ``(1, block_kv, 1, D)`` / ``(1, g, D)`` is refused
-    by the TPU lowering whenever Hkv > 1 or g < 8."""
+    (sublane, 128) tile or equal to the array's own: a page tile is
+    ``(block_kv, Hkv*D)`` of the pool AS STORED, with the whole lane
+    axis, and q/out blocks carry the whole ``(Hq, D)`` head axis; the
+    kernel selects each kv head by a static lane slice. The layer rides
+    in as a prefetched scalar beside the table, so the index map
+    addresses ``(layer, page, tile)`` of the whole pool and one kernel
+    serves a Python-loop layer and a scanned one. A per-head block
+    ``(1, block_kv, 1, D)`` / ``(1, g, D)`` is refused by the TPU
+    lowering whenever Hkv > 1 or g < 8."""
     s, hq, d = q.shape
-    nb, bl, h_kv, _ = k_pages.shape
+    _, _, bl, hd = k_pages.shape
+    h_kv = hd // d
     mb = block_table.shape[1]
     g = hq // h_kv
     sub = bl // block_kv
     scale = 1.0 / math.sqrt(d)
 
-    def q_map(i, j, table_ref, pos_ref):
-        del j, table_ref, pos_ref
+    def q_map(i, j, layer_ref, table_ref, pos_ref):
+        del j, layer_ref, table_ref, pos_ref
         return (i, 0, 0)
 
-    def page_map(i, j, table_ref, pos_ref):
+    def page_map(i, j, layer_ref, table_ref, pos_ref):
         del pos_ref
-        # Dim 0 is blocked at one whole page, so the page id IS the
-        # dim-0 block index; dim 1 is tiled at block_kv rows, so the
-        # within-page tile is the dim-1 block index.
-        return (table_ref[i * mb + j // sub], j % sub, 0)
+        # Dims 0 and 1 are blocked at one layer and one whole page, so
+        # the layer and the page id ARE their block indices; dim 2 is
+        # tiled at block_kv rows, so the within-page tile is its index.
+        return (layer_ref[0], table_ref[i * mb + j // sub], j % sub, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s, mb * sub),
         in_specs=[
             pl.BlockSpec((1, hq, d), q_map),
-            pl.BlockSpec((1, block_kv, h_kv * d), page_map),
-            pl.BlockSpec((1, block_kv, h_kv * d), page_map),
+            pl.BlockSpec((1, 1, block_kv, hd), page_map),
+            pl.BlockSpec((1, 1, block_kv, hd), page_map),
         ],
         out_specs=pl.BlockSpec((1, hq, d), q_map),
         scratch_shapes=[
@@ -262,22 +286,24 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
         ),
         interpret=interpret,
         name="paged_decode",
-    )(block_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(positions, jnp.int32), q,
-      k_pages.reshape(nb, bl, h_kv * d), v_pages.reshape(nb, bl, h_kv * d))
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_table.reshape(-1).astype(jnp.int32),
+      jnp.asarray(positions, jnp.int32), q, k_pages, v_pages)
 
 
-def _attend_xla(q, k_pages, v_pages, block_table, positions, valid):
+def _attend_xla(q, k_pages, v_pages, block_table, positions, layer):
     """The portable gather+attend: contiguous per-slot context, einsum
     attention with f32 softmax statistics. ``q`` (S, C, Hq, D); returns
-    ``out`` (S, C, Hq*D). Exactly the pre-kernel implementation — the
-    proven-bitwise-identical CPU fallback."""
-    del valid  # padded rows produce well-defined garbage; callers ignore
+    ``out`` (S, C, Hq*D). Heads are split on the gathered context, never
+    on the pool. Padded query rows produce well-defined garbage the
+    callers ignore."""
     s, c, hq, d = q.shape
-    h_kv = k_pages.shape[2]
+    h_kv = k_pages.shape[3] // d
     g = hq // h_kv
-    k_ctx = paged_gather(k_pages, block_table)          # (S, T, Hkv, D)
-    v_ctx = paged_gather(v_pages, block_table)
+    k_ctx = paged_gather(k_pages, block_table, layer=layer) \
+        .reshape(s, -1, h_kv, d)                        # (S, T, Hkv, D)
+    v_ctx = paged_gather(v_pages, block_table, layer=layer) \
+        .reshape(s, -1, h_kv, d)
     t = k_ctx.shape[1]
     scale = 1.0 / math.sqrt(d)
     q5 = q.reshape(s, c, h_kv, g, d)
@@ -296,15 +322,18 @@ def _attend_xla(q, k_pages, v_pages, block_table, positions, valid):
 
 
 def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
-                    positions, valid, *, impl: Optional[str] = None,
+                    positions, valid, *, layer=0,
+                    impl: Optional[str] = None,
                     block_kv: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """One chunk of causal GQA attention against the paged pool.
 
     ``q`` ``(S, C, Hq, D)``; ``k_new``/``v_new`` ``(S, C, Hkv, D)`` (RoPE
-    already applied); pool/table/positions/valid as in
-    :func:`write_kv_pages`. The chunk's rows are written into the pool
-    FIRST, then each query row ``i`` attends over key positions
+    already applied); pool ``(L, NB, BL, Hkv*D)``, table/positions/valid
+    and ``layer`` (int or traced scalar: which layer of the pool this
+    call reads and writes) as in :func:`write_kv_pages`. The chunk's rows
+    are written into the pool FIRST, then each query row ``i`` attends
+    over key positions
     ``<= positions[s] + i`` — exact prefix semantics at any chunk size
     (C=1 decode and C=chunk prefill share this one signature, which is
     what makes chunked prefill bit-match one-shot prefill).
@@ -324,13 +353,18 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
     them.
     """
     s, c, hq, d = q.shape
-    bl = int(k_pages.shape[1])
-    h_kv = int(k_pages.shape[2])
+    bl = int(k_pages.shape[2])
+    h_kv = int(k_new.shape[2])
     mb = int(block_table.shape[1])
     if hq % h_kv:
         raise ValueError(f"paged_attention: Hq {hq} not a multiple of Hkv {h_kv}")
+    if k_pages.ndim != 4 or k_pages.shape[3] != h_kv * d:
+        raise ValueError(
+            f"paged_attention: pool {k_pages.shape} is not "
+            f"(L, NB, BL, Hkv*D) with Hkv*D = {h_kv} * {d}"
+        )
     itemsize = jnp.dtype(k_pages.dtype).itemsize
-    on_cpu = jax.devices()[0].platform == "cpu"
+    on_cpu = _on_cpu()
     kernel_can_run = c == 1 and paged_decode_supported(bl, d, itemsize)
     if (impl is None or block_kv is None) and c == 1:
         # Tunable surface (tune kernel "paged_decode"): impl is a REAL
@@ -376,7 +410,8 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         )
 
     k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, block_table, positions, valid, k_new, v_new
+        k_pages, v_pages, block_table, positions, valid, k_new, v_new,
+        layer=layer,
     )
 
     if impl == "pallas":
@@ -386,9 +421,9 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
                 f"multiple of the sublane tile dividing block_len={bl}"
             )
         out = _paged_decode_pallas(
-            q[:, 0], k_pages, v_pages, block_table, positions,
+            q[:, 0], k_pages, v_pages, block_table, positions, layer=layer,
             block_kv=int(block_kv), interpret=on_cpu or bool(interpret),
         ).reshape(s, 1, hq * d)
         return out, k_pages, v_pages
-    out = _attend_xla(q, k_pages, v_pages, block_table, positions, valid)
+    out = _attend_xla(q, k_pages, v_pages, block_table, positions, layer)
     return out, k_pages, v_pages

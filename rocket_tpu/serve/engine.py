@@ -204,11 +204,7 @@ def abstract_wave_inputs(
         lambda p: _decode_params(p, model.config.activation_dtype), abs_params
     )
     s, mb, c = int(max_slots), int(max_blocks_per_seq), int(prefill_chunk)
-    pool_shape = (
-        spec.num_layers, spec.num_blocks, spec.block_len,
-        spec.num_kv_heads, spec.head_dim,
-    )
-    pool = jax.ShapeDtypeStruct(pool_shape, jnp.dtype(spec.dtype))
+    pool = jax.ShapeDtypeStruct(spec.pages_shape, jnp.dtype(spec.dtype))
     i32 = jnp.int32
     f32 = jnp.float32
     vec_i = jax.ShapeDtypeStruct((s,), i32)

@@ -1,12 +1,16 @@
 """Paged KV block pool — fixed-shape HBM arrays + the host-side allocator.
 
 The pool is the serving engine's only model-state memory: two
-``(L, num_blocks, block_len, Hkv, D)`` arrays allocated ONCE, sized
-independently of how many requests ever flow through the engine. Requests
+``(L, num_blocks, block_len, Hkv*D)`` arrays allocated ONCE, sized
+independently of how many requests ever flow through the engine. A page is
+stored as the ``(block_len, Hkv*D)`` tile both compiled programs read and
+write in place: all kv heads of a row side by side on the minor (lane)
+axis, so a row is one scatter update, a page is one kernel block and no
+program ever relayouts the pool (``ops/paged_attention.py``). Requests
 own *blocks*, not cache rows: the allocator hands out integer block ids on
 the host and the compiled step indexes the pool through per-slot block
-tables (``ops/paged_attention.py``), so admitting a request is a few host
-list operations and never touches compiled code.
+tables, so admitting a request is a few host list operations and never
+touches compiled code.
 
 Block 0 is RESERVED as the trash sink: masked writes (prompt padding,
 inactive slots) land there and unmapped block-table entries point at it,
@@ -64,15 +68,19 @@ class KVPoolSpec:
         engine's peak KV memory regardless of request count."""
         return self.num_blocks * self.block_bytes
 
+    @property
+    def pages_shape(self) -> tuple[int, int, int, int]:
+        """Shape of ``k_pages`` (and of ``v_pages``): ``(L, NB, BL, Hkv*D)``."""
+        return (
+            self.num_layers, self.num_blocks, self.block_len,
+            self.num_kv_heads * self.head_dim,
+        )
+
     def init_pages(self):
         """The zeroed device pool: ``(k_pages, v_pages)``, each
-        ``(L, NB, BL, Hkv, D)``."""
-        shape = (
-            self.num_layers, self.num_blocks, self.block_len,
-            self.num_kv_heads, self.head_dim,
-        )
+        :attr:`pages_shape`."""
         dt = jnp.dtype(self.dtype)
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+        return jnp.zeros(self.pages_shape, dt), jnp.zeros(self.pages_shape, dt)
 
 
 class BlockAllocator:

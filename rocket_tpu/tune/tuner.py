@@ -442,7 +442,7 @@ def _paged_case(name, s, mb, bl, hkv, hq, d, dtype, smoke=False):
         q = (jax.random.normal(kq, (s, 1, hq, d)) * 0.2).astype(dtype)
         k_new = (jax.random.normal(kn, (s, 1, hkv, d)) * 0.2).astype(dtype)
         v_new = k_new * 0.5
-        k_pages = (jax.random.normal(kp, (nb, bl, hkv, d)) * 0.2) \
+        k_pages = (jax.random.normal(kp, (1, nb, bl, hkv * d)) * 0.2) \
             .astype(dtype)
         v_pages = k_pages * 0.5
         table = jnp.asarray(

@@ -52,6 +52,19 @@ def llama_lm():
     return model, variables
 
 
+@pytest.fixture(scope="module")
+def scan_lm():
+    """``scan_layers=True``: the paged step scans the blocks with the whole
+    pool in the carry and the layer index as a traced scalar."""
+    config = TransformerConfig(
+        vocab_size=64, max_seq_len=64, dim=32, num_layers=3, num_heads=4,
+        num_kv_heads=2, dropout=0.0, scan_layers=True,
+    )
+    model = TransformerLM(config)
+    variables = jax.jit(model.init)(jax.random.key(2))
+    return model, variables
+
+
 # ---------------------------------------------------------------------------
 # Block pool
 # ---------------------------------------------------------------------------
@@ -87,7 +100,7 @@ def test_kv_pool_spec_bytes_and_pages():
     assert spec.block_bytes == 2 * 2 * 4 * 3 * 8 * 2
     assert spec.pool_bytes == 5 * spec.block_bytes
     k, v = spec.init_pages()
-    assert k.shape == v.shape == (2, 5, 4, 3, 8)
+    assert k.shape == v.shape == spec.pages_shape == (2, 5, 4, 3 * 8)
     assert k.dtype == jnp.bfloat16
     with pytest.raises(ValueError):
         KVPoolSpec(num_layers=1, num_blocks=1, block_len=4,
@@ -98,7 +111,7 @@ def test_kv_pool_spec_bytes_and_pages():
 # Paged decode correctness
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("lm", ["tiny_lm", "llama_lm"])
+@pytest.mark.parametrize("lm", ["tiny_lm", "llama_lm", "scan_lm"])
 @pytest.mark.parametrize("chunk", [3, 16])
 def test_chunked_prefill_matches_one_shot_logits(lm, chunk, request):
     """Prefill through the paged path in chunks of any size must produce
@@ -574,7 +587,10 @@ def test_scanned_eviction_backpressure_resume_equivalence(tiny_lm):
 # The pallas paged-decode kernel (ISSUE 11 tentpole)
 # ---------------------------------------------------------------------------
 
-def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32):
+def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32,
+                    layers=1):
+    """One C=1 wave's operands against a pool in the stored layout,
+    ``(layers, NB, BL, Hkv*D)``."""
     rng = np.random.default_rng(11)
     nb = 1 + s * mb
     q = jnp.asarray(rng.normal(size=(s, 1, hq, d)).astype(np.float32)) \
@@ -584,7 +600,7 @@ def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32):
     ).astype(dtype)
     v_new = k_new * 0.5
     k_pages = jnp.asarray(
-        rng.normal(size=(nb, bl, hkv, d)).astype(np.float32)
+        rng.normal(size=(layers, nb, bl, hkv * d)).astype(np.float32)
     ).astype(dtype)
     v_pages = k_pages * 0.25
     table = jnp.asarray(
@@ -594,6 +610,121 @@ def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32):
     positions = jnp.asarray([0, bl + 3, mb * bl - 1], jnp.int32)[:s]
     valid = jnp.ones((s,), jnp.int32)
     return q, k_new, v_new, k_pages, v_pages, table, positions, valid
+
+
+def _dense_reference(q, k_new, v_new, k_pages, v_pages, table, positions,
+                     valid, layer):
+    """Plain numpy attention of each real chunk row over its slot's prefix:
+    pool rows below the chunk, the chunk's own rows from ``k_new``/``v_new``.
+    Returns ``(S, C, Hq*D)`` with padded rows left at zero."""
+    q, k_new, v_new, k_pages, v_pages = (
+        np.asarray(a, np.float64) for a in (q, k_new, v_new, k_pages, v_pages)
+    )
+    table, positions, valid = (np.asarray(a) for a in (table, positions, valid))
+    s, c, hq, d = q.shape
+    hkv = k_new.shape[2]
+    bl = k_pages.shape[2]
+    out = np.zeros((s, c, hq, d))
+    for n in range(s):
+        start = int(positions[n])
+        for i in range(int(valid[n])):
+            keys, vals = [], []
+            for t in range(start + i + 1):
+                if t >= start:
+                    keys.append(k_new[n, t - start])
+                    vals.append(v_new[n, t - start])
+                else:
+                    page = (layer, table[n, t // bl], t % bl)
+                    keys.append(k_pages[page].reshape(hkv, d))
+                    vals.append(v_pages[page].reshape(hkv, d))
+            keys, vals = np.stack(keys), np.stack(vals)      # (T, Hkv, D)
+            for h in range(hq):
+                kv = h // (hq // hkv)
+                logits = keys[:, kv] @ q[n, i, h] / np.sqrt(d)
+                w = np.exp(logits - logits.max())
+                out[n, i, h] = (w / w.sum()) @ vals[:, kv]
+    return out.reshape(s, c, hq * d)
+
+
+#: (Hq, Hkv, D): MHA at the chat cell's 20 x 64 (1280 lanes, ten lane
+#: tiles), GQA with Hkv < Hq, and an Hkv*D that is no multiple of 128
+#: (3 x 8 = 24 lanes: legal because a block spans the whole lane axis).
+_LAYOUT_GEOMETRIES = {
+    "mha_20x64": (20, 20, 64),
+    "gqa_6over2": (6, 2, 16),
+    "lanes_24": (3, 3, 8),
+}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("geometry", list(_LAYOUT_GEOMETRIES))
+def test_paged_attention_matches_dense_reference(geometry, impl):
+    """Both implementations against plain attention, on the stored
+    ``(L, NB, BL, Hkv*D)`` layout and at a layer that is not the first:
+    the wave's rows land at ``(layer, block, row)``, every other row of
+    the pool (other layers included) stays bitwise what it was."""
+    from rocket_tpu.ops.paged_attention import paged_attention
+
+    hq, hkv, d = _LAYOUT_GEOMETRIES[geometry]
+    layer = 1
+    ops = _paged_operands(hq=hq, hkv=hkv, d=d, layers=3)
+    q, k_new, v_new, k_pages, v_pages, table, positions, valid = ops
+    assert k_pages.shape == (3, 13, 16, hkv * d)
+    out, kp, vp = paged_attention(
+        *ops, layer=layer, impl=impl, interpret=impl == "pallas"
+    )
+    ref = _dense_reference(*ops, layer)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
+    assert kp.shape == k_pages.shape and vp.shape == v_pages.shape
+    want_k, want_v = np.array(k_pages), np.array(v_pages)
+    for n in range(3):
+        pos = int(positions[n])
+        at = (layer, int(table[n, pos // 16]), pos % 16)
+        want_k[at] = np.asarray(k_new[n, 0]).reshape(-1)
+        want_v[at] = np.asarray(v_new[n, 0]).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(kp), want_k)
+    np.testing.assert_array_equal(np.asarray(vp), want_v)
+
+
+@pytest.mark.parametrize("geometry", list(_LAYOUT_GEOMETRIES))
+def test_paged_prefill_chunk_matches_dense_reference(geometry):
+    """A C > 1 chunk (the prefill program's shape) with a padded tail:
+    real rows match plain attention, padded rows write only to the trash
+    block, and the layer may be a traced scalar (the scanned model's)."""
+    from rocket_tpu.ops.paged_attention import paged_attention
+
+    hq, hkv, d = _LAYOUT_GEOMETRIES[geometry]
+    rng = np.random.default_rng(5)
+    s, c, bl, mb, layers = 2, 5, 16, 3, 2
+    _, _, _, k_pages, v_pages, _, _, _ = _paged_operands(
+        s=s, hq=hq, hkv=hkv, d=d, mb=mb, layers=layers
+    )
+    table = jnp.asarray(1 + np.arange(s * mb, dtype=np.int32).reshape(s, mb))
+    q = jnp.asarray(rng.normal(size=(s, c, hq, d)).astype(np.float32))
+    k_new = jnp.asarray(rng.normal(size=(s, c, hkv, d)).astype(np.float32))
+    v_new = k_new * 0.5
+    positions = jnp.asarray([bl - 2, 7], jnp.int32)   # slot 0 crosses a page
+    valid = jnp.asarray([5, 3], jnp.int32)            # slot 1: 2 padded rows
+    ops = (q, k_new, v_new, k_pages, v_pages, table, positions, valid)
+
+    out, kp, vp = jax.jit(
+        lambda layer, *a: paged_attention(*a, layer=layer)
+    )(jnp.int32(1), *ops)
+    ref = _dense_reference(*ops, 1)
+    real = np.arange(c)[None, :] < np.asarray(valid)[:, None]
+    np.testing.assert_allclose(
+        np.asarray(out)[real], ref[real], atol=2e-5, rtol=2e-5
+    )
+    want_k = np.array(k_pages)
+    for n in range(s):
+        for i in range(int(valid[n])):
+            pos = int(positions[n]) + i
+            want_k[1, int(table[n, pos // bl]), pos % bl] = \
+                np.asarray(k_new[n, i]).reshape(-1)
+    got_k = np.asarray(kp)
+    np.testing.assert_array_equal(got_k[:, 1:], want_k[:, 1:])
+    np.testing.assert_array_equal(got_k[0], want_k[0])   # layer 0 untouched
+    np.testing.assert_array_equal(got_k[1, 0, 1:], want_k[1, 0, 1:])
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 4), (6, 2)])

@@ -126,7 +126,7 @@ def test_paged_decode_kernel(sds, hq, hkv, d, block_len, dtype):
     assert paged_decode_supported(block_len, d, itemsize)
     slots, ctx = 16, 1024
     mb = ctx // block_len
-    pool = sds((slots * mb, block_len, hkv, d), dtype)
+    pool = sds((1, slots * mb, block_len, hkv * d), dtype)
     _compile(
         functools.partial(
             _paged_decode_pallas,
@@ -194,7 +194,7 @@ def test_paged_decode_kernel_carries_its_name_at_the_chat_cell_width(sds):
 
     slots, ctx, bl, h, d = 32, 1024, 16, 20, 64
     mb = ctx // bl
-    pool = sds((1 + slots * mb, bl, h, d), jnp.bfloat16)
+    pool = sds((1, 1 + slots * mb, bl, h * d), jnp.bfloat16)
     text = _compile(
         functools.partial(
             _paged_decode_pallas, block_kv=_default_block_kv(bl, 2),
@@ -205,3 +205,110 @@ def test_paged_decode_kernel_carries_its_name_at_the_chat_cell_width(sds):
     )
     names = _kernel_instructions(text)
     assert any("paged_decode" in n for n in names), names
+
+
+# -- the KV pool is read and written where it lies ---------------------------
+
+
+def _materialised(text):
+    """``(opcode, name, elements, line)`` of every instruction of a compiled
+    program that owns a result buffer: those of the entry computation and
+    of loop bodies, not those inside a fusion (they never reach memory).
+    ``elements`` is the largest array in the result's type."""
+    import math
+    import re
+
+    fused = set(re.findall(r" fusion\([^\n]*calls=%?([\w.\-]+)", text))
+    out, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (.+?) ([\w\-]+)\(", line)
+        if not m or inside in fused:
+            continue
+        sizes = [
+            math.prod(map(int, dims.split(",")))
+            for dims in re.findall(r"\w+\[([0-9,]+)\]", m.group(2))
+        ]
+        out.append((m.group(3), m.group(1), max(sizes, default=0), line))
+    return out
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["loop", "scan"])
+def test_serve_programs_update_the_pool_in_place(
+    sds, monkeypatch, scan_layers
+):
+    """The engine's own two programs at the chat cell's widths (GPT-2
+    large: 20 heads x 64, 32 slots x 1024 positions in blocks of 16; two
+    layers and a small vocabulary, so that it compiles in seconds and the
+    pool is the only large array): in the optimized HLO nothing but the
+    donated pool's own row scatter produces a result of one layer slice's
+    size (NB*BL*Hkv*D elements) or more, the temporaries stay under one
+    layer slice, and both pool buffers are aliased input to output.
+
+    The op is told the backend is a TPU, so the decode program holds the
+    REAL Mosaic kernel and not its interpreted stand-in: what is asserted
+    is the whole program, the kernel's operands included. (A count of
+    instructions in a compile for a described chip; not a device number.)"""
+    import re
+
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=1024, dim=1280, num_layers=2,
+        num_heads=20, dropout=0.0, activation_dtype="bfloat16",
+        scan_layers=scan_layers,
+    ))
+    sc = ServeConfig(max_slots=32, block_len=16, prefill_chunk=128)
+    spec, mb, _, waves = sc.resolve(model.config)
+    assert spec.pages_shape == (2, 2049, 16, 1280)
+    pool_type = "bf16[%s]" % ",".join(map(str, spec.pages_shape))
+    layer_slice = spec.num_blocks * spec.block_len * 1280      # elements
+    decode_args, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    programs = {
+        "decode": (build_decode_wave(model, waves=waves), decode_args,
+                   DECODE_DONATE),
+        "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE),
+    }
+    for name, (fn, args, donate) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        text = compiled.as_text()
+        kernels = _kernel_instructions(text)
+        if name == "decode":
+            assert any("paged_decode" in k for k in kernels), (name, kernels)
+        large = [
+            (op, inst) for op, inst, elements, line in _materialised(text)
+            if elements >= layer_slice
+            and op not in ("parameter", "tuple", "get-tuple-element",
+                           "bitcast", "while")
+            # the one thing a program may do to the pool: scatter the new
+            # rows into the buffer it was given
+            and not (op == "fusion" and "/scatter\"" in line
+                     and pool_type in line)
+        ]
+        assert not large, (name, large)
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < layer_slice * 2, (   # bf16 bytes
+            name, memory.temp_size_in_bytes)
+        assert memory.alias_size_in_bytes >= spec.pool_bytes, (
+            name, memory.alias_size_in_bytes)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert aliases and aliases.group(1).count("alias") == 2, (name, text[:300])

@@ -576,7 +576,7 @@ def test_paged_decode_table_resolution(table_dir):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(2, 1, 2, 16)).astype(np.float32))
     kn = jnp.asarray(rng.normal(size=(2, 1, 2, 16)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(5, 16, 2, 16)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(1, 5, 16, 2 * 16)).astype(np.float32))
     table = jnp.asarray(np.asarray([[1, 2], [3, 4]], np.int32))
     pos = jnp.asarray([3, 17], jnp.int32)
     valid = jnp.ones((2,), jnp.int32)
