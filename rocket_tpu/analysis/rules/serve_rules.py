@@ -211,9 +211,10 @@ def check_serve_donation(
 ) -> list[Finding]:
     """RKT604: pool donation + the one-small-host-transfer-per-wave story.
 
-    Every compiled program must alias BOTH pool buffers input->output
-    (``pool_bytes`` of aliasing — ``KVPoolSpec.pool_bytes`` covers K and
-    V together; anything less means XLA inserted a pool copy somewhere
+    Every compiled program must alias EVERY pool buffer input->output
+    (``pool_bytes`` of aliasing — ``KVPoolSpec.pool_bytes`` covers all
+    the pool's arrays, K and V or the one latent array; anything less
+    means XLA inserted a pool copy somewhere
     on the wave path); the decode wave's non-aliased output (what the
     driver's single ``device_get`` fetches) must stay under
     ``host_bytes_max``; and the prefill program must return nothing
@@ -230,9 +231,9 @@ def check_serve_donation(
                 f"serve-donation-sync: the {prog.name} program aliases "
                 f"only {prog.aliased_bytes / 2**20:.2f} MiB of the "
                 f"{expected / 2**20:.2f} MiB donated pool buffers "
-                "(k_pages + v_pages) — the pool is copied every "
-                f"{prog.name} call; donate both pool arguments and keep "
-                "them flowing input->output unchanged in shape/dtype",
+                "(every array of the pool) — the pool is copied every "
+                f"{prog.name} call; donate the pool argument and keep "
+                "its arrays flowing input->output unchanged in shape/dtype",
             ))
         # Prefill returns only the aliased pool; a few bytes of tuple/
         # layout padding show up in output accounting on some backends.

@@ -593,16 +593,16 @@ def decode_floor_bytes(
     max_blocks_per_seq: int,
 ) -> int:
     """Analytic HBM floor of ONE decode wave: master params (read) +
-    the active-KV gather (every slot's mapped blocks, K and V) + the
-    one-new-row-per-slot pool scatter. What a perfectly fused wave
-    streams — the RKT602 denominator."""
-    itemsize = np.dtype(spec.dtype).itemsize
-    row = spec.num_kv_heads * spec.head_dim * itemsize
+    the active-KV gather (every slot's mapped blocks, of every pool
+    array: K and V, or the one latent) + the one-new-row-per-slot pool
+    scatter. What a perfectly fused wave streams — the RKT602
+    denominator."""
+    row = sum(spec.lanes) * np.dtype(spec.dtype).itemsize
     kv_gather = (
-        2 * spec.num_layers * max_slots * max_blocks_per_seq
+        spec.num_layers * max_slots * max_blocks_per_seq
         * spec.block_len * row
     )
-    scatter = 2 * spec.num_layers * max_slots * row
+    scatter = spec.num_layers * max_slots * row
     return int(params_bytes + kv_gather + scatter)
 
 

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +139,25 @@ class TransformerConfig:
     #: largest single allocation in the step. ``next_token_loss`` consumes
     #: either form. Eval mode always materializes logits (metrics need them).
     loss_chunk: int = 0
+    #: Latent attention (MLA, ``nn.attention.LatentAttention``) in place of
+    #: multi-head K/V attention: a ``LatentAttentionConfig``. The layer
+    #: rotates its own decoupled keys, so ``pos_embedding`` must be "rope";
+    #: its serving cache is ONE latent array (:attr:`kv_pool_lanes`).
+    latent_attention: Optional[Any] = None
+    #: Routed expert FFN of the sigmoid / group-limited kind with a shared
+    #: expert and an ``experts_held`` share (``nn.moe.RoutedExperts``): a
+    #: ``RoutedExpertsConfig``. The first ``first_dense_layers`` blocks
+    #: keep the dense FFN (of ``mlp`` kind and ``mlp_hidden`` width).
+    #: Serving and eval only: the router's balance has no training path.
+    routed_experts: Optional[Any] = None
+    first_dense_layers: int = 0
+    #: Dense FFN width where it is not ``mlp_ratio * dim``.
+    mlp_hidden: Optional[int] = None
+    #: Biases on the dense FFN's projections (False: the Llama/DeepSeek
+    #: families carry none).
+    mlp_bias: bool = True
+    #: Epsilon of every normalizer (None = the normalizer's default).
+    norm_eps: Optional[float] = None
     #: Label smoothing for ``next_token_loss``: the target distribution is
     #: (1-eps) one-hot + eps uniform. Lives on the CONFIG (not the
     #: objective) so the fused (loss_chunk) and full-logits paths apply the
@@ -191,7 +210,38 @@ class TransformerConfig:
         if self.num_experts > 0 and self.mlp != "gelu":
             raise ValueError(
                 f"TransformerConfig: mlp={self.mlp!r} has no effect with "
-                "num_experts > 0 (the MoE brings its own FFN)"
+                "num_experts > 0 (nn.moe.MoE's experts are two-matrix GELU; "
+                "gated experts are routed_experts)"
+            )
+        if self.routed_experts is not None:
+            if self.num_experts > 0:
+                raise ValueError(
+                    "TransformerConfig: num_experts (nn.moe.MoE) and "
+                    "routed_experts (nn.moe.RoutedExperts) are two kinds "
+                    "of expert layer; give one"
+                )
+            if not 0 <= self.first_dense_layers <= self.num_layers:
+                raise ValueError(
+                    f"TransformerConfig: first_dense_layers "
+                    f"{self.first_dense_layers} outside [0, {self.num_layers}]"
+                )
+            if self.scan_layers and 0 < self.first_dense_layers < self.num_layers:
+                raise ValueError(
+                    "TransformerConfig: scan_layers needs every block alike; "
+                    "dense-then-routed layers run as a Python loop"
+                )
+            if self.pipeline_axis:
+                raise ValueError(
+                    "TransformerConfig: routed_experts has no pipelined path"
+                )
+        elif self.first_dense_layers:
+            raise ValueError(
+                "TransformerConfig: first_dense_layers without routed_experts"
+            )
+        if self.latent_attention is not None and self.pos_embedding != "rope":
+            raise ValueError(
+                "TransformerConfig: latent_attention rotates its own "
+                "decoupled keys; set pos_embedding='rope'"
             )
         if self.pipeline_schedule not in ("gpipe", "1f1b"):
             raise ValueError(
@@ -216,6 +266,26 @@ class TransformerConfig:
         :meth:`validate` first; unknown values fall through to it."""
         self.validate()
         return RMSNorm if self.norm == "rmsnorm" else LayerNorm
+
+    def make_norm(self, features: int):
+        """A normalizer of the configured class and epsilon."""
+        cls = self.norm_cls()
+        if self.norm_eps is None:
+            return cls(features)
+        return cls(features, eps=self.norm_eps)
+
+    @property
+    def kv_pool_lanes(self) -> tuple:
+        """What one layer caches per token in the serving pool: the lanes
+        of each pool array. Two arrays of ``Hkv * head_dim`` (K and V), or
+        ONE latent array under ``latent_attention`` — the one description
+        ``serve/kv_pool.py`` sizes the pool from."""
+        if self.latent_attention is not None:
+            return (self.latent_attention.pool_lanes,)
+        lanes = (self.num_kv_heads or self.num_heads) * (
+            self.dim // self.num_heads
+        )
+        return (lanes, lanes)
 
     @staticmethod
     def char_lm(vocab_size: int = 128, max_seq_len: int = 256) -> "TransformerConfig":
@@ -276,15 +346,31 @@ class Block(Layer):
     def __init__(self, config: TransformerConfig, layer_idx: int):
         c = config
         c.validate()
-        norm_cls = c.norm_cls()
-        self.ln1 = norm_cls(c.dim)
-        self.attn = MultiHeadAttention(
-            c.dim, c.num_heads, num_kv_heads=c.num_kv_heads, causal=c.causal,
-            dropout=c.dropout, impl=c.attention_impl, seq_axis=c.seq_axis,
-            rope=c.pos_embedding == "rope", rope_base=c.rope_base,
-        )
-        self.ln2 = norm_cls(c.dim)
-        if c.num_experts > 0:
+        self.ln1 = c.make_norm(c.dim)
+        self.latent = c.latent_attention is not None
+        if self.latent:
+            from rocket_tpu.nn.attention import LatentAttention
+
+            self.attn = LatentAttention(
+                c.dim, c.num_heads, c.latent_attention,
+                rope_base=c.rope_base,
+                norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
+            )
+        else:
+            self.attn = MultiHeadAttention(
+                c.dim, c.num_heads, num_kv_heads=c.num_kv_heads,
+                causal=c.causal, dropout=c.dropout, impl=c.attention_impl,
+                seq_axis=c.seq_axis, rope=c.pos_embedding == "rope",
+                rope_base=c.rope_base,
+            )
+        self.ln2 = c.make_norm(c.dim)
+        self.routed = None
+        if c.routed_experts is not None and layer_idx >= c.first_dense_layers:
+            from rocket_tpu.nn.moe import RoutedExperts
+
+            self.routed = RoutedExperts(c.dim, c.routed_experts)
+            self.moe = self.fc_in = self.fc_out = self.fc_gate = None
+        elif c.num_experts > 0:
             from rocket_tpu.nn.moe import MoE
 
             self.moe = MoE(
@@ -296,7 +382,8 @@ class Block(Layer):
             self.fc_in = self.fc_out = self.fc_gate = None
         else:
             self.moe = None
-            hidden = c.mlp_ratio * c.dim
+            hidden = c.mlp_hidden or c.mlp_ratio * c.dim
+            dense = functools.partial(Dense, use_bias=c.mlp_bias)
             if c.mlp == "swiglu":
                 # TWO separate projections, not one fused (gate|up) matmul.
                 # Same matmul FLOPs, but the fused variant materializes the
@@ -306,12 +393,12 @@ class Block(Layer):
                 # for the whole MLP fwd+bwd on chip (6-8 ms vs 3.8 ms/layer
                 # at GPT-2 shapes). Separate kernels also shard
                 # column-parallel independently.
-                self.fc_gate = Dense(c.dim, hidden)
-                self.fc_in = Dense(c.dim, hidden)  # the "up" projection
+                self.fc_gate = dense(c.dim, hidden)
+                self.fc_in = dense(c.dim, hidden)  # the "up" projection
             else:
                 self.fc_gate = None
-                self.fc_in = Dense(c.dim, hidden)
-            self.fc_out = Dense(hidden, c.dim)
+                self.fc_in = dense(c.dim, hidden)
+            self.fc_out = dense(hidden, c.dim)
         self.mlp_type = c.mlp
         self.dropout = Dropout(c.dropout) if c.dropout else None
         # GPT-2: residual projections scaled by 1/sqrt(2*num_layers).
@@ -323,7 +410,8 @@ class Block(Layer):
         # biased configuration (the char-LM shape). Anything else stays
         # on the reference chain statically.
         self._block_attn_ok = (
-            c.norm == "layernorm"
+            not self.latent
+            and c.norm == "layernorm"
             and c.pos_embedding != "rope"
             and c.causal
             and (c.num_kv_heads is None or c.num_kv_heads == c.num_heads)
@@ -342,7 +430,9 @@ class Block(Layer):
         }
         # Residual-output scaling (attn.proj and the FFN output kernel).
         params["attn"]["proj"]["w"] = params["attn"]["proj"]["w"] * self._resid_scale
-        if self.moe is not None:
+        if self.routed is not None:
+            params["moe"] = self.routed.init_params(keys[3])
+        elif self.moe is not None:
             params["moe"] = self.moe.init_params(keys[3])
             params["moe"]["experts"]["w_out"] = (
                 params["moe"]["experts"]["w_out"] * self._resid_scale
@@ -384,7 +474,11 @@ class Block(Layer):
 
         h, _ = self.ln2.apply({"params": p["ln2"], "state": {}}, x)
         aux = None
-        if self.moe is not None:
+        if self.routed is not None:
+            h, _ = self.routed.apply(
+                {"params": p["moe"], "state": {}}, h, mode=mode
+            )
+        elif self.moe is not None:
             h, moe_out = self.moe.apply({"params": p["moe"], "state": {}}, h)
             aux = moe_out
         else:
@@ -493,31 +587,43 @@ class Block(Layer):
         h, cache = self.attn.apply_cached(params["attn"], h, cache, pos)
         x = x + h
         h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        if self.moe is not None:
-            h, _ = self.moe.apply({"params": params["moe"], "state": {}}, h)
-        else:
-            h = self._mlp(params["mlp"], h)
-        return x + h, cache
+        return x + self._ffn_eval(params, h)[0], cache
 
-    def apply_paged(self, params, x, k_pages, v_pages, block_table,
-                    positions, valid, layer=0):
+    def _ffn_eval(self, params, h, token_mask=None):
+        """The block's FFN of whichever kind, eval semantics: ``(out,
+        counts)`` — ``counts`` the pairs each held expert received (a
+        routed block; ``token_mask`` marks the rows that are tokens) or
+        None."""
+        if self.routed is not None:
+            return self.routed.apply(
+                {"params": params["moe"], "state": {}}, h,
+                token_mask=token_mask,
+            )
+        if self.moe is not None:
+            return self.moe.apply({"params": params["moe"], "state": {}}, h)[0], None
+        return self._mlp(params["mlp"], h), None
+
+    def apply_paged(self, params, x, pages, block_table, positions, valid,
+                    layer=0):
         """Decode/prefill chunk through the block against an EXTERNAL
-        paged KV pool (``rocket_tpu.serve``): ``x`` (S, C, D) at per-slot
-        global positions (eval semantics — no dropout); ``layer`` is this
-        block's coordinate in the whole pool. Returns
-        ``(y, k_pages', v_pages')``."""
+        paged pool (``rocket_tpu.serve``): ``x`` (S, C, D) at per-slot
+        global positions (eval semantics — no dropout); ``pages`` the
+        pool's arrays as ``TransformerConfig.kv_pool_lanes`` declares them
+        (``(k_pages, v_pages)``, or one latent array); ``layer`` is this
+        block's coordinate in the whole pool. Returns ``(y, pages',
+        counts)`` — ``counts`` the pairs each held expert received (a
+        routed block) or None."""
         h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
-        h, k_pages, v_pages = self.attn.apply_paged(
-            params["attn"], h, k_pages, v_pages, block_table, positions,
-            valid, layer=layer,
+        h, *pages = self.attn.apply_paged(
+            params["attn"], h, *pages, block_table, positions, valid,
+            layer=layer,
         )
         x = x + h
         h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        if self.moe is not None:
-            h, _ = self.moe.apply({"params": params["moe"], "state": {}}, h)
-        else:
-            h = self._mlp(params["mlp"], h)
-        return x + h, k_pages, v_pages
+        # Padding rows and idle slots are no tokens: they route nowhere.
+        real = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :] < valid[:, None]
+        h, counts = self._ffn_eval(params, h, token_mask=real)
+        return x + h, tuple(pages), counts
 
     def _mlp_tp_spec(self, h):
         """Overlap spec when the MLP can take the collective-matmul
@@ -594,7 +700,7 @@ class TransformerLM(Model):
             else Embedding(config.max_seq_len, config.dim)
         )
         self.blocks = [Block(config, i) for i in range(config.num_layers)]
-        self.ln_f = config.norm_cls()(config.dim)
+        self.ln_f = config.make_norm(config.dim)
         self.head = (
             None
             if config.tied_embeddings
@@ -691,22 +797,36 @@ class TransformerLM(Model):
 
     def decode_step_paged(self, params, tokens, k_pages, v_pages,
                           block_table, positions, valid):
-        """Decode/prefill chunk against an EXTERNAL paged KV pool — the
+        """:meth:`paged_step` for a model whose pool is a K and a V array:
+        ``(logits, k_pages', v_pages')``."""
+        logits, (k_pages, v_pages), _ = self.paged_step(
+            params, tokens, (k_pages, v_pages), block_table, positions, valid
+        )
+        return logits, k_pages, v_pages
+
+    def paged_step(self, params, tokens, pages, block_table, positions,
+                   valid):
+        """Decode/prefill chunk against an EXTERNAL paged pool — the
         cache is indexed by slot, not owned by the call
         (``rocket_tpu.serve``; pool layout in ``ops/paged_attention.py``).
 
         ``tokens`` (S, C) int32 — slot ``s``'s chunk occupies global
         positions ``[positions[s], positions[s]+C)`` with the first
-        ``valid[s]`` rows real; ``k_pages``/``v_pages`` are the whole pool
-        ``(L, NB, BL, Hkv*D)``; ``block_table`` (S, MB) maps slot positions
-        onto pool blocks. Returns ``(logits (S, V) of the chunk's LAST
-        position, k_pages', v_pages')`` — C=1 is the decode wave, C=chunk
-        the prefill step, one code path for both. Every layer reads and
-        writes the whole pool at its own layer coordinate: no layer is
-        sliced out or put back, so a donated pool is updated in place.
+        ``valid[s]`` rows real; ``pages`` is the whole pool, a tuple of
+        ``(L, NB, BL, lanes)`` arrays as ``config.kv_pool_lanes`` declares
+        (K and V, or one latent array); ``block_table`` (S, MB) maps slot
+        positions onto pool blocks. Returns ``(logits (S, V) of the
+        chunk's LAST position, pages', expert_pairs)`` — C=1 is the decode
+        wave, C=chunk the prefill step, one code path for both. Every
+        layer reads and writes the whole pool at its own layer coordinate:
+        no layer is sliced out or put back, so a donated pool is updated
+        in place. ``expert_pairs`` is None for a model with no routed
+        layer, else int32 ``(routed layers, experts held)``: the (token,
+        choice) pairs each held expert received in this call.
         """
         p = params
         s, c = tokens.shape
+        pages = tuple(pages)
         x = jnp.take(p["wte"]["table"], tokens, axis=0)
         if self.wpe is not None:
             pos_ids = jnp.clip(
@@ -722,20 +842,25 @@ class TransformerLM(Model):
 
             def body(carry, xs):
                 params_i, i = xs
-                return block.apply_paged(
+                x, pages, counts = block.apply_paged(
                     params_i, *carry, block_table, positions, valid, layer=i
-                ), None
+                )
+                return (x, pages), counts
 
             layers = jnp.arange(self.config.num_layers, dtype=jnp.int32)
-            (x, k_pages, v_pages), _ = jax.lax.scan(
-                body, (x, k_pages, v_pages), (p["blocks_stacked"], layers)
+            (x, pages), pairs = jax.lax.scan(
+                body, (x, pages), (p["blocks_stacked"], layers)
             )
         else:
+            pairs = []
             for i, block in enumerate(self.blocks):
-                x, k_pages, v_pages = block.apply_paged(
-                    p["blocks"][str(i)], x, k_pages, v_pages,
-                    block_table, positions, valid, layer=i,
+                x, pages, counts = block.apply_paged(
+                    p["blocks"][str(i)], x, pages, block_table, positions,
+                    valid, layer=i,
                 )
+                if counts is not None:
+                    pairs.append(counts)
+            pairs = jnp.stack(pairs) if pairs else None
 
         x = x[:, -1:]  # only the last position's logits are consumed
         x, _ = self.ln_f.apply({"params": p["ln_f"], "state": {}}, x)
@@ -745,7 +870,7 @@ class TransformerLM(Model):
             logits = jnp.einsum(
                 "btd,vd->btv", x, p["wte"]["table"].astype(x.dtype)
             )
-        return logits[:, 0], k_pages, v_pages
+        return logits[:, 0], pages, pairs
 
     def _resolve_pipe_mesh(self):
         """Pin the pipeline mesh at first trace (same rule as ring/flash

@@ -17,22 +17,29 @@ for TPU:
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from rocket_tpu.nn.layers import Dense
 from rocket_tpu.nn.module import Layer
 
 __all__ = [
+    "LatentAttention",
+    "LatentAttentionConfig",
     "MultiHeadAttention",
+    "YarnScaling",
     "apply_rope",
     "apply_rope_bthd",
     "apply_rope_offsets",
     "dot_product_attention",
     "grouped_dot_product_attention",
     "resolve_impl",
+    "yarn_inv_freq",
+    "yarn_mscale",
 ]
 
 
@@ -162,9 +169,87 @@ def _rope_rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def _rope_trig(t_len: int, half: int, offset, base: float):
-    """(cos, sin), each (T, half), in f32."""
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN context extension (Peng et al. 2023, arXiv 2309.00071) as a
+    published ``rope_scaling`` block of ``type`` ``yarn`` states it."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class LatentAttentionConfig:
+    """The published sizes of a latent-attention (MLA) layer; see
+    :class:`LatentAttention`."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    yarn: Optional[YarnScaling] = None
+
+    @property
+    def cache_lanes(self) -> int:
+        """Values cached per token and layer: ``c_kv | k_rope``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_lanes(self) -> int:
+        """Width of the pool array that holds them: ``cache_lanes`` rounded
+        up to whole 128-lane tiles, the rest zero. An array whose minor
+        dimension is no multiple of 128 (576 here) is given another
+        dimension as its minor one by the TPU compiler, and each program
+        then relayouts the WHOLE pool for the kernel and back (seen in a
+        compile for a described v5e: two copies of the pool a wave;
+        ``tests/test_tpu_compile.py`` guards it). The tiled layout pads
+        576 lanes to 640 in HBM anyway, so the zeros cost no memory."""
+        return -(-self.cache_lanes // 128) * 128
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 * mscale * ln(factor) + 1`` (1 where nothing is extended)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, yarn: YarnScaling) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies under YaRN, float32: a blend of
+    ``base^(-2i/dim)`` (kept where a dimension turns more than
+    ``beta_fast`` times over the original context) and the same over
+    ``factor`` (where it turns fewer than ``beta_slow`` times), by a
+    linear ramp between the two correction dimensions. Host arithmetic
+    in float64: the frequencies are constants of the program."""
+    half = dim // 2
+    plain = base ** (-np.arange(half, dtype=np.float64) / half)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(
+            yarn.original_max_position_embeddings / (rotations * 2 * math.pi)
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0, 1)
+    return (plain / yarn.factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def _rope_freqs(half: int, base: float, inv_freq=None):
+    if inv_freq is not None:
+        return jnp.asarray(inv_freq, jnp.float32)
+    return base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def _rope_trig(t_len: int, half: int, offset, base: float, inv_freq=None):
+    """(cos, sin), each (T, half), in f32. ``inv_freq`` (``half`` values,
+    e.g. :func:`yarn_inv_freq`) replaces ``base^(-i/half)``."""
+    freqs = _rope_freqs(half, base, inv_freq)
     pos = offset + jnp.arange(t_len)
     angles = pos[:, None].astype(jnp.float32) * freqs[None, :]
     return jnp.cos(angles), jnp.sin(angles)
@@ -191,23 +276,26 @@ def apply_rope_bthd(x: jax.Array, offset=0, base: float = 10000.0) -> jax.Array:
 
 
 def apply_rope_offsets(x: jax.Array, offsets: jax.Array,
-                       base: float = 10000.0) -> jax.Array:
+                       base: float = 10000.0, inv_freq=None,
+                       trig_scale: float = 1.0) -> jax.Array:
     """:func:`apply_rope_bthd` with a PER-ROW position offset: ``x`` is
     feature-major (B, T, H, D) and row ``b``'s positions are
     ``offsets[b] .. offsets[b]+T`` — the paged-decode layout, where every
     serving slot sits at its own sequence position. Same rotate-half
-    convention and f32 trig."""
+    convention and f32 trig; ``inv_freq`` as in :func:`_rope_trig`,
+    ``trig_scale`` multiplies cos and sin (YaRN's ``mscale`` ratio)."""
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = _rope_freqs(half, base, inv_freq)
     pos = (
         offsets[:, None].astype(jnp.float32)
         + jnp.arange(x.shape[1], dtype=jnp.float32)[None, :]
     )
     angles = pos[..., None] * freqs                      # (B, T, half)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if trig_scale != 1.0:
+        cos, sin = cos * trig_scale, sin * trig_scale
     # (B, T, 1, half) — broadcasts over the H dim.
-    return _rope_rotate(
-        x, jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
-    )
+    return _rope_rotate(x, cos[:, :, None, :], sin[:, :, None, :])
 
 
 def grouped_dot_product_attention(
@@ -708,3 +796,267 @@ class MultiHeadAttention(Layer):
             else ""
         )
         return f"MultiHeadAttention(d={self.features}, h={self.num_heads}{kv})"
+
+
+class LatentAttention(Layer):
+    """Multi-head latent attention (MLA; DeepSeek-V2, arXiv 2405.04434,
+    §2.1, with the decoupled rotary key) — the attention whose cache is
+    ONE low-rank latent per token instead of K and V per head.
+
+    For hidden ``h_t``: ``c_q = RMSNorm(W_dq h_t)``; ``[q_nope | q_rope] =
+    W_uq c_q`` per head; ``[c_kv | k_r] = W_dkv h_t``; ``c_kv =
+    RMSNorm(c_kv)``; ``k_rope = RoPE(k_r)`` — one per token, shared by all
+    heads; ``[k_nope | v] = W_ukv c_kv`` per head; ``score = (q_nope.k_nope
+    + RoPE(q_rope).k_rope) * scale``; causal softmax in float32; ``o = W_o
+    concat_heads(softmax . v)``. ``scale`` is ``(nope + rope)^-0.5 * m^2``
+    with YaRN's ``m = 0.1 * mscale_all_dim * ln(factor) + 1``.
+
+    **What is cached** per token and layer: ``c_kv`` after its norm and
+    ``k_rope`` after RoPE, side by side — ``cache_lanes`` values in ONE
+    pool array of ``pool_lanes`` (whole 128-lane tiles, zeros behind the
+    values; ``LatentAttentionConfig.pool_lanes`` says why), no V
+    (``serve/kv_pool.py``).
+
+    Two forms of the same numbers. A decode wave (one query row a slot)
+    runs **absorbed**: ``q_abs = q_nope . W_uk`` per head carries the query
+    into the latent's space, ``score = [q_abs | q_rope] . [c_kv | k_rope]``,
+    ``o_head = (softmax . c_kv) . W_uv`` — the pool is read once for all
+    heads, by the paged kernel under the name ``mla_decode``
+    (``ops/paged_attention.py``). A prefill chunk runs **non-absorbed**:
+    the slot's latent pages are gathered a few pages at a time, up-projected
+    to ``k_nope`` and ``v`` and folded into a running softmax, over the
+    LIVE context only (a loop whose trip count is a traced value); per
+    attended row that is 3.4 times fewer operations than the absorbed
+    form, which pays ``kv_lora_rank`` lanes a head instead of ``nope``.
+
+    Eval semantics only (no dropout, no training path yet)."""
+
+    def __init__(self, features: int, num_heads: int,
+                 config: LatentAttentionConfig, *,
+                 rope_base: float = 10000.0, norm_eps: float = 1e-6):
+        from rocket_tpu.nn.layers import RMSNorm
+
+        self.features = features
+        self.num_heads = num_heads
+        self.config = config
+        q_lora_rank = config.q_lora_rank
+        self.kv_lora_rank = kv_lora_rank = config.kv_lora_rank
+        self.nope = config.qk_nope_head_dim
+        self.rope = config.qk_rope_head_dim
+        self.v_dim = v_head_dim = config.v_head_dim
+        self.rope_base = rope_base
+        yarn = config.yarn
+        h = num_heads
+        self.q_a = Dense(features, q_lora_rank, use_bias=False)
+        self.q_norm = RMSNorm(q_lora_rank, eps=norm_eps)
+        self.q_b = Dense(q_lora_rank, h * (self.nope + self.rope), use_bias=False)
+        self.kv_a = Dense(features, kv_lora_rank + self.rope, use_bias=False)
+        self.kv_norm = RMSNorm(kv_lora_rank, eps=norm_eps)
+        self.kv_b = Dense(kv_lora_rank, h * (self.nope + v_head_dim), use_bias=False)
+        self.proj = Dense(h * v_head_dim, features, use_bias=False)
+        self.scale = float(self.nope + self.rope) ** -0.5
+        self.inv_freq = None
+        self.trig_scale = 1.0
+        if yarn is not None:
+            self.inv_freq = yarn_inv_freq(self.rope, rope_base, yarn)
+            m = yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+            self.scale *= m * m
+            self.trig_scale = yarn_mscale(yarn.factor, yarn.mscale) / m
+
+    def init_params(self, key):
+        names = ("q_a", "q_norm", "q_b", "kv_a", "kv_norm", "kv_b", "proj")
+        keys = jax.random.split(key, len(names))
+        return {
+            n: getattr(self, n).init(k)["params"] for n, k in zip(names, keys)
+        }
+
+    # -- the two projections every form shares ------------------------------
+
+    def _sub(self, name, p, x):
+        return getattr(self, name).apply({"params": p[name], "state": {}}, x)[0]
+
+    def _rotate(self, x, positions):
+        return apply_rope_offsets(
+            x, positions, self.rope_base, self.inv_freq, self.trig_scale
+        )
+
+    def _down(self, p, x, positions):
+        """``x`` (S, C, D) at per-slot ``positions`` (S,) -> ``q_nope``
+        (S, C, H, nope), ``q_rope`` (S, C, H, rope) rotated, and the rows
+        to cache ``[c_kv | k_rope | 0]`` (S, C, pool_lanes)."""
+        s, c, _ = x.shape
+        with jax.named_scope("mla/down"):
+            q = self._sub("q_b", p, self._sub("q_norm", p, self._sub("q_a", p, x)))
+            q = q.reshape(s, c, self.num_heads, self.nope + self.rope)
+            q_rope = self._rotate(q[..., self.nope:], positions)
+            kv = self._sub("kv_a", p, x)
+            c_kv = self._sub("kv_norm", p, kv[..., :self.kv_lora_rank])
+            k_rope = self._rotate(
+                kv[..., None, self.kv_lora_rank:], positions
+            )[:, :, 0]
+            latent = self._to_pool_lanes(
+                jnp.concatenate([c_kv, k_rope], axis=-1)
+            )
+        return q[..., :self.nope], q_rope, latent
+
+    def _to_pool_lanes(self, x):
+        pad = self.config.pool_lanes - x.shape[-1]
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+    def _k_rope(self, rows):
+        return rows[..., self.kv_lora_rank:self.kv_lora_rank + self.rope]
+
+    def _up_weights(self, p, dtype):
+        """``W_ukv`` as ``(kv_lora_rank, H, nope + v)``: ``[..., :nope]`` is
+        ``W_uk``, the rest ``W_uv``."""
+        return p["kv_b"]["w"].astype(dtype).reshape(
+            self.kv_lora_rank, self.num_heads, self.nope + self.v_dim
+        )
+
+    def _out(self, p, heads):
+        with jax.named_scope("mla/out"):
+            return self._sub("proj", p, heads)
+
+    # -- whole sequence ------------------------------------------------------
+
+    def apply(self, variables, x, *, mode="train", rng=None):
+        """``x`` (B, T, D) at positions ``0..T``: plain non-absorbed causal
+        attention, nothing cached."""
+        if mode == "train":
+            raise NotImplementedError(
+                "LatentAttention has no training path (no dropout, no "
+                "flash kernel for 192/128-lane heads) — eval only"
+            )
+        p = variables["params"]
+        b, t, _ = x.shape
+        q_nope, q_rope, latent = self._down(p, x, jnp.zeros((b,), jnp.int32))
+        with jax.named_scope("mla/attend"):
+            kv = jnp.einsum(
+                "btk,khe->bthe", latent[..., :self.kv_lora_rank],
+                self._up_weights(p, x.dtype),
+            )
+            logits = (
+                jnp.einsum("bqhn,bthn->bhqt", q_nope, kv[..., :self.nope],
+                           preferred_element_type=jnp.float32)
+                + jnp.einsum("bqhr,btr->bhqt", q_rope,
+                             self._k_rope(latent),
+                             preferred_element_type=jnp.float32)
+            ) * self.scale
+            causal = jnp.tril(jnp.ones((t, t), bool))
+            weights = jax.nn.softmax(
+                jnp.where(causal, logits, -jnp.inf), axis=-1
+            )
+            heads = jnp.einsum(
+                "bhqt,bthv->bqhv", weights.astype(x.dtype), kv[..., self.nope:]
+            ).reshape(b, t, self.num_heads * self.v_dim)
+        return self._out(p, heads), variables["state"]
+
+    # -- against the paged latent pool ---------------------------------------
+
+    def absorb(self, p, q_nope, q_rope):
+        """``[q_nope . W_uk | q_rope | 0]``: the queries in the latent's
+        own space, ``(..., H, pool_lanes)``."""
+        with jax.named_scope("mla/absorb"):
+            w_uk = self._up_weights(p, q_nope.dtype)[..., :self.nope]
+            q_abs = jnp.einsum("...hn,khn->...hk", q_nope, w_uk)
+            return self._to_pool_lanes(
+                jnp.concatenate([q_abs, q_rope], axis=-1)
+            )
+
+    def unabsorb(self, p, latent_out):
+        """``(softmax . c_kv) . W_uv`` per head: ``(..., H, kv_lora_rank)``
+        -> ``(..., H * v)``."""
+        w_uv = self._up_weights(p, latent_out.dtype)[..., self.nope:]
+        out = jnp.einsum("...hk,khv->...hv", latent_out, w_uv)
+        return out.reshape(*out.shape[:-2], self.num_heads * self.v_dim)
+
+    def apply_paged(self, params, x, pages, block_table, positions, valid,
+                    layer=0, interpret=None):
+        """Decode wave or prefill chunk against the latent pool: ``x``
+        (S, C, D) as in ``MultiHeadAttention.apply_paged``; ``pages``
+        ``(L, NB, BL, pool_lanes)``, the WHOLE pool, in and out, written
+        in place at ``(layer, block, row)``. Returns ``(out, pages')``."""
+        from rocket_tpu.ops.paged_attention import (
+            paged_latent_decode,
+            write_pages,
+        )
+
+        s, c, _ = x.shape
+        q_nope, q_rope, latent = self._down(params, x, positions)
+        pages = write_pages(
+            pages, block_table, positions, valid, latent, layer=layer
+        )
+        if c == 1:
+            q = self.absorb(params, q_nope[:, 0], q_rope[:, 0])
+            with jax.named_scope("mla/attend"):
+                out = paged_latent_decode(
+                    q, pages, block_table, positions, layer=layer,
+                    d_v=self.kv_lora_rank, scale=self.scale,
+                    interpret=interpret,
+                )
+            with jax.named_scope("mla/absorb"):
+                heads = self.unabsorb(params, out)[:, None]
+        else:
+            with jax.named_scope("mla/attend"):
+                heads = self._attend_chunk(
+                    params, q_nope, q_rope, pages, block_table, positions,
+                    layer,
+                )
+        return self._out(params, heads), pages
+
+    def _attend_chunk(self, p, q_nope, q_rope, pages, block_table,
+                      positions, layer):
+        """Non-absorbed attention of a chunk over the slot's LIVE pages:
+        a few pages at a time are gathered, up-projected and folded into a
+        running softmax (float32 statistics). The loop runs as far as the
+        furthest query sees, a traced bound, so a chunk early in a long
+        pool pays for its own context and not for ``max_blocks_per_seq``.
+        Query row ``i`` of slot ``s`` sees key positions ``<= positions[s]
+        + i`` (its own row was scattered first). Returns (S, C, H*v)."""
+        s, c, h, _ = q_nope.shape
+        bl, mb = int(pages.shape[2]), int(block_table.shape[1])
+        ppc = math.gcd(mb, max(1, 512 // bl))      # pages a step
+        tk = ppc * bl
+        w_ukv = self._up_weights(p, q_nope.dtype)
+        q_pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+        steps = jnp.minimum((jnp.max(positions) + c + tk - 1) // tk, mb // ppc)
+
+        def body(j, carry):
+            m, l, acc = carry
+            ids = jax.lax.dynamic_slice_in_dim(block_table, j * ppc, ppc, 1)
+            rows = pages[layer, ids].reshape(s, tk, pages.shape[3])
+            kv = jnp.einsum(
+                "stk,khe->sthe", rows[..., :self.kv_lora_rank], w_ukv
+            )
+            logits = (
+                jnp.einsum("schn,sthn->shct", q_nope, kv[..., :self.nope],
+                           preferred_element_type=jnp.float32)
+                + jnp.einsum("schr,str->shct", q_rope, self._k_rope(rows),
+                             preferred_element_type=jnp.float32)
+            ) * self.scale
+            key_pos = j * tk + jnp.arange(tk, dtype=jnp.int32)
+            seen = key_pos[None, None, :] <= q_pos[:, :, None]   # (S, C, tk)
+            logits = jnp.where(seen[:, None], logits, -1e30)
+            m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.where(seen[:, None], jnp.exp(logits - m_new[..., None]), 0.0)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "shct,sthv->shcv", pr.astype(kv.dtype), kv[..., self.nope:],
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l * alpha + jnp.sum(pr, axis=-1), acc
+
+        init = (
+            jnp.full((s, h, c), -1e30, jnp.float32),
+            jnp.zeros((s, h, c), jnp.float32),
+            jnp.zeros((s, h, c, self.v_dim), jnp.float32),
+        )
+        _, l, acc = jax.lax.fori_loop(0, steps, body, init)
+        out = (acc / l[..., None]).astype(q_nope.dtype)        # (S, H, C, v)
+        return jnp.moveaxis(out, 1, 2).reshape(s, c, h * self.v_dim)
+
+    def __repr__(self):
+        return (
+            f"LatentAttention(d={self.features}, h={self.num_heads}, "
+            f"latent={self.kv_lora_rank}+{self.rope})"
+        )

@@ -22,13 +22,17 @@ surfaces it in the output batch for the objective to add.
 
 from __future__ import annotations
 
+import functools
+from dataclasses import dataclass
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from rocket_tpu.nn.layers import Dense
 from rocket_tpu.nn.module import Layer
 
-__all__ = ["MoE"]
+__all__ = ["MoE", "RoutedExperts", "RoutedExpertsConfig", "route_sigmoid_grouped"]
 
 
 def _gmm_config(m: int, k: int, n: int, dtype) -> dict:
@@ -80,16 +84,60 @@ def _grouped_matmul(lhs, rhs, group_sizes):
     """
     m, k = lhs.shape
     _, _, n = rhs.shape
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu and k % 128 == 0 and n % 128 == 0 and m % 8 == 0:
+    if _megablox_fits(m, k, n):
         from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
 
         return gmm(lhs, rhs, group_sizes, lhs.dtype,
                    _gmm_tiling(m, k, n, lhs.dtype))
+    return _ragged_dot(lhs, rhs, group_sizes)
+
+
+def _on_tpu() -> bool:
+    """Whether this process's default backend is a TPU."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def _megablox_fits(m: int, k: int, n: int) -> bool:
+    """Whether the grouped matmul is the megablox kernel: on a TPU, at
+    shapes its tiles divide."""
+    return _on_tpu() and k % 128 == 0 and n % 128 == 0 and m % 8 == 0
+
+
+def _ragged_dot(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot(
         lhs.astype(jnp.float32), rhs.astype(jnp.float32), group_sizes,
         preferred_element_type=jnp.float32,
     ).astype(lhs.dtype)
+
+
+@functools.cache
+def _named_gmm(name: str):
+    """The megablox ``gmm`` under a jit of its own called ``name``: the
+    Mosaic custom call takes the innermost jit's name, so the profiler's
+    events read ``<name>.<n> custom-call`` and a per-layer metric can sum
+    them (megablox itself offers no ``name=``)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    def call(lhs, rhs, group_sizes, tiling):
+        return gmm.__wrapped__(lhs, rhs, group_sizes, lhs.dtype, tiling)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, static_argnames=("tiling",))
+
+
+def _held_grouped_matmul(name, lhs, rhs, group_sizes):
+    """``lhs`` rows ``[0, sum(group_sizes))`` grouped by ``group_sizes``
+    times ``rhs[g]``; rows past the last group are NOT computed (on the
+    TPU they are whatever the buffer held: the caller masks them). Only
+    the row tiles that hold a group's rows are visited, and only the
+    weights of a group that has rows are read."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    if _megablox_fits(m, k, n):
+        return _named_gmm(name)(
+            lhs, rhs, group_sizes, tiling=_gmm_tiling(m, k, n, lhs.dtype)
+        )
+    return _ragged_dot(lhs, rhs, group_sizes)
 
 
 class MoE(Layer):
@@ -408,4 +456,187 @@ class MoE(Layer):
         return (
             f"MoE(d={self.dim}, h={self.hidden}, E={self.num_experts}, "
             f"k={self.top_k})"
+        )
+
+
+# -- sigmoid, group-limited, bias-corrected routing over a held share --------
+
+
+@dataclass(frozen=True)
+class RoutedExpertsConfig:
+    """The routed FFN of a DeepSeek-V3-style layer (arXiv 2412.19437,
+    §2.1.2 and its auxiliary-loss-free balancing) as ONE chip of an
+    expert-parallel deployment holds it."""
+
+    num_experts: int            # the router's outputs (all chips' experts)
+    top_k: int
+    hidden: int                 # one routed expert's width
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    #: Width of the shared expert (``n_shared_experts * hidden``); 0 = none.
+    shared_hidden: int = 0
+    #: ``(offset, count)``: the experts THIS chip holds, ``offset ..
+    #: offset + count``. The router scores all ``num_experts``; only the
+    #: held experts' part of the sum is computed here. None = all.
+    experts_held: Optional[tuple] = None
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.num_experts)
+
+
+def route_sigmoid_grouped(scores_logits, bias, cfg: RoutedExpertsConfig):
+    """``(weights (N, k) f32, experts (N, k) int32)`` from router logits
+    ``(N, E)`` in float32: ``s = sigmoid(logits)``; ``s' = s + bias``
+    decides the SELECTION only — each of ``n_group`` groups is scored by
+    the sum of its two best ``s'``, the best ``topk_group`` groups stay,
+    the ``top_k`` best ``s'`` within them are chosen; the WEIGHTS are the
+    unbiased ``s`` of the chosen, normalised over all ``top_k`` (held here
+    or not) and times ``routed_scaling_factor``."""
+    n, e = scores_logits.shape
+    s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
+    biased = s + bias.astype(jnp.float32)[None, :]
+    if cfg.n_group > 1:
+        per = e // cfg.n_group
+        grouped = biased.reshape(n, cfg.n_group, per)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, cfg.topk_group)     # (N, kept)
+        kept = jnp.zeros((n, cfg.n_group), bool).at[
+            jnp.arange(n)[:, None], keep
+        ].set(True)
+        biased = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    _, experts = jax.lax.top_k(biased, cfg.top_k)
+    weights = jnp.take_along_axis(s, experts, axis=1)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scaling_factor, experts.astype(jnp.int32)
+
+
+class RoutedExperts(Layer):
+    """One chip's share of a routed expert layer: gated, bias-free experts
+    ``E(x) = W_down(silu(W_gate x) * W_up x)``, the router of
+    :func:`route_sigmoid_grouped`, a shared expert every chip computes
+    alike, and ``experts_held``::
+
+        y = sum_{i chosen and held} w_i * E_i(x) + E_shared(x)
+
+    What the absent experts would add is left out; nothing stands in for
+    the exchange. Dropless: the (token, choice) pairs routed to a held
+    expert are sorted to the front by expert and run through two grouped
+    matmuls (``moe_gmm_gate_up``, ``moe_gmm_down``) whose work follows the
+    pairs that are there — a pair routed to an absent expert costs no
+    matmul row and no token is ever dropped. ``apply`` returns ``(y,
+    counts)``: ``counts`` (held,) int32, the pairs each held expert got.
+    ``token_mask`` (the shape of ``x`` without its last axis) marks the
+    rows that are tokens: the pairs of a padding row or an idle slot are
+    treated as absent too, and its output is only the shared expert's.
+
+    Parameters: ``router`` ``{w (D, E), bias (E,)}`` (the bias is
+    ``e_score_correction_bias``), ``experts`` ``{w_gate_up (held, D, 2H),
+    w_down (held, H, D)}`` (gate and up side by side: one matmul),
+    ``shared`` ``{w_gate, w_up, w_down}``."""
+
+    def __init__(self, dim: int, config: RoutedExpertsConfig):
+        c = config
+        offset, count = c.held
+        if not (0 <= offset and offset + count <= c.num_experts and count > 0):
+            raise ValueError(
+                f"RoutedExperts: experts_held {c.held} outside "
+                f"[0, {c.num_experts})"
+            )
+        if c.num_experts % c.n_group or not 1 <= c.topk_group <= c.n_group:
+            raise ValueError(
+                f"RoutedExperts: {c.num_experts} experts in {c.n_group} "
+                f"groups, {c.topk_group} kept"
+            )
+        self.dim = dim
+        self.config = c
+
+    def init_params(self, key):
+        c, d = self.config, self.dim
+        held = c.held[1]
+        ks = jax.random.split(key, 6)
+        normal = jax.random.normal
+        params = {
+            "router": {
+                "w": normal(ks[0], (d, c.num_experts)) * d ** -0.5,
+                "bias": jnp.zeros((c.num_experts,)),
+            },
+            "experts": {
+                "w_gate_up": normal(ks[1], (held, d, 2 * c.hidden)) * d ** -0.5,
+                "w_down": normal(ks[2], (held, c.hidden, d)) * c.hidden ** -0.5,
+            },
+        }
+        if c.shared_hidden:
+            hs = c.shared_hidden
+            params["shared"] = {
+                "w_gate": normal(ks[3], (d, hs)) * d ** -0.5,
+                "w_up": normal(ks[4], (d, hs)) * d ** -0.5,
+                "w_down": normal(ks[5], (hs, d)) * hs ** -0.5,
+            }
+        return params
+
+    def apply(self, variables, x, *, mode="eval", rng=None, token_mask=None):
+        if mode == "train":
+            raise NotImplementedError(
+                "RoutedExperts has no training path (no balance term for "
+                "its router): eval and serving only"
+            )
+        p, c = variables["params"], self.config
+        shape = x.shape
+        x2 = x.reshape(-1, shape[-1])
+        n, k = x2.shape[0], c.top_k
+        offset, held = c.held
+        with jax.named_scope("moe/route"):
+            # Float32 end to end: a bfloat16 score within rounding of the
+            # k-th/(k+1)-th boundary picks another expert.
+            logits = jnp.dot(
+                x2.astype(jnp.float32), p["router"]["w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            weights, experts = route_sigmoid_grouped(
+                logits, p["router"]["bias"], c
+            )
+            # Held pairs to the front, by expert; absent pairs behind them.
+            local = experts.reshape(n * k) - offset
+            here = (local >= 0) & (local < held)
+            if token_mask is not None:
+                here &= jnp.repeat(token_mask.reshape(n), k)
+            group = jnp.where(here, local, held)
+            order = jnp.argsort(group, stable=True)
+            counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+            token = (order // k).astype(jnp.int32)
+            gate = weights.reshape(n * k)[order]
+            live = jnp.arange(n * k, dtype=jnp.int32) < jnp.sum(counts)
+        with jax.named_scope("moe/experts"):
+            ex = p["experts"]
+            h = _held_grouped_matmul(
+                "moe_gmm_gate_up", x2[token], ex["w_gate_up"].astype(x.dtype),
+                counts,
+            )
+            h = jax.nn.silu(h[:, :c.hidden]) * h[:, c.hidden:]
+            out = _held_grouped_matmul(
+                "moe_gmm_down", h, ex["w_down"].astype(x.dtype), counts
+            )
+            out = jnp.where(
+                live[:, None], out.astype(jnp.float32) * gate[:, None], 0.0
+            )
+            y = jnp.zeros((n, shape[-1]), jnp.float32).at[token].add(out)
+        if c.shared_hidden:
+            with jax.named_scope("moe/shared"):
+                sh = p["shared"]
+                dt = x.dtype
+                hid = jax.nn.silu(x2 @ sh["w_gate"].astype(dt)) * (
+                    x2 @ sh["w_up"].astype(dt)
+                )
+                y = y + (hid @ sh["w_down"].astype(dt)).astype(jnp.float32)
+        return y.astype(x.dtype).reshape(shape), counts
+
+    def __repr__(self):
+        c = self.config
+        return (
+            f"RoutedExperts(d={self.dim}, h={c.hidden}, E={c.num_experts}, "
+            f"k={c.top_k}, held={c.held})"
         )

@@ -75,7 +75,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "write_pages",
     "write_kv_pages",
+    "paged_latent_decode",
     "paged_attention",
     "paged_gather",
     "paged_decode_supported",
@@ -87,22 +89,21 @@ _NEG_INF = -1e30
 _SUBLANE = {4: 8, 2: 16, 1: 32}
 
 
-def write_kv_pages(k_pages, v_pages, block_table, positions, valid,
-                   k_new, v_new, *, layer=0):
-    """Scatter one chunk's K/V rows into layer ``layer`` of the paged pool.
+def write_pages(pages, block_table, positions, valid, rows, *, layer=0):
+    """Scatter one chunk's rows into layer ``layer`` of ONE pool array.
 
-    ``k_pages``/``v_pages`` ``(L, NB, BL, Hkv*D)``; ``block_table`` ``(S, MB)``
-    int32 block ids (0 = the reserved trash block); ``positions`` ``(S,)``
-    int32 — slot ``s``'s chunk occupies global positions
-    ``[positions[s], positions[s] + C)``; ``valid`` ``(S,)`` int32 — only the
-    first ``valid[s]`` rows of the chunk are real (the rest are padding and
-    land in the trash block); ``k_new``/``v_new`` ``(S, C, Hkv, D)``.
-    Returns the updated ``(k_pages, v_pages)``: one scatter of whole
-    ``Hkv*D`` rows at ``(layer, block, row)`` each, in place where the
-    pool is donated.
-    """
-    bl = k_pages.shape[2]
-    s, c = k_new.shape[0], k_new.shape[1]
+    ``pages`` ``(L, NB, BL, lanes)``; ``block_table`` ``(S, MB)`` int32 block
+    ids (0 = the reserved trash block); ``positions`` ``(S,)`` int32 — slot
+    ``s``'s chunk occupies global positions ``[positions[s], positions[s] +
+    C)``; ``valid`` ``(S,)`` int32 — only the first ``valid[s]`` rows of the
+    chunk are real (the rest are padding and land in the trash block);
+    ``rows`` ``(S, C, ...)`` with ``lanes`` values a row. Returns the
+    updated array: one scatter of whole rows at ``(layer, block, row)``, in
+    place where the pool is donated. What a row holds is the layer's own
+    business: K or V of every kv head side by side, or a latent
+    (``nn.attention.LatentAttention``)."""
+    bl = pages.shape[2]
+    s, c = rows.shape[0], rows.shape[1]
     pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]  # (S, C)
     slot = jnp.clip(pos // bl, 0, block_table.shape[1] - 1)
     block = jnp.take_along_axis(block_table, slot, axis=1)              # (S, C)
@@ -111,12 +112,20 @@ def write_kv_pages(k_pages, v_pages, block_table, positions, valid,
     # never allocated, so collisions there are harmless).
     block = jnp.where(ok, block, 0).reshape(-1)
     row = jnp.where(ok, pos % bl, 0).reshape(-1)
-    k_pages = k_pages.at[layer, block, row].set(
-        k_new.astype(k_pages.dtype).reshape(s * c, -1)
+    return pages.at[layer, block, row].set(
+        rows.astype(pages.dtype).reshape(s * c, -1)
     )
-    v_pages = v_pages.at[layer, block, row].set(
-        v_new.astype(v_pages.dtype).reshape(s * c, -1)
-    )
+
+
+def write_kv_pages(k_pages, v_pages, block_table, positions, valid,
+                   k_new, v_new, *, layer=0):
+    """:func:`write_pages` for a K and a V array: ``k_new``/``v_new``
+    ``(S, C, Hkv, D)`` into ``k_pages``/``v_pages`` ``(L, NB, BL, Hkv*D)``.
+    Returns the updated ``(k_pages, v_pages)``."""
+    k_pages = write_pages(k_pages, block_table, positions, valid, k_new,
+                          layer=layer)
+    v_pages = write_pages(v_pages, block_table, positions, valid, v_new,
+                          layer=layer)
     return k_pages, v_pages
 
 
@@ -160,9 +169,8 @@ def _default_block_kv(block_len: int, itemsize: int = 4) -> int:
     return block_len
 
 
-def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_kv, sub, mb, scale,
-                   h_kv, g, d):
+def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, *refs,
+                   block_kv, sub, mb, scale, h_kv, g, d, d_v, shared):
     """One (slot, kv-tile) grid step of the fused paged decode.
 
     Streams a ``(block_kv, Hkv*D)`` tile of the mapped page — every kv
@@ -173,8 +181,17 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     The new K/V row was scattered into the pool BEFORE the kernel, so
     key positions ``<= pos`` (the query's own row included) are all
     read from the pool — exact prefix semantics, one code path. All ops
-    stay 2D per head (Mosaic rejects 3D shape casts)."""
+    stay 2D per head (Mosaic rejects 3D shape casts).
+
+    ``shared``: there is no V array — a row's first ``d_v`` lanes ARE its
+    value (a latent pool: one ``d``-lane row per token, read once for
+    every query head), so the tile that gave the scores gives the values
+    too and the pool is streamed once."""
     del layer_ref, table_ref  # consumed by the index maps
+    if shared:
+        v_ref, (o_ref, m_ref, l_ref, acc_ref) = k_ref, refs
+    else:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[i]
@@ -194,7 +211,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             rows = slice(h * g, (h + 1) * g)
             q = q_ref[0, rows, :]                      # (g, D)
             k = k_ref[0, 0, :, h * d:(h + 1) * d]      # (block_kv, D)
-            v = v_ref[0, 0, :, h * d:(h + 1) * d]
+            v = v_ref[0, 0, :, h * d:h * d + d_v]
             s_ij = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -225,10 +242,19 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
-                         *, layer=0, block_kv: int, interpret: bool):
+                         *, layer=0, block_kv: int, interpret: bool,
+                         scale: Optional[float] = None,
+                         d_v: Optional[int] = None,
+                         name: str = "paged_decode"):
     """The fused gather+attend for one decode wave: ``q`` (S, Hq, D),
     pool/table/positions/layer as in :func:`paged_attention` (new rows
-    already scattered). Returns ``out`` (S, Hq, D).
+    already scattered). Returns ``out`` (S, Hq, d_v).
+
+    ``v_pages=None`` is the latent pool (:func:`paged_latent_decode`): ONE
+    kv "head" of ``D`` = the array's whole lane axis for all ``Hq`` query
+    heads, values = the first ``d_v`` lanes of the same rows, ``scale``
+    given by the caller. With a V array ``d_v`` is ``D`` and ``scale``
+    ``1/sqrt(D)`` — one kernel body for both.
 
     Mosaic wants the last two dims of every block divisible by the
     (sublane, 128) tile or equal to the array's own: a page tile is
@@ -242,11 +268,13 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
     lowering whenever Hkv > 1 or g < 8."""
     s, hq, d = q.shape
     _, _, bl, hd = k_pages.shape
+    shared = v_pages is None
     h_kv = hd // d
     mb = block_table.shape[1]
     g = hq // h_kv
     sub = bl // block_kv
-    scale = 1.0 / math.sqrt(d)
+    d_v = d if d_v is None else int(d_v)
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
 
     def q_map(i, j, layer_ref, table_ref, pos_ref):
         del j, layer_ref, table_ref, pos_ref
@@ -259,36 +287,75 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
         # tiled at block_kv rows, so the within-page tile is its index.
         return (layer_ref[0], table_ref[i * mb + j // sub], j % sub, 0)
 
+    page_spec = pl.BlockSpec((1, 1, block_kv, hd), page_map)
+    pools = (k_pages,) if shared else (k_pages, v_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s, mb * sub),
-        in_specs=[
-            pl.BlockSpec((1, hq, d), q_map),
-            pl.BlockSpec((1, 1, block_kv, hd), page_map),
-            pl.BlockSpec((1, 1, block_kv, hd), page_map),
-        ],
-        out_specs=pl.BlockSpec((1, hq, d), q_map),
+        in_specs=[pl.BlockSpec((1, hq, d), q_map)] + [page_spec] * len(pools),
+        out_specs=pl.BlockSpec((1, hq, d_v), q_map),
         scratch_shapes=[
             pltpu.VMEM((hq, 128), jnp.float32),   # running max (lane-bcast)
             pltpu.VMEM((hq, 128), jnp.float32),   # running denom
-            pltpu.VMEM((hq, d), jnp.float32),     # unnormalized accumulator
+            pltpu.VMEM((hq, d_v), jnp.float32),   # unnormalized accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(
             _decode_kernel, block_kv=block_kv, sub=sub, mb=mb, scale=scale,
-            h_kv=h_kv, g=g, d=d,
+            h_kv=h_kv, g=g, d=d, d_v=d_v, shared=shared,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, hq, d_v), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="paged_decode",
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       block_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(positions, jnp.int32), q, k_pages, v_pages)
+      jnp.asarray(positions, jnp.int32), q, *pools)
+
+
+def paged_latent_decode(q, pages, block_table, positions, *, layer=0,
+                        d_v: int, scale: float,
+                        block_kv: Optional[int] = None,
+                        interpret: Optional[bool] = None):
+    """One decode wave of latent attention (MLA, absorbed form) against
+    ONE pool array: ``q`` ``(S, Hq, Dk)`` — every query head already
+    carried into the latent's own space, its rotary part behind it —
+    ``pages`` ``(L, NB, BL, Dk)`` holding one ``Dk``-lane row per token
+    (the new rows already scattered, :func:`write_pages`). Scores are
+    ``q . row * scale`` over all ``Dk`` lanes, values the first ``d_v``
+    lanes of the SAME rows. Returns ``(S, Hq, d_v)``.
+
+    Where the fused kernel can run (a TPU, or ``interpret=True``) it is
+    :func:`_paged_decode_pallas` with no V array, under the Pallas name
+    ``mla_decode``: each live page is streamed once for all ``Hq`` heads.
+    Elsewhere the slot's pages are gathered and attended in XLA."""
+    bl = int(pages.shape[2])
+    itemsize = jnp.dtype(pages.dtype).itemsize
+    on_cpu = _on_cpu()
+    if paged_decode_supported(bl, q.shape[-1], itemsize) and (
+        not on_cpu or interpret
+    ):
+        return _paged_decode_pallas(
+            q, pages, None, block_table, positions, layer=layer,
+            block_kv=int(block_kv or _default_block_kv(bl, itemsize)),
+            interpret=on_cpu or bool(interpret), scale=scale, d_v=d_v,
+            name="mla_decode",
+        )
+    ctx = paged_gather(pages, block_table, layer=layer)      # (S, T, Dk)
+    logits = jnp.einsum(
+        "shd,std->sht", q, ctx, preferred_element_type=jnp.float32
+    ) * scale
+    seen = jnp.arange(ctx.shape[1], dtype=jnp.int32)[None, :] \
+        <= positions[:, None]                                # (S, T)
+    logits = jnp.where(seen[:, None, :], logits, -jnp.inf)
+    weights = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum(
+        "sht,stv->shv", weights.astype(ctx.dtype), ctx[..., :d_v]
+    )
 
 
 def _attend_xla(q, k_pages, v_pages, block_table, positions, layer):

@@ -76,7 +76,6 @@ class ServeConfig:
         the audited k-wave program are byte-identical to the served
         ones."""
         mc = model_config
-        h_kv = mc.num_kv_heads or mc.num_heads
         max_len = self.max_model_len or mc.max_seq_len
         if max_len > mc.max_seq_len:
             raise ValueError(
@@ -89,13 +88,19 @@ class ServeConfig:
                 f"ServeConfig.decode_waves_per_dispatch {waves} < 1"
             )
         mb = -(-max_len // self.block_len)  # ceil: blocks per sequence
+        lanes = tuple(mc.kv_pool_lanes)
+        h_kv = (mc.num_kv_heads or mc.num_heads) if len(lanes) == 2 else 1
         num_blocks = self.num_blocks or (1 + self.max_slots * mb)
         spec = KVPoolSpec(
             num_layers=mc.num_layers,
             num_blocks=num_blocks,
             block_len=self.block_len,
+            # What a layer caches per token is the model's to declare: K
+            # and V of Hkv*D lanes, or one latent array (one "head" as
+            # wide as the array).
+            lanes=lanes,
             num_kv_heads=h_kv,
-            head_dim=mc.dim // mc.num_heads,
+            head_dim=lanes[0] // h_kv,
             dtype=self.dtype or mc.activation_dtype or "float32",
         )
         return spec, mb, num_blocks, waves
@@ -614,6 +619,9 @@ class ServeEngine:
                 self.tracer.aggregate() if self.tracer is not None else None
             ),
             "pool": {
+                # What a layer caches per token, by pool array: K and V
+                # lanes, or one latent array (the bytes below sum them).
+                "lanes": list(self.engine.spec.lanes),
                 "num_blocks": self.engine.spec.num_blocks,
                 "block_len": self.engine.spec.block_len,
                 "block_bytes": self.engine.spec.block_bytes,

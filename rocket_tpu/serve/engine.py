@@ -15,7 +15,7 @@ Exactly TWO jit-compiled programs serve the whole request lifecycle:
   slots ALL finish early still executes its remaining waves, but they
   write only to the reserved trash block);
 * the **prefill chunk**: a fixed-size ``(1, prefill_chunk)`` prompt slice
-  through the same ``decode_step_paged`` code path, padded + masked at
+  through the same ``paged_step`` code path, padded + masked at
   the tail, so a prompt of ANY length runs through one compiled program
   and interleaves with decode waves chunk by chunk.
 
@@ -47,13 +47,14 @@ wave N−1's results while N runs (dispatch-then-harvest pipelining).
 
 from __future__ import annotations
 
+import json
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from rocket_tpu.models.sampling import freeze_after_eos, sample_tokens
-from rocket_tpu.obs.spans import timed
+from rocket_tpu.obs.spans import span, timed
 from rocket_tpu.serve.kv_pool import KVPoolSpec
 
 __all__ = [
@@ -66,11 +67,13 @@ __all__ = [
     "PREFILL_DONATE",
 ]
 
-#: Donated argument positions of the two compiled programs — the pool
-#: buffers (k_pages, v_pages). One definition shared by the engine's jit
-#: and the static auditor's AOT compile, so they cannot disagree.
-DECODE_DONATE = (1, 2)
-PREFILL_DONATE = (1, 2)
+#: Donated argument positions of the two compiled programs — the pool,
+#: ONE argument: the tuple of arrays the model's layers declare
+#: (``(k_pages, v_pages)``, or one latent array). One definition shared by
+#: the engine's jit and the static auditor's AOT compile, so they cannot
+#: disagree.
+DECODE_DONATE = (1,)
+PREFILL_DONATE = (1,)
 
 
 class WaveHandle(NamedTuple):
@@ -80,12 +83,26 @@ class WaveHandle(NamedTuple):
     finished flag the wave raised, and whether the slot actually ran
     that wave (a slot frozen mid-scan stops emitting). ``seq`` numbers
     the dispatch: the ``serve/dispatch`` and ``serve/harvest_wait`` spans
-    and the request tracer's wave record carry it."""
+    and the request tracer's wave record carry it. ``pairs`` (a model
+    with routed layers only) rides along to the same fetch: the expert
+    pair counts of this dispatch's waves, and of the prefill chunks
+    enqueued before it (done on the device before it is)."""
 
     tokens: jax.Array    # (k, S) int32
     done: jax.Array      # (k, S) bool
     emitted: jax.Array   # (k, S) bool
     seq: int = -1
+    pairs: Optional[tuple] = None   # ((k, layers, held) int32, [chunks...])
+
+
+def record_expert_pairs(kind: str, tick: int, tokens: int, pairs) -> None:
+    """One ``moe/expert_pairs`` record (a span of no length, on when spans
+    are): ``pairs`` (routed layers, experts held) int32 of ONE program
+    call — a decode wave or a prefill chunk — that processed ``tokens``
+    tokens."""
+    with span("moe/expert_pairs", kind=kind, tick=int(tick),
+              tokens=int(tokens)) as sp:
+        sp.set(pairs=json.dumps(pairs.tolist(), separators=(",", ":")))
 
 
 def build_decode_wave(model, on_trace: Optional[Callable] = None,
@@ -106,27 +123,29 @@ def build_decode_wave(model, on_trace: Optional[Callable] = None,
     ``on_trace`` is invoked at TRACE time inside the body (the engine
     passes its retrace counter; the auditor passes its own). Signature::
 
-        decode_wave(params, k_pages, v_pages, block_table, lengths,
+        decode_wave(params, pages, block_table, lengths,
                     last_tok, run_mask, limits, temp, top_k, top_p,
                     eos, seeds, key)
-            -> (k_pages, v_pages, tokens (k, S), done (k, S),
-                emitted (k, S))
+            -> (pages, tokens (k, S), done (k, S), emitted (k, S),
+                expert_pairs (k, layers, held) or None)
+
+    ``pages`` is the pool, a tuple of arrays (``TransformerLM.paged_step``).
     """
     k = int(waves)
     if k < 1:
         raise ValueError(f"build_decode_wave: waves {k} < 1")
 
-    def decode_wave(params, k_pages, v_pages, block_table, lengths,
+    def decode_wave(params, pages, block_table, lengths,
                     last_tok, run_mask, limits, temp, top_k, top_p,
                     eos, seeds, key):
         if on_trace is not None:
             on_trace()  # trace-time: counts (re)traces only
 
         def one_wave(carry, _):
-            k_pages, v_pages, lengths, last_tok, run = carry
+            pages, lengths, last_tok, run = carry
             valid = run.astype(jnp.int32)
-            logits, k_pages, v_pages = model.decode_step_paged(
-                params, last_tok[:, None], k_pages, v_pages, block_table,
+            logits, pages, pairs = model.paged_step(
+                params, last_tok[:, None], pages, block_table,
                 lengths, valid,
             )
             # Per-wave salt, derived on device so every wave of the scan
@@ -143,14 +162,14 @@ def build_decode_wave(model, on_trace: Optional[Callable] = None,
             # coherent) and emit nothing this wave.
             nxt = jnp.where(run, nxt, last_tok)
             done = done & run
-            carry = (k_pages, v_pages, lengths + valid, nxt, run & ~done)
-            return carry, (nxt, done, run)
+            carry = (pages, lengths + valid, nxt, run & ~done)
+            return carry, (nxt, done, run, pairs)
 
-        init = (k_pages, v_pages, lengths, last_tok, run_mask)
-        (k_pages, v_pages, _, _, _), (toks, done, emitted) = jax.lax.scan(
+        init = (tuple(pages), lengths, last_tok, run_mask)
+        (pages, _, _, _), (toks, done, emitted, pairs) = jax.lax.scan(
             one_wave, init, None, length=k
         )
-        return k_pages, v_pages, toks, done, emitted
+        return pages, toks, done, emitted, pairs
 
     return decode_wave
 
@@ -159,19 +178,18 @@ def build_prefill_step(model, on_trace: Optional[Callable] = None) -> Callable:
     """The prefill-chunk step function for ``model``; see
     :func:`build_decode_wave` for the builder contract. Signature::
 
-        prefill_chunk(params, k_pages, v_pages, block_table_row,
-                      tokens, positions, valid) -> (k_pages, v_pages)
+        prefill_chunk(params, pages, block_table_row, tokens, positions,
+                      valid) -> (pages, expert_pairs (layers, held) or None)
     """
 
-    def prefill_chunk_fn(params, k_pages, v_pages, block_table, tokens,
-                         positions, valid):
+    def prefill_chunk_fn(params, pages, block_table, tokens, positions,
+                         valid):
         if on_trace is not None:
             on_trace()  # trace-time: counts (re)traces only
-        _, k_pages, v_pages = model.decode_step_paged(
-            params, tokens, k_pages, v_pages, block_table,
-            positions, valid,
+        _, pages, pairs = model.paged_step(
+            params, tokens, pages, block_table, positions, valid,
         )
-        return k_pages, v_pages
+        return pages, pairs
 
     return prefill_chunk_fn
 
@@ -204,14 +222,17 @@ def abstract_wave_inputs(
         lambda p: _decode_params(p, model.config.activation_dtype), abs_params
     )
     s, mb, c = int(max_slots), int(max_blocks_per_seq), int(prefill_chunk)
-    pool = jax.ShapeDtypeStruct(spec.pages_shape, jnp.dtype(spec.dtype))
+    pool = tuple(
+        jax.ShapeDtypeStruct(shape, jnp.dtype(spec.dtype))
+        for shape in spec.pages_shapes
+    )
     i32 = jnp.int32
     f32 = jnp.float32
     vec_i = jax.ShapeDtypeStruct((s,), i32)
     vec_f = jax.ShapeDtypeStruct((s,), f32)
     key = jax.eval_shape(lambda: jax.random.key(0))
     decode_args = (
-        abs_params, pool, pool,
+        abs_params, pool,
         jax.ShapeDtypeStruct((s, mb), i32),   # block_table
         vec_i,                                # lengths
         vec_i,                                # last_tok
@@ -225,7 +246,7 @@ def abstract_wave_inputs(
         key,
     )
     prefill_args = (
-        abs_params, pool, pool,
+        abs_params, pool,
         jax.ShapeDtypeStruct((1, mb), i32),   # block_table row
         jax.ShapeDtypeStruct((1, c), i32),    # tokens
         jax.ShapeDtypeStruct((1,), i32),      # position
@@ -238,7 +259,7 @@ class SlotEngine:
     """Owns the device pool and the two compiled step programs.
 
     ``model`` is a :class:`~rocket_tpu.models.transformer.TransformerLM`
-    (or anything exposing ``decode_step_paged`` with the same signature);
+    (or anything exposing ``paged_step`` with the same signature);
     ``params`` its param tree — float leaves are cast ONCE to the model's
     activation dtype (the same hoisted master-cast ``generate()`` does:
     decode is HBM-bound on parameter streaming). ``waves_per_dispatch``
@@ -277,7 +298,16 @@ class SlotEngine:
         self.prefill_chunk = int(prefill_chunk)
         self.waves_per_dispatch = int(waves_per_dispatch)
         self._params = _decode_params(params, model.config.activation_dtype)
-        self.k_pages, self.v_pages = spec.init_pages()
+        #: The device pool: the arrays the model's layers declare, as
+        #: ONE tuple handed whole to both programs and taken back whole.
+        self.pages = spec.init_pages()
+        #: Expert pair counts of the prefill chunks enqueued since the
+        #: last decode dispatch (a model with routed layers only): the
+        #: next dispatch's handle takes them to its own fetch.
+        self._chunk_pairs: list = []
+        #: The scheduler's tick, which it keeps current: the ``tick=`` of
+        #: ``moe/expert_pairs``.
+        self.tick = 0
         self._key = jax.random.key(0) if key is None else key
         #: Trace counters — incremented at TRACE time inside the compiled
         #: bodies; == 1 each after any number of waves is the no-retrace
@@ -316,6 +346,11 @@ class SlotEngine:
             donate_argnums=PREFILL_DONATE,
         )
 
+    @property
+    def k_pages(self):
+        """The first pool array (K, or the latent array)."""
+        return self.pages[0]
+
     # -- compiled-step drivers ---------------------------------------------
 
     def decode_dispatch(self, block_table, lengths, last_tok, run_mask,
@@ -332,12 +367,16 @@ class SlotEngine:
             if sp.on:
                 sp.set(occupancy=int(run_mask.sum()))
             self.last_dispatch_at = sp.start
-            self.k_pages, self.v_pages, toks, done, emitted = self._decode(
-                self._params, self.k_pages, self.v_pages, block_table,
+            self.pages, toks, done, emitted, pairs = self._decode(
+                self._params, self.pages, block_table,
                 lengths, last_tok, run_mask, limits, temp, top_k, top_p,
                 eos, seeds, self._key,
             )
-        return WaveHandle(tokens=toks, done=done, emitted=emitted, seq=seq)
+        if pairs is not None:
+            pairs = (pairs, self.tick, self._chunk_pairs)
+            self._chunk_pairs = []
+        return WaveHandle(tokens=toks, done=done, emitted=emitted, seq=seq,
+                          pairs=pairs)
 
     def harvest(self, handle: WaveHandle):
         """Fetch one dispatch's results to host numpy — the single
@@ -346,13 +385,23 @@ class SlotEngine:
         around the fetch is the time the host waited for the device; its
         two instants feed ``harvest_wait_s`` and ``last_harvest_at``."""
         self.device_gets += 1
+        fetch = (handle.tokens, handle.done, handle.emitted)
+        if handle.pairs is not None:
+            wave_pairs, tick, chunks = handle.pairs
+            fetch += (wave_pairs, [c[-1] for c in chunks])
         with timed("serve/harvest_wait", seq=handle.seq) as sp:
-            out = jax.device_get(
-                (handle.tokens, handle.done, handle.emitted)
-            )
+            out = jax.device_get(fetch)
         self.last_harvest_at = sp.end
         self.harvest_wait_s += sp.end - sp.start
-        return out
+        if handle.pairs is not None and sp.on:
+            # The counter moe/expert_pairs: per program call and routed
+            # layer, the pairs each held expert received and the tokens
+            # the call processed — from arrays this fetch brought anyway.
+            for wave, emitted in zip(out[3], out[2]):
+                record_expert_pairs("decode", tick, int(emitted.sum()), wave)
+            for (at, valid, _), pairs in zip(chunks, out[4]):
+                record_expert_pairs("prefill", at, valid, pairs)
+        return out[:3]
 
     def decode(self, block_table, lengths, last_tok, run_mask, limits,
                temp, top_k, top_p, eos, seeds):
@@ -369,7 +418,9 @@ class SlotEngine:
         ``valid`` ``(1,)``. Fire-and-forget — nothing is fetched, so
         chunks pipeline behind decode waves."""
         self.prefill_chunks += 1
-        self.k_pages, self.v_pages = self._prefill(
-            self._params, self.k_pages, self.v_pages, block_table_row,
-            tokens, position, valid,
+        self.pages, pairs = self._prefill(
+            self._params, self.pages, block_table_row, tokens, position,
+            valid,
         )
+        if pairs is not None:
+            self._chunk_pairs.append((self.tick, int(valid[0]), pairs))

@@ -1,12 +1,17 @@
 """Paged KV block pool — fixed-shape HBM arrays + the host-side allocator.
 
-The pool is the serving engine's only model-state memory: two
-``(L, num_blocks, block_len, Hkv*D)`` arrays allocated ONCE, sized
-independently of how many requests ever flow through the engine. A page is
-stored as the ``(block_len, Hkv*D)`` tile both compiled programs read and
-write in place: all kv heads of a row side by side on the minor (lane)
-axis, so a row is one scatter update, a page is one kernel block and no
-program ever relayouts the pool (``ops/paged_attention.py``). Requests
+The pool is the serving engine's only model-state memory: the arrays the
+model's layers declare (``TransformerConfig.kv_pool_lanes``), each
+``(L, num_blocks, block_len, lanes)``, allocated ONCE and sized
+independently of how many requests ever flow through the engine. A
+multi-head model declares two, K and V, of ``Hkv*D`` lanes; a latent-
+attention model ONE, of ``kv_lora_rank + qk_rope_head_dim`` lanes (the
+normed latent and the rotated shared key side by side: there is no V). A
+page is stored as the ``(block_len, lanes)`` tile both compiled programs
+read and write in place: everything a token caches in a layer side by
+side on the minor (lane) axis, so a row is one scatter update, a page is
+one kernel block and no program ever relayouts the pool
+(``ops/paged_attention.py``). Requests
 own *blocks*, not cache rows: the allocator hands out integer block ids on
 the host and the compiled step indexes the pool through per-slot block
 tables, so admitting a request is a few host list operations and never
@@ -35,14 +40,17 @@ __all__ = ["KVPoolSpec", "BlockAllocator"]
 
 @dataclass(frozen=True)
 class KVPoolSpec:
-    """Shape of the paged pool for one model."""
+    """Shape of the paged pool for one model. ``lanes`` holds, for each
+    pool array, what a layer caches per token in it; left empty it is the
+    K and V of ``num_kv_heads * head_dim`` lanes each."""
 
     num_layers: int
     num_blocks: int
     block_len: int
-    num_kv_heads: int
-    head_dim: int
+    num_kv_heads: int = 0
+    head_dim: int = 0
     dtype: str = "float32"
+    lanes: tuple = ()
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -52,35 +60,44 @@ class KVPoolSpec:
             )
         if self.block_len < 1:
             raise ValueError(f"KVPoolSpec: block_len {self.block_len} < 1")
+        if not self.lanes:
+            kv = self.num_kv_heads * self.head_dim
+            object.__setattr__(self, "lanes", (kv, kv))
+        if not all(int(n) > 0 for n in self.lanes):
+            raise ValueError(f"KVPoolSpec: lanes {self.lanes} must be > 0")
 
     @property
     def block_bytes(self) -> int:
-        """HBM bytes ONE block costs across K+V and all layers."""
+        """HBM bytes ONE block costs across the pool's arrays (K and V,
+        or the one latent array) and all layers."""
         itemsize = jnp.dtype(self.dtype).itemsize
-        return (
-            2 * self.num_layers * self.block_len * self.num_kv_heads
-            * self.head_dim * itemsize
-        )
+        return self.num_layers * self.block_len * sum(self.lanes) * itemsize
 
     @property
     def pool_bytes(self) -> int:
         """Total pool HBM: ``num_blocks * block_bytes`` — the serving
-        engine's peak KV memory regardless of request count."""
+        engine's peak cache memory regardless of request count."""
         return self.num_blocks * self.block_bytes
 
     @property
-    def pages_shape(self) -> tuple[int, int, int, int]:
-        """Shape of ``k_pages`` (and of ``v_pages``): ``(L, NB, BL, Hkv*D)``."""
-        return (
-            self.num_layers, self.num_blocks, self.block_len,
-            self.num_kv_heads * self.head_dim,
+    def pages_shapes(self) -> tuple:
+        """Shape of each pool array: ``(L, NB, BL, lanes)``."""
+        return tuple(
+            (self.num_layers, self.num_blocks, self.block_len, int(n))
+            for n in self.lanes
         )
 
-    def init_pages(self):
-        """The zeroed device pool: ``(k_pages, v_pages)``, each
-        :attr:`pages_shape`."""
+    @property
+    def pages_shape(self) -> tuple[int, int, int, int]:
+        """Shape of the first pool array (of ``k_pages``; ``v_pages`` has
+        the same): ``(L, NB, BL, Hkv*D)``."""
+        return self.pages_shapes[0]
+
+    def init_pages(self) -> tuple:
+        """The zeroed device pool: one array per entry of ``lanes`` —
+        ``(k_pages, v_pages)`` for a K/V pool."""
         dt = jnp.dtype(self.dtype)
-        return jnp.zeros(self.pages_shape, dt), jnp.zeros(self.pages_shape, dt)
+        return tuple(jnp.zeros(shape, dt) for shape in self.pages_shapes)
 
 
 class BlockAllocator:

@@ -207,6 +207,8 @@ class Scheduler:
         dispatch the next k waves. Returns the tokens the HARVESTED
         dispatch emitted (one tick behind the device — the pipelining);
         an idle engine returns []."""
+        # The engine dates its ``moe/expert_pairs`` records by this.
+        self.engine.tick = self.ticks
         if self.queue:
             with span("serve/admit", tick=self.ticks) as sp:
                 sp.set(admitted=self._admit())

@@ -252,18 +252,9 @@ def test_serve_programs_update_the_pool_in_place(
     REAL Mosaic kernel and not its interpreted stand-in: what is asserted
     is the whole program, the kernel's operands included. (A count of
     instructions in a compile for a described chip; not a device number.)"""
-    import re
-
     import rocket_tpu.ops.paged_attention as paged
     from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
     from rocket_tpu.serve import ServeConfig
-    from rocket_tpu.serve.engine import (
-        DECODE_DONATE,
-        PREFILL_DONATE,
-        abstract_wave_inputs,
-        build_decode_wave,
-        build_prefill_step,
-    )
 
     monkeypatch.setattr(paged, "_on_cpu", lambda: False)
     model = TransformerLM(TransformerConfig(
@@ -272,10 +263,73 @@ def test_serve_programs_update_the_pool_in_place(
         scan_layers=scan_layers,
     ))
     sc = ServeConfig(max_slots=32, block_len=16, prefill_chunk=128)
+    spec = sc.resolve(model.config)[0]
+    assert spec.pages_shapes == ((2, 2049, 16, 1280),) * 2
+    _assert_pool_in_place(sds, model, sc, "paged_decode")
+
+
+def test_latent_serve_programs_update_the_pool_in_place(sds, monkeypatch):
+    """The same two programs for a latent-attention, routed-expert model at
+    the docqa cell's attention widths (128 heads, latent 512 + 64, 32
+    slots x 8192 positions in blocks of 64; one dense and one routed layer,
+    FFN widths and vocabulary small so that it compiles in seconds and the
+    pool is the only large array): ONE pool array of 576 lanes in 640, read by
+    the ``mla_decode`` kernel and by the prefill's page gathers where it
+    lies and written only by its own row scatter."""
+    import rocket_tpu.nn.moe as moe
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.nn.attention import LatentAttentionConfig, YarnScaling
+    from rocket_tpu.nn.moe import RoutedExpertsConfig
+    from rocket_tpu.serve import ServeConfig
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=8192, dim=1024, num_layers=2,
+        num_heads=128, dropout=0.0, activation_dtype="bfloat16",
+        pos_embedding="rope", norm="rmsnorm", norm_eps=1e-6, mlp="swiglu",
+        mlp_hidden=1024, mlp_bias=False, tied_embeddings=False,
+        latent_attention=LatentAttentionConfig(
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128,
+            yarn=YarnScaling(factor=40, original_max_position_embeddings=4096,
+                             mscale=1.0, mscale_all_dim=1.0),
+        ),
+        routed_experts=RoutedExpertsConfig(
+            num_experts=256, top_k=8, hidden=256, n_group=8, topk_group=4,
+            routed_scaling_factor=2.5, shared_hidden=256, experts_held=(0, 16),
+        ),
+        first_dense_layers=1,
+    ))
+    sc = ServeConfig(max_slots=32, block_len=64, prefill_chunk=512)
+    spec = sc.resolve(model.config)[0]
+    assert spec.pages_shapes == ((2, 4097, 64, 640),)
+    kernels = _assert_pool_in_place(sds, model, sc, "mla_decode")
+    # (The prefill chunk discards its logits, so its LAST layer's experts
+    # are dead code: with one routed layer it holds no grouped matmul.)
+    for name in ("moe_gmm_gate_up", "moe_gmm_down"):
+        assert any(name in k for k in kernels["decode"]), kernels
+
+
+def _assert_pool_in_place(sds, model, sc, decode_kernel):
+    """Both programs of ``model`` under ``sc``, compiled for the described
+    chip: see the tests above. Returns the kernels of each program."""
+    import re
+
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
     spec, mb, _, waves = sc.resolve(model.config)
-    assert spec.pages_shape == (2, 2049, 16, 1280)
-    pool_type = "bf16[%s]" % ",".join(map(str, spec.pages_shape))
-    layer_slice = spec.num_blocks * spec.block_len * 1280      # elements
+    pool_types = [
+        "bf16[%s]" % ",".join(map(str, shape)) for shape in spec.pages_shapes
+    ]
+    layer_slice = spec.num_blocks * spec.block_len * max(spec.lanes)  # elements
     decode_args, prefill_args = jax.tree.map(
         lambda a: sds(a.shape, a.dtype),
         abstract_wave_inputs(
@@ -288,12 +342,14 @@ def test_serve_programs_update_the_pool_in_place(
                    DECODE_DONATE),
         "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE),
     }
+    found = {}
     for name, (fn, args, donate) in programs.items():
         compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         text = compiled.as_text()
         kernels = _kernel_instructions(text)
+        found[name] = kernels
         if name == "decode":
-            assert any("paged_decode" in k for k in kernels), (name, kernels)
+            assert any(decode_kernel in k for k in kernels), (name, kernels)
         large = [
             (op, inst) for op, inst, elements, line in _materialised(text)
             if elements >= layer_slice
@@ -302,7 +358,7 @@ def test_serve_programs_update_the_pool_in_place(
             # the one thing a program may do to the pool: scatter the new
             # rows into the buffer it was given
             and not (op == "fusion" and "/scatter\"" in line
-                     and pool_type in line)
+                     and any(t in line for t in pool_types))
         ]
         assert not large, (name, large)
         memory = compiled.memory_analysis()
@@ -311,4 +367,6 @@ def test_serve_programs_update_the_pool_in_place(
         assert memory.alias_size_in_bytes >= spec.pool_bytes, (
             name, memory.alias_size_in_bytes)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
-        assert aliases and aliases.group(1).count("alias") == 2, (name, text[:300])
+        assert aliases and aliases.group(1).count("alias") == len(pool_types), (
+            name, text[:300])
+    return found
