@@ -406,7 +406,8 @@ def serve_phase(config, *, seed: int, max_slots: int, block_len: int,
                 for r in requests
             ),
             "kernel_supported": paged_decode_supported(
-                spec.block_len, spec.head_dim, np.dtype(spec.dtype).itemsize
+                spec.block_len, spec.head_dim, np.dtype(spec.dtype).itemsize,
+                lanes=spec.lanes[0],
             ) if require_kernels else None,
             "pallas_in_decode":
                 (pallas_calls > 0) if require_kernels else None,

@@ -811,7 +811,8 @@ def audit_serving(
     )
     wave = by_name.get("decode_wave") or decode
     kernel_engages = paged_decode_supported(
-        spec.block_len, spec.head_dim, np.dtype(spec.dtype).itemsize
+        spec.block_len, spec.head_dim, np.dtype(spec.dtype).itemsize,
+        lanes=spec.lanes[0],
     )
     fused = fused_decode_bytes(
         spec, params_bytes,

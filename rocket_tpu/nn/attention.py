@@ -990,7 +990,7 @@ class LatentAttention(Layer):
             q = self.absorb(params, q_nope[:, 0], q_rope[:, 0])
             with jax.named_scope("mla/attend"):
                 out = paged_latent_decode(
-                    q, pages, block_table, positions, layer=layer,
+                    q, pages, block_table, positions, valid, layer=layer,
                     d_v=self.kv_lora_rank, scale=self.scale,
                     interpret=interpret,
                 )

@@ -31,16 +31,20 @@ Two device-side implementations share one signature:
   wave (RKT602's CPU cost model prices it at 4.6x the analytic floor
   for a decode wave: a prediction, not a measurement).
 * **pallas paged-decode kernel** (TPU, C=1 decode waves): the same
-  scatter, then gather and attend are FUSED per block-table page —
-  each grid step streams one ``(block_kv, Hkv*D)`` tile of one mapped
-  page (all kv heads side by side on the lane axis) straight into VMEM
-  and folds each head's lane slice into a flash-style running softmax,
-  so only the slot's ACTIVE pages ever leave HBM and no transient
-  context materializes. Inactive table entries point at the reserved
-  trash block 0; Mosaic's pipeline skips re-fetching a repeated block
-  index, so the dead tail of a short sequence costs at most one trash
-  PAGE of fetches (``block_len / block_kv`` tiles, cycled thereafter),
-  not ``max_blocks_per_seq`` gathers.
+  scatter, then gather and attend are FUSED over the slot's LIVE pages.
+  The grid is the slots; the pool stays in HBM and each slot that RUNS
+  this wave (``valid[s] > 0``) loops over ``cdiv(positions[s] + 1,
+  block_kv)`` tiles of its context, copying each tile's pages — every
+  kv head side by side on the lane axis — from where the block table
+  says they lie into one of two VMEM buffers while the other is folded,
+  head by lane slice, into a flash-style running softmax. So a call
+  costs a small constant per slot plus what the running slots' contexts
+  cost: a free slot, a slot still prefilling and the unmapped tail of a
+  table cost no copy and no loop step, and no transient context
+  materializes. (The earlier body walked a static grid of ``max_slots x
+  max_blocks_per_seq`` steps and skipped the dead ones' work, which still
+  cost 0.12 us a step: 99 % of a call at the chat cell's load; PERF.md,
+  PR 30.)
 
 Implementation choice and the ``block_kv`` tile height resolve through
 the ``paged_decode`` tune table (``rocket_tpu.tune``) — ``impl`` is a
@@ -49,13 +53,15 @@ the kernel on a shape and pin it). With nothing pinned the choice is a
 function of what the call can observe: the kernel for C=1 decode on a
 TPU wherever :func:`paged_decode_supported` holds, the XLA path for
 prefill chunks, unsupported pool geometries and on the CPU (bitwise
-identical to an untuned checkout — asserted in tests).
+identical to an untuned checkout — asserted in tests); the tile height
+from the page length and the row's width (:func:`_default_block_kv`).
 ``ROCKET_TPU_PAGED_DECODE`` (``pallas``/``xla``) force-overrides the
 table. A PINNED ``pallas`` (argument, table or environment) that cannot
 run raises — it never silently becomes the other path.
 
-Layout notes for TPU: the kernel's block spans the pool's whole
-``Hkv*D`` lane axis (so any head count and width is Mosaic-legal) and
+Layout notes for TPU: a copy moves whole rows of the pool as stored, so
+any head count and width whose ``Hkv*D`` is a multiple of 128 lanes is
+Mosaic-legal (an HBM array is sliced at its tiling only), and
 ``block_len`` must be a multiple of the dtype's sublane tile (8 f32 / 16
 bf16).
 
@@ -141,15 +147,18 @@ def paged_gather(pages, block_table, *, layer=0):
     return ctx.reshape(s, mb * pages.shape[2], pages.shape[3])
 
 
-def paged_decode_supported(block_len: int, head_dim: int, itemsize: int = 4) -> bool:
-    """Shape gate for the fused kernel: a page streams as
-    ``(block_kv, Hkv*D)`` VMEM tiles (the whole lane axis, so any head
-    count is Mosaic-legal) — block_len must be a multiple of the dtype's
-    sublane minimum so such a tile divides the page, and D a multiple
-    of 8 (the per-head lane slice). ``tests/test_tpu_compile.py``
-    compiles the kernel for a v5e chip across this gate's edge."""
+def paged_decode_supported(block_len: int, head_dim: int, itemsize: int = 4,
+                           *, lanes: int) -> bool:
+    """Shape gate for the fused kernel: it copies whole rows of a page —
+    ``lanes`` = the pool array's lane axis, every kv head side by side —
+    from HBM into VMEM tiles. Mosaic slices an HBM array only at its
+    (sublane, 128) tiling, so ``lanes`` must be a multiple of 128 and
+    block_len of the dtype's sublane minimum; D a multiple of 8 (the
+    per-head lane slice). ``tests/test_tpu_compile.py`` compiles the
+    kernel for a v5e chip across this gate's edge."""
     sub = _SUBLANE.get(itemsize, 8)
-    return block_len % sub == 0 and head_dim % 8 == 0 and head_dim >= 8
+    return (block_len % sub == 0 and lanes % 128 == 0
+            and head_dim % 8 == 0 and head_dim >= 8)
 
 
 def _on_cpu() -> bool:
@@ -158,60 +167,130 @@ def _on_cpu() -> bool:
     return jax.devices()[0].platform == "cpu"
 
 
-def _default_block_kv(block_len: int, itemsize: int = 4) -> int:
-    """The hand-picked tile height: the largest power-of-two row count
-    (<= 128) that divides the page — one page per grid step when the
-    page itself is small."""
+#: Most rows of context one compute step folds in. A taller tile pays
+#: the step's fixed costs (the loop, the copies' waits, the accumulator's
+#: rescale) less often and computes more masked rows in a context's last
+#: tile: on a v5e 512 rows beat 128 by a third at 640 lanes and one kv
+#: head (PERF.md, PR 30).
+_TILE_ROWS = 512
+#: Most elements the streamed tiles may hold in VMEM (both buffers of
+#: every pool array): 2 MiB of bfloat16, 4 MiB of float32, well under the
+#: 16 MiB a kernel is given by default. A tile is thus about the same
+#: BYTES whatever the row's width: 128 rows of 20 K and V heads of 64
+#: (where 128 rows measured best), 512 rows of a 640-lane latent.
+_TILE_ELEMENTS = 1 << 20
+
+
+def _default_block_kv(block_len: int, itemsize: int = 4,
+                      row_lanes: int = 0) -> int:
+    """The tile height nobody pinned: ``_TILE_ROWS`` rows — several small
+    pages a compute step, or part of a large one — halved while the
+    double-buffered tiles (``row_lanes`` = one context row of every pool
+    array) overrun ``_TILE_ELEMENTS``, and while the height neither
+    divides the page nor is a multiple of it."""
     sub = _SUBLANE.get(itemsize, 8)
-    for rows in (128, 64, 32, 16, 8):
-        if rows % sub == 0 and block_len % rows == 0:
-            return rows
-    return block_len
+    rows = _TILE_ROWS
+    while rows > sub and (
+        2 * rows * row_lanes > _TILE_ELEMENTS
+        or (block_len % rows and rows % block_len)
+    ):
+        rows //= 2
+    return rows
 
 
-def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, *refs,
-                   block_kv, sub, mb, scale, h_kv, g, d, d_v, shared):
-    """One (slot, kv-tile) grid step of the fused paged decode.
+def _decode_kernel(layer_ref, table_ref, pos_ref, valid_ref, q_ref, k_hbm,
+                   *refs, block_kv, bl, mb, scale, h_kv, g, d, d_v, shared):
+    """One SLOT of the fused paged decode: a loop over the slot's LIVE
+    tiles, so a call costs a constant per slot plus what its running
+    slots' contexts cost — nothing per page that nobody holds.
 
-    Streams a ``(block_kv, Hkv*D)`` tile of the mapped page — every kv
-    head's rows side by side on the lane axis — and folds each head's
-    ``(block_kv, D)`` lane slice into the flash-style running softmax
-    held in f32 scratch (one row group of ``g`` query heads per kv
-    head); the normalized output is written once, after the last tile.
-    The new K/V row was scattered into the pool BEFORE the kernel, so
-    key positions ``<= pos`` (the query's own row included) are all
-    read from the pool — exact prefix semantics, one code path. All ops
-    stay 2D per head (Mosaic rejects 3D shape casts).
+    The pool stays in HBM (``memory_space=HBM``). A tile is ``block_kv``
+    rows of context: ``block_kv // bl`` whole pages, or a ``block_kv``-row
+    part of one, copied by ``make_async_copy`` from where the prefetched
+    table says they lie into one of two VMEM buffers while the other is
+    computed on. The trip count is ``cdiv(pos + 1, block_kv)`` for a slot
+    that runs this wave and 0 for one that does not (``valid`` 0: free, or
+    mid-prefill — its output row is zeros, which the wave ignores); page
+    copies past the context are not started, their rows keep what an
+    earlier tile left (zeros at first) and are masked by position.
+
+    Each tile has every kv head's rows side by side on the lane axis; each
+    head's ``(block_kv, D)`` lane slice is folded into the flash-style
+    running softmax held in f32 scratch (one row group of ``g`` query
+    heads per kv head) and the normalized output written once, after the
+    last tile. The new K/V row was scattered into the pool BEFORE the
+    kernel, so key positions ``<= pos`` (the query's own row included) are
+    all read from the pool — exact prefix semantics, one code path. All
+    ops stay 2D per head (Mosaic rejects 3D shape casts).
 
     ``shared``: there is no V array — a row's first ``d_v`` lanes ARE its
     value (a latent pool: one ``d``-lane row per token, read once for
     every query head), so the tile that gave the scores gives the values
     too and the pool is streamed once."""
-    del layer_ref, table_ref  # consumed by the index maps
-    if shared:
-        v_ref, (o_ref, m_ref, l_ref, acc_ref) = k_ref, refs
-    else:
-        v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    n = 1 if shared else 2                # pool arrays: K (and V)
+    pools, (o_ref, *refs) = (k_hbm, *refs[:n - 1]), refs[n - 1:]
+    bufs, (sems, m_ref, l_ref, acc_ref) = refs[:n], refs[n:]
+    k_buf, v_buf = bufs[0], bufs[-1]
     i = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = pos_ref[i]
-    n_ctx = pos + 1                       # visible keys: positions [0, pos]
+    layer = layer_ref[0]
+    # Visible keys: positions [0, pos] of a slot that runs, none otherwise.
+    n_ctx = jnp.where(valid_ref[i] > 0, pos_ref[i] + 1, 0)
+    n_tiles = pl.cdiv(n_ctx, block_kv)
+    chunk = min(bl, block_kv)             # rows of one copy
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copies(t, buf):
+        """The copies of tile ``t`` into buffer ``buf``, each with the
+        condition under which it is live."""
+        out = []
+        for c in range(block_kv // chunk):
+            row = t * block_kv + c * chunk          # global position
+            page = table_ref[i * mb + jnp.minimum(row // bl, mb - 1)]
+            for n, (hbm, vmem) in enumerate(zip(pools, bufs)):
+                src = hbm.at[layer, page] if chunk == bl else \
+                    hbm.at[layer, page, pl.ds(row % bl, chunk)]
+                out.append((row < n_ctx, pltpu.make_async_copy(
+                    src, vmem.at[buf, pl.ds(c * chunk, chunk)],
+                    sems.at[buf, n],
+                )))
+        return out
 
-    base = j * block_kv                   # global position of tile row 0
+    def start(t, buf):
+        for live, copy in copies(t, buf):
+            pl.when(live)(copy.start)
 
-    @pl.when(base < n_ctx)
-    def _tile():
+    def wait(t, buf):
+        for live, copy in copies(t, buf):
+            pl.when(live)(copy.wait)
+
+    @pl.when(i == 0)
+    def _clear():
+        # Rows no copy has written yet must hold finite values: a masked
+        # column's weight is exactly 0, and 0 x garbage may be NaN.
+        for vmem in bufs:
+            vmem[...] = jnp.zeros_like(vmem)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        start(0, 0)
+
+    def tile(t, carry):
+        buf = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _next():
+            start(t + 1, 1 - buf)
+
+        wait(t, buf)
+        base = t * block_kv               # global position of tile row 0
         for h in range(h_kv):
             rows = slice(h * g, (h + 1) * g)
             q = q_ref[0, rows, :]                      # (g, D)
-            k = k_ref[0, 0, :, h * d:(h + 1) * d]      # (block_kv, D)
-            v = v_ref[0, 0, :, h * d:h * d + d_v]
+            k = k_buf[buf, :, h * d:(h + 1) * d]       # (block_kv, D)
+            v = v_buf[buf, :, h * d:h * d + d_v]
             s_ij = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -235,20 +314,26 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref, *refs,
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+        return carry
 
-    @pl.when(j == sub * mb - 1)
-    def _emit():
-        o_ref[0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_tiles, tile, None)
+    # A slot that did not run folded nothing in: 0 / 1, not 0 / 0.
+    denom = jnp.where(n_tiles > 0, l_ref[:, 0:1], 1.0)
+    o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
+@functools.partial(
+    jax.jit, static_argnames=("block_kv", "interpret", "scale", "d_v", "name")
+)
+def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions, valid,
                          *, layer=0, block_kv: int, interpret: bool,
                          scale: Optional[float] = None,
                          d_v: Optional[int] = None,
                          name: str = "paged_decode"):
     """The fused gather+attend for one decode wave: ``q`` (S, Hq, D),
-    pool/table/positions/layer as in :func:`paged_attention` (new rows
-    already scattered). Returns ``out`` (S, Hq, d_v).
+    pool/table/positions/valid/layer as in :func:`paged_attention` (new
+    rows already scattered). Returns ``out`` (S, Hq, d_v); the row of a
+    slot with ``valid`` 0 is zeros.
 
     ``v_pages=None`` is the latent pool (:func:`paged_latent_decode`): ONE
     kv "head" of ``D`` = the array's whole lane axis for all ``Hq`` query
@@ -256,45 +341,40 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
     given by the caller. With a V array ``d_v`` is ``D`` and ``scale``
     ``1/sqrt(D)`` — one kernel body for both.
 
-    Mosaic wants the last two dims of every block divisible by the
-    (sublane, 128) tile or equal to the array's own: a page tile is
-    ``(block_kv, Hkv*D)`` of the pool AS STORED, with the whole lane
-    axis, and q/out blocks carry the whole ``(Hq, D)`` head axis; the
-    kernel selects each kv head by a static lane slice. The layer rides
-    in as a prefetched scalar beside the table, so the index map
-    addresses ``(layer, page, tile)`` of the whole pool and one kernel
-    serves a Python-loop layer and a scanned one. A per-head block
-    ``(1, block_kv, 1, D)`` / ``(1, g, D)`` is refused by the TPU
-    lowering whenever Hkv > 1 or g < 8."""
+    The grid is the slots; the pool is handed over where it lies and the
+    body copies a slot's live tiles itself (:func:`_decode_kernel`). A
+    copy moves whole rows of the pool AS STORED, every kv head side by
+    side on the lane axis, so any head count and width is Mosaic-legal;
+    q/out blocks carry the whole ``(Hq, D)`` head axis and the kernel
+    selects each kv head by a static lane slice. The layer rides in as a
+    prefetched scalar beside the table, positions and valid, so one
+    kernel serves a Python-loop layer and a scanned one — and, jitted,
+    the layers of a Python-loop model share ONE traced and lowered body
+    (36 lowerings of it cost GPT-2 large 30 s of every start; PERF.md,
+    PR 30)."""
     s, hq, d = q.shape
     _, _, bl, hd = k_pages.shape
     shared = v_pages is None
     h_kv = hd // d
     mb = block_table.shape[1]
     g = hq // h_kv
-    sub = bl // block_kv
-    d_v = d if d_v is None else int(d_v)
-    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
+    d_v = d if d_v is None else d_v
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
 
-    def q_map(i, j, layer_ref, table_ref, pos_ref):
-        del j, layer_ref, table_ref, pos_ref
+    def slot_map(i, *prefetched):
+        del prefetched
         return (i, 0, 0)
 
-    def page_map(i, j, layer_ref, table_ref, pos_ref):
-        del pos_ref
-        # Dims 0 and 1 are blocked at one layer and one whole page, so
-        # the layer and the page id ARE their block indices; dim 2 is
-        # tiled at block_kv rows, so the within-page tile is its index.
-        return (layer_ref[0], table_ref[i * mb + j // sub], j % sub, 0)
-
-    page_spec = pl.BlockSpec((1, 1, block_kv, hd), page_map)
     pools = (k_pages,) if shared else (k_pages, v_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s, mb * sub),
-        in_specs=[pl.BlockSpec((1, hq, d), q_map)] + [page_spec] * len(pools),
-        out_specs=pl.BlockSpec((1, hq, d_v), q_map),
-        scratch_shapes=[
+        num_scalar_prefetch=4,
+        grid=(s,),
+        in_specs=[pl.BlockSpec((1, hq, d), slot_map)]
+        + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(pools),
+        out_specs=pl.BlockSpec((1, hq, d_v), slot_map),
+        scratch_shapes=[pltpu.VMEM((2, block_kv, hd), k_pages.dtype)
+                        for _ in pools] + [
+            pltpu.SemaphoreType.DMA((2, len(pools))),
             pltpu.VMEM((hq, 128), jnp.float32),   # running max (lane-bcast)
             pltpu.VMEM((hq, 128), jnp.float32),   # running denom
             pltpu.VMEM((hq, d_v), jnp.float32),   # unnormalized accumulator
@@ -302,23 +382,25 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_table, positions,
     )
     return pl.pallas_call(
         functools.partial(
-            _decode_kernel, block_kv=block_kv, sub=sub, mb=mb, scale=scale,
+            _decode_kernel, block_kv=block_kv, bl=bl, mb=mb, scale=scale,
             h_kv=h_kv, g=g, d=d, d_v=d_v, shared=shared,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hq, d_v), q.dtype),
+        # One slot after another: the tile buffers are cleared at slot 0.
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       block_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(positions, jnp.int32), q, *pools)
+      jnp.asarray(positions, jnp.int32), jnp.asarray(valid, jnp.int32),
+      q, *pools)
 
 
-def paged_latent_decode(q, pages, block_table, positions, *, layer=0,
-                        d_v: int, scale: float,
+def paged_latent_decode(q, pages, block_table, positions, valid=None, *,
+                        layer=0, d_v: int, scale: float,
                         block_kv: Optional[int] = None,
                         interpret: Optional[bool] = None):
     """One decode wave of latent attention (MLA, absorbed form) against
@@ -327,23 +409,29 @@ def paged_latent_decode(q, pages, block_table, positions, *, layer=0,
     ``pages`` ``(L, NB, BL, Dk)`` holding one ``Dk``-lane row per token
     (the new rows already scattered, :func:`write_pages`). Scores are
     ``q . row * scale`` over all ``Dk`` lanes, values the first ``d_v``
-    lanes of the SAME rows. Returns ``(S, Hq, d_v)``.
+    lanes of the SAME rows. Returns ``(S, Hq, d_v)``. ``valid`` ``(S,)``
+    as in :func:`paged_attention` (None: every slot runs): the row of a
+    slot with ``valid`` 0 is garbage the caller ignores.
 
     Where the fused kernel can run (a TPU, or ``interpret=True``) it is
     :func:`_paged_decode_pallas` with no V array, under the Pallas name
-    ``mla_decode``: each live page is streamed once for all ``Hq`` heads.
-    Elsewhere the slot's pages are gathered and attended in XLA."""
+    ``mla_decode``: each live page of a slot that runs is streamed once
+    for all ``Hq`` heads. Elsewhere the slot's pages are gathered and
+    attended in XLA."""
     bl = int(pages.shape[2])
     itemsize = jnp.dtype(pages.dtype).itemsize
     on_cpu = _on_cpu()
-    if paged_decode_supported(bl, q.shape[-1], itemsize) and (
-        not on_cpu or interpret
-    ):
+    if paged_decode_supported(
+        bl, q.shape[-1], itemsize, lanes=pages.shape[3]
+    ) and (not on_cpu or interpret):
+        if valid is None:
+            valid = jnp.ones(positions.shape, jnp.int32)
         return _paged_decode_pallas(
-            q, pages, None, block_table, positions, layer=layer,
-            block_kv=int(block_kv or _default_block_kv(bl, itemsize)),
-            interpret=on_cpu or bool(interpret), scale=scale, d_v=d_v,
-            name="mla_decode",
+            q, pages, None, block_table, positions, valid, layer=layer,
+            block_kv=int(block_kv or _default_block_kv(
+                bl, itemsize, pages.shape[3])),
+            interpret=on_cpu or bool(interpret), scale=float(scale),
+            d_v=int(d_v), name="mla_decode",
         )
     ctx = paged_gather(pages, block_table, layer=layer)      # (S, T, Dk)
     logits = jnp.einsum(
@@ -432,7 +520,8 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         )
     itemsize = jnp.dtype(k_pages.dtype).itemsize
     on_cpu = _on_cpu()
-    kernel_can_run = c == 1 and paged_decode_supported(bl, d, itemsize)
+    kernel_can_run = c == 1 and paged_decode_supported(
+        bl, d, itemsize, lanes=h_kv * d)
     if (impl is None or block_kv is None) and c == 1:
         # Tunable surface (tune kernel "paged_decode"): impl is a REAL
         # structural axis (fused pallas kernel vs XLA gather) and
@@ -459,7 +548,7 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         # asked to run interpreted), the XLA gather everywhere else.
         impl = "pallas" if kernel_can_run and (not on_cpu or interpret) \
             else "xla"
-    block_kv = block_kv or _default_block_kv(bl, itemsize)
+    block_kv = block_kv or _default_block_kv(bl, itemsize, 2 * h_kv * d)
     if impl not in ("pallas", "xla"):
         raise ValueError(
             f"paged_attention: unknown impl {impl!r} — the table is "
@@ -470,10 +559,10 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         # switch to the other path.
         raise ValueError(
             f"paged_attention: impl='pallas' cannot run here (C={c}, "
-            f"block_len={bl}, head_dim={d}, itemsize={itemsize}) — the "
-            "fused kernel is C=1 decode only and needs "
-            "paged_decode_supported(block_len, head_dim, itemsize); "
-            "pin impl='xla' for this shape"
+            f"block_len={bl}, head_dim={d}, lanes={h_kv * d}, "
+            f"itemsize={itemsize}) — the fused kernel is C=1 decode only "
+            "and needs paged_decode_supported(block_len, head_dim, "
+            "itemsize, lanes=Hkv*D); pin impl='xla' for this shape"
         )
 
     k_pages, v_pages = write_kv_pages(
@@ -482,13 +571,17 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
     )
 
     if impl == "pallas":
-        if block_kv % _SUBLANE.get(itemsize, 8) or bl % block_kv:
+        if block_kv % _SUBLANE.get(itemsize, 8) or (
+            bl % block_kv and block_kv % bl
+        ):
             raise ValueError(
                 f"paged_attention: block_kv={block_kv} must be a "
-                f"multiple of the sublane tile dividing block_len={bl}"
+                f"multiple of the sublane tile that divides "
+                f"block_len={bl} or is a multiple of it"
             )
         out = _paged_decode_pallas(
-            q[:, 0], k_pages, v_pages, block_table, positions, layer=layer,
+            q[:, 0], k_pages, v_pages, block_table, positions, valid,
+            layer=layer,
             block_kv=int(block_kv), interpret=on_cpu or bool(interpret),
         ).reshape(s, 1, hq * d)
         return out, k_pages, v_pages

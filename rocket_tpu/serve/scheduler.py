@@ -219,6 +219,16 @@ class Scheduler:
             run = self._grow_tables()
             sp.set(evicted=self.preemptions - evicted)
         if run.any():
+            # The counter serve/decode_pages: how much of the block table
+            # this wave's running slots hold, which is all the decode
+            # kernel walks (``live`` pages of ``table`` entries).
+            with span("serve/decode_pages", tick=self.ticks) as sp:
+                if sp.on:
+                    sp.set(
+                        live=int((self.lengths[run] // self.block_len
+                                  + 1).sum()),
+                        table=int(self.block_table.size),
+                    )
             self.pending = self.engine.decode_dispatch(
                 self.block_table, self.lengths, self.last_tok, run,
                 self.limits, self.temp, self.top_k, self.top_p, self.eos,

@@ -241,22 +241,22 @@ def _decode_legal(config, shape, spec, dtype) -> list:
 
 
 def _paged_legal(config, shape, spec, dtype) -> list:
-    """paged_decode: for the fused kernel, ``block_kv`` must tile the
-    pool page (sublane multiple dividing block_len, which itself must
-    be sublane-tileable for the dtype) and the per-step streamed blocks
-    must fit VMEM. For ``impl="xla"`` block_kv is INERT (the gather
-    path never reads it) — it is pinned to the default so the cross
-    product enumerates ONE xla candidate instead of timing
-    byte-identical programs once per block_kv value."""
-    from rocket_tpu.ops.paged_attention import _default_block_kv
-
+    """paged_decode: for the fused kernel, ``block_kv`` — the rows of one
+    streamed tile — must be a sublane multiple that divides the pool page
+    or is a multiple of it (part of a page, or whole pages, a copy), the
+    page itself sublane-tileable for the dtype, the pool's rows a
+    multiple of 128 lanes (Mosaic slices an HBM array at its tiling), and
+    the double-buffered tiles must fit VMEM. For ``impl="xla"`` block_kv
+    is INERT (the gather path never reads it) — it is pinned to the
+    default so the cross product enumerates ONE xla candidate instead of
+    timing byte-identical programs once per block_kv value."""
     bl, d = shape["bl"], shape["d"]
     block_kv = config["block_kv"]
     problems = []
     if d % 8:
         problems.append(f"head_dim={d} % 8 (lane-minor tiling)")
     if config["impl"] == "xla":
-        default_kv = _default_block_kv(bl)
+        default_kv = _paged_default(shape)["block_kv"]
         if block_kv != default_kv:
             problems.append(
                 f"block_kv={block_kv} is inert for impl=xla — only the "
@@ -272,14 +272,19 @@ def _paged_legal(config, shape, spec, dtype) -> list:
             f"block_len={bl} % {sublane_min(dtype)} sublane tile "
             f"({dtype}) — the fused kernel cannot tile this pool page"
         )
+    if (shape["hkv"] * d) % 128:
+        problems.append(
+            f"pool rows of {shape['hkv'] * d} lanes, no multiple of 128 — "
+            "the fused kernel cannot copy them out of HBM"
+        )
     if block_kv % sublane_min(dtype):
         problems.append(
             f"block_kv={block_kv} % {sublane_min(dtype)} sublane tile "
             f"({dtype})"
         )
-    if bl % block_kv:
-        problems.append(f"block_kv={block_kv} does not divide "
-                        f"block_len={bl}")
+    if bl % block_kv and block_kv % bl:
+        problems.append(f"block_kv={block_kv} neither divides "
+                        f"block_len={bl} nor is a multiple of it")
     if spec is not None:
         # Double-buffered K+V tiles (every kv head rides one tile) + the
         # q/out/accumulator residents (every query head).
@@ -297,11 +302,12 @@ def _paged_legal(config, shape, spec, dtype) -> list:
 
 def _paged_default(shape) -> dict:
     """An untuned checkout's behavior: the fused kernel (TPU decode
-    waves; CPU dispatch falls back to the XLA path regardless) with one
-    page — or its largest power-of-two divisor — streamed per step."""
+    waves; CPU dispatch falls back to the XLA path regardless) with the
+    tile height the op derives from the page and the row's width."""
     from rocket_tpu.ops.paged_attention import _default_block_kv
 
-    return {"impl": "pallas", "block_kv": _default_block_kv(shape["bl"])}
+    return {"impl": "pallas", "block_kv": _default_block_kv(
+        shape["bl"], row_lanes=2 * shape["hkv"] * shape["d"])}
 
 
 #: Hand-picked defaults, single-sourced: the TuneSpace ``default``
@@ -484,7 +490,7 @@ TUNE_SPACES: dict[str, TuneSpace] = {
         TuneSpace(
             kernel="paged_decode",
             axes={"impl": ("pallas", "xla"),
-                  "block_kv": (8, 16, 32, 64, 128)},
+                  "block_kv": (8, 16, 32, 64, 128, 256, 512)},
             shape_keys=("s", "mb", "bl", "hkv", "hq", "d"),
             default=_paged_default,
             legal=_paged_legal,
@@ -493,7 +499,8 @@ TUNE_SPACES: dict[str, TuneSpace] = {
                 "impl is a structural axis (fused VMEM-streaming pallas "
                 "kernel vs the XLA gather path — the tuner measures "
                 "both and may pin XLA on shapes where the gather wins), "
-                "block_kv the per-grid-step streamed KV tile height",
+                "block_kv the rows of one streamed KV tile (part of a "
+                "page, or whole pages, a compute step)",
         ),
         TuneSpace(
             kernel="moe_gmm",

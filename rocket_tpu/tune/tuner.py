@@ -737,7 +737,7 @@ def _builtin_cases() -> list:
                         h_kv=2, dtype=bf16, smoke=True),
         _decode_case("decode/smoke", b=2, hq=2, h_kv=2, d=64, t=128,
                      dtype=bf16, smoke=True),
-        _paged_case("paged/smoke", s=2, mb=2, bl=16, hkv=2, hq=2, d=16,
+        _paged_case("paged/smoke", s=2, mb=2, bl=16, hkv=2, hq=2, d=64,
                     dtype=jnp.float32, smoke=True),
         _bn_case("bn/smoke", b=8, hw=8, c=16, dtype=bf16, smoke=True),
         _fused_conv_case("fused_conv/smoke", b=8, hw=8, c=16,

@@ -26,8 +26,9 @@ from rocket_tpu.models.transformer import (  # noqa: E402
 
 
 def tiny_config(vocab_size=512):
+    # 4 heads x 32 = 128 lanes: the least pool row the fused kernel copies.
     return TransformerConfig(
-        vocab_size=vocab_size, max_seq_len=128, dim=64, num_layers=2,
+        vocab_size=vocab_size, max_seq_len=128, dim=128, num_layers=2,
         num_heads=4, dropout=0.0, activation_dtype="bfloat16", loss_chunk=32,
     )
 

@@ -271,28 +271,52 @@ def test_yarn_frequencies_and_scale_against_hand_computed_values():
 
 # -- (f) the generalised kernel, interpreted -------------------------------------
 
+#: Waves of ``(position, valid)`` a slot against 4 pages of 16 rows (None:
+#: a free slot, its table zeros; ``valid`` 0: it does not run this wave).
+_WAVES = {
+    "all_run": [(5, 1), (37, 1), (63, 1)],
+    "free_slot": [None, (37, 1), (63, 1)],
+    "valid0_long_context": [(62, 0), (5, 1), (63, 1)],
+    "context_of_1": [(0, 1), (0, 1), (0, 1)],
+    "exactly_one_page": [(15, 1), (15, 1), (0, 1)],
+    "one_page_plus_1_row": [(16, 1), (16, 1), (15, 1)],
+    "full_table": [(63, 1), (63, 1), (63, 1)],
+    "ragged": [None, (62, 0), (0, 1), (15, 1), (16, 1), (63, 1)],
+}
+
+
+@pytest.mark.parametrize("wave", list(_WAVES))
 @pytest.mark.parametrize("g,dk,dv", [(128, 640, 512), (8, 128, 32)])
-def test_latent_decode_kernel_interpreted_matches_xla(g, dk, dv):
+def test_latent_decode_kernel_interpreted_matches_xla(g, dk, dv, wave):
     """``h_kv`` 1, ``g`` query heads, K = the whole ``dk``-lane row, V = its
     first ``dv`` lanes: the kernel body interpreted against the gathered
-    XLA attention. bfloat16 operands at the real widths (128 / 576 in 640 / 512,
-    few pages), float32 in miniature."""
+    XLA attention over ragged waves, at the tile nobody pinned and at one
+    of two pages (a context's last tile half dead). bfloat16 operands at
+    the real widths (128 / 576 in 640 / 512, few pages), float32 in
+    miniature. A slot that does not run gives a finite row."""
     from rocket_tpu.ops.paged_attention import paged_latent_decode
 
     dtype, tol = (jnp.bfloat16, 2e-2) if dk == 640 else (jnp.float32, 2e-5)
-    s, mb, bl, nb = 3, 4, 16, 14
+    slots = _WAVES[wave]
+    s, mb, bl, nb = len(slots), 4, 16, 1 + 4 * len(slots)
     ks = jax.random.split(jax.random.key(0), 2)
     pages = (jax.random.normal(ks[0], (2, nb, bl, dk)) * 0.5).astype(dtype)
     q = (jax.random.normal(ks[1], (s, g, dk)) * 0.5).astype(dtype)
-    table = jnp.asarray(np.random.default_rng(0).permutation(np.arange(1, nb))[:s * mb]
-                        .reshape(s, mb), jnp.int32)
-    positions = jnp.asarray([5, 37, 63], jnp.int32)
+    table = np.random.default_rng(0).permutation(np.arange(1, nb))[:s * mb].reshape(s, mb)
+    table[[slot is None for slot in slots]] = 0
+    positions, valid = (jnp.asarray([0 if slot is None else slot[i] for slot in slots], jnp.int32)
+                        for i in (0, 1))
+    table = jnp.asarray(table, jnp.int32)
     kw = dict(layer=1, d_v=dv, scale=0.11)
-    want = paged_latent_decode(q, pages, table, positions, **kw)
-    got = paged_latent_decode(q, pages, table, positions, interpret=True, **kw)
-    assert got.shape == (s, g, dv)
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
-                               atol=tol, rtol=tol)
+    want = np.asarray(paged_latent_decode(q, pages, table, positions, valid, **kw), np.float32)
+    run = np.asarray(valid) > 0
+    for block_kv in (None, 32):
+        got = paged_latent_decode(q, pages, table, positions, valid, interpret=True,
+                                  block_kv=block_kv, **kw)
+        assert got.shape == (s, g, dv)
+        got = np.asarray(got, np.float32)
+        np.testing.assert_allclose(got[run], want[run], atol=tol, rtol=tol)
+        assert np.isfinite(got).all()
 
 
 # -- the engine end to end -------------------------------------------------------

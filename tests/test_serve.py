@@ -587,10 +587,14 @@ def test_scanned_eviction_backpressure_resume_equivalence(tiny_lm):
 # The pallas paged-decode kernel (ISSUE 11 tentpole)
 # ---------------------------------------------------------------------------
 
-def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32,
-                    layers=1):
+def _paged_operands(s=3, hq=4, hkv=2, d=64, bl=16, mb=4, dtype=np.float32,
+                    layers=1, wave=None):
     """One C=1 wave's operands against a pool in the stored layout,
-    ``(layers, NB, BL, Hkv*D)``."""
+    ``(layers, NB, BL, Hkv*D)`` (128 lanes unless told otherwise: the
+    fused kernel's gate). ``wave``: ``[(position, valid), ...]`` a slot,
+    ``None`` for a free one (position 0, valid 0, a table of zeros)."""
+    if wave is not None:
+        s = len(wave)
     rng = np.random.default_rng(11)
     nb = 1 + s * mb
     q = jnp.asarray(rng.normal(size=(s, 1, hq, d)).astype(np.float32)) \
@@ -609,6 +613,13 @@ def _paged_operands(s=3, hq=4, hkv=2, d=16, bl=16, mb=4, dtype=np.float32,
     # Positions spanning page-start, mid-page and the full context.
     positions = jnp.asarray([0, bl + 3, mb * bl - 1], jnp.int32)[:s]
     valid = jnp.ones((s,), jnp.int32)
+    if wave is not None:
+        free = jnp.asarray([slot is None for slot in wave])
+        table = jnp.where(free[:, None], 0, table)
+        positions, valid = (
+            jnp.asarray([0 if slot is None else slot[i] for slot in wave],
+                        jnp.int32) for i in (0, 1)
+        )
     return q, k_new, v_new, k_pages, v_pages, table, positions, valid
 
 
@@ -648,10 +659,11 @@ def _dense_reference(q, k_new, v_new, k_pages, v_pages, table, positions,
 
 #: (Hq, Hkv, D): MHA at the chat cell's 20 x 64 (1280 lanes, ten lane
 #: tiles), GQA with Hkv < Hq, and an Hkv*D that is no multiple of 128
-#: (3 x 8 = 24 lanes: legal because a block spans the whole lane axis).
+#: (3 x 8 = 24 lanes: the XLA path's alone — the fused kernel copies
+#: whole rows out of HBM, which Mosaic slices at 128 lanes only).
 _LAYOUT_GEOMETRIES = {
     "mha_20x64": (20, 20, 64),
-    "gqa_6over2": (6, 2, 16),
+    "gqa_6over2": (6, 2, 64),
     "lanes_24": (3, 3, 8),
 }
 
@@ -670,6 +682,11 @@ def test_paged_attention_matches_dense_reference(geometry, impl):
     ops = _paged_operands(hq=hq, hkv=hkv, d=d, layers=3)
     q, k_new, v_new, k_pages, v_pages, table, positions, valid = ops
     assert k_pages.shape == (3, 13, 16, hkv * d)
+    if impl == "pallas" and (hkv * d) % 128:
+        # A pinned kernel that cannot run is an error, never the other path.
+        with pytest.raises(ValueError, match="cannot run here"):
+            paged_attention(*ops, layer=layer, impl=impl, interpret=True)
+        return
     out, kp, vp = paged_attention(
         *ops, layer=layer, impl=impl, interpret=impl == "pallas"
     )
@@ -727,28 +744,60 @@ def test_paged_prefill_chunk_matches_dense_reference(geometry):
     np.testing.assert_array_equal(got_k[1, 0, 1:], want_k[1, 0, 1:])
 
 
+#: Waves of ``(position, valid)`` a slot against 4 pages of 16 rows (None:
+#: a free slot, its table zeros). A slot with ``valid`` 0 does not run:
+#: free, or mid-prefill with a long context the wave must not pay for.
+_FULL = 4 * 16 - 1
+_WAVES = {
+    "all_run": [(0, 1), (16 + 3, 1), (_FULL, 1)],
+    "free_slot": [None, (16 + 3, 1), (_FULL, 1)],
+    "valid0_long_context": [(_FULL - 1, 0), (5, 1), (_FULL, 1)],
+    "context_of_1": [(0, 1), (0, 1), (0, 1)],
+    "exactly_one_page": [(15, 1), (15, 1), (0, 1)],
+    "one_page_plus_1_row": [(16, 1), (16, 1), (15, 1)],
+    "full_table": [(_FULL, 1), (_FULL, 1), (_FULL, 1)],
+    "none_runs": [None, (40, 0), None],
+    "ragged": [None, (_FULL - 1, 0), (0, 1), (15, 1), (16, 1), (_FULL, 1),
+               None],
+}
+
+
+def _assert_wave_rows(out, ref, valid, tol, err_msg=""):
+    """The rows of the slots that run equal the reference's; the others
+    are finite (the wave ignores them)."""
+    out, run = np.asarray(out, np.float32), np.asarray(valid) > 0
+    np.testing.assert_allclose(
+        out[run], np.asarray(ref, np.float32)[run], atol=tol, rtol=tol,
+        err_msg=err_msg,
+    )
+    assert np.isfinite(out).all(), err_msg
+
+
+@pytest.mark.parametrize("wave", list(_WAVES))
 @pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 4), (6, 2)])
-def test_paged_decode_pallas_matches_xla_on_cpu_interpret(hq, hkv):
+def test_paged_decode_pallas_matches_xla_on_cpu_interpret(hq, hkv, wave):
     """Fused-kernel vs XLA-gather parity on CPU-interpretable shapes
     (GQA g=2, MHA g=1 and g=3 — the kernel picks each kv head's lane
-    slice itself): outputs allclose at every legal block_kv and the
-    scattered pool bitwise identical (the scatter is shared)."""
+    slice itself) over ragged waves: the running slots' outputs allclose
+    at every legal block_kv — part of a page, one page, several — a slot
+    that does not run gives a finite row, and the scattered pool is
+    bitwise identical (the scatter is shared)."""
     from rocket_tpu.ops.paged_attention import paged_attention
 
-    ops = _paged_operands(hq=hq, hkv=hkv)
+    ops = _paged_operands(hq=hq, hkv=hkv, wave=_WAVES[wave])
+    valid = ops[-1]
     ref, kx, vx = paged_attention(*ops, impl="xla")
-    for block_kv in (8, 16):
+    for block_kv in (8, 16, 64):
         out, kp, vp = paged_attention(
             *ops, impl="pallas", block_kv=block_kv, interpret=True
         )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5,
-            err_msg=f"block_kv={block_kv}",
-        )
+        _assert_wave_rows(out, ref, valid, 1e-5, f"block_kv={block_kv}")
         np.testing.assert_array_equal(np.asarray(kp), np.asarray(kx))
         np.testing.assert_array_equal(np.asarray(vp), np.asarray(vx))
     with pytest.raises(ValueError, match="block_kv"):
         paged_attention(*ops, impl="pallas", block_kv=12, interpret=True)
+    with pytest.raises(ValueError, match="block_kv"):
+        paged_attention(*ops, impl="pallas", block_kv=24, interpret=True)
     with pytest.raises(ValueError, match="impl"):
         paged_attention(*ops, impl="mosaic")
 
@@ -781,14 +830,23 @@ def test_paged_decode_supported_gate():
         paged_decode_supported,
     )
 
-    assert paged_decode_supported(16, 64, 4)        # f32, one sublane tile
-    assert paged_decode_supported(16, 64, 2)        # bf16 at 16 rows
-    assert not paged_decode_supported(8, 64, 2)     # bf16 needs 16 rows
-    assert not paged_decode_supported(4, 64, 4)     # sub-sublane page
-    assert not paged_decode_supported(16, 12, 4)    # D % 8
-    assert _default_block_kv(16) == 16
-    assert _default_block_kv(256) == 128
-    assert _default_block_kv(32, itemsize=2) == 32
+    assert paged_decode_supported(16, 64, 4, lanes=128)   # f32, one sublane tile
+    assert paged_decode_supported(16, 64, 2, lanes=1280)  # bf16 at 16 rows
+    assert not paged_decode_supported(8, 64, 2, lanes=128)   # bf16 needs 16 rows
+    assert not paged_decode_supported(4, 64, 4, lanes=128)   # sub-sublane page
+    assert not paged_decode_supported(16, 12, 4, lanes=384)  # D % 8
+    assert not paged_decode_supported(16, 64, 4, lanes=192)  # HBM rows: 128 lanes
+    assert not paged_decode_supported(16, 8, 4, lanes=24)
+    # Nobody pinned a tile: up to 512 rows of context a step, as whole
+    # pages or part of one, fewer where both buffers of a wide row would
+    # overrun the VMEM budget.
+    assert _default_block_kv(16) == 512
+    assert _default_block_kv(1024) == 512
+    assert _default_block_kv(48) == 16              # 512 ... 32 fit no page of 48
+    assert _default_block_kv(16, 2, row_lanes=2 * 1280) == 128   # chat
+    assert _default_block_kv(64, 2, row_lanes=640) == 512        # docqa
+    assert _default_block_kv(16, 2, row_lanes=2 * 768) == 256    # GPT-2 124M
+    assert _default_block_kv(16, 2, row_lanes=2 * 65536) == 16
 
 
 # ---------------------------------------------------------------------------
@@ -891,3 +949,52 @@ def test_reset_metrics_windows_registry_histograms(tiny_lm):
     engine.drain()
     hists = telemetry.registry.snapshot()["histograms"]
     assert hists["serve/ttft_s"]["count"] == 1
+
+
+def test_scheduler_records_decode_pages_per_dispatched_wave(tiny_lm):
+    """The counter ``serve/decode_pages``: one zero-length record per
+    dispatched wave while spans are on, with ``live`` = the pages the
+    wave's RUNNING slots hold (``lengths // block_len + 1`` each: a slot
+    still prefilling holds pages and is not counted) and ``table`` =
+    ``max_slots x max_blocks_per_seq``; off, nothing is recorded."""
+    from rocket_tpu.obs import spans
+
+    model, variables = tiny_lm
+    engine = ServeEngine(
+        model, variables["params"],
+        ServeConfig(max_slots=4, block_len=4, prefill_chunk=4,
+                    max_model_len=32),
+    )
+    sched = engine.scheduler
+    want, dispatch = [], engine.engine.decode_dispatch
+
+    def counted(block_table, lengths, last_tok, run_mask, *rest):
+        want.append(int(sum(n // 4 + 1 for n in lengths[run_mask])))
+        return dispatch(block_table, lengths, last_tok, run_mask, *rest)
+
+    engine.engine.decode_dispatch = counted
+    recorder = spans.SpanRecorder()
+    spans.install(recorder)
+    try:
+        for n in (3, 9, 14):        # 14 = four chunks: prefilling while others run
+            engine.submit(np.arange(1, 1 + n, dtype=np.int32),
+                          max_new_tokens=5, temperature=0.0)
+        engine.drain()
+    finally:
+        spans.uninstall(recorder)
+    records = [e for e in recorder.events() if e.name == "serve/decode_pages"]
+    assert len(records) == len(want) == engine.engine.decode_dispatches > 0
+    assert [e.ids["live"] for e in records] == want
+    assert {e.ids["table"] for e in records} == {4 * 8}
+    assert all(e.start <= e.end for e in records)
+    # A wave with one running slot of 3..4 tokens holds one or two pages;
+    # no wave holds the whole table.
+    assert 1 <= min(want) <= 2 and max(want) < 4 * 8
+    assert len({e.ids["tick"] for e in records}) == len(records)
+
+    before = len(spans.recorded())
+    engine.submit(np.arange(1, 4, dtype=np.int32), max_new_tokens=2,
+                  temperature=0.0)
+    engine.drain()
+    assert not [e for e in spans.recorded()[before:]
+                if e.name == "serve/decode_pages"]

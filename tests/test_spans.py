@@ -325,15 +325,16 @@ def test_serve_tick_children_lie_inside_their_tick_and_do_not_overlap(tiny_lm, s
         for a, b in zip(children, children[1:]):
             assert a.end <= b.start, (a.name, b.name)
         # The order of a tick: admit, enqueue a chunk, harvest, replay,
-        # grow, dispatch.
+        # grow, the wave's page count, dispatch.
         order = [c.name for c in children]
         assert order == sorted(order, key=[
             "serve/admit", "serve/prefill_enqueue", "serve/harvest_wait",
-            "serve/replay", "serve/grow", "serve/dispatch",
+            "serve/replay", "serve/grow", "serve/decode_pages",
+            "serve/dispatch",
         ].index)
     assert kinds == {
         "serve/admit", "serve/prefill_enqueue", "serve/harvest_wait",
-        "serve/replay", "serve/grow", "serve/dispatch",
+        "serve/replay", "serve/grow", "serve/decode_pages", "serve/dispatch",
     }
     # One identifier joins a dispatch, the fetch that waited for it, its
     # replay and the tracer's wave record.

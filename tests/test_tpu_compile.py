@@ -108,7 +108,7 @@ def test_decode_attention_gpt2(sds):
         (12, 12, 64, 16, jnp.bfloat16),    # GPT-2 124M: g = 1
         (12, 4, 64, 16, jnp.bfloat16),     # llama-style 12/4 preset: g = 3
         (32, 8, 128, 128, jnp.bfloat16),   # lane-aligned heads, big pages
-        (4, 4, 8, 8, jnp.float32),         # the support gate's low edge
+        (16, 16, 8, 8, jnp.float32),       # the support gate's low edge
     ],
     ids=["gpt2_g1", "gqa_g3", "d128_bl128", "gate_edge"],
 )
@@ -123,18 +123,19 @@ def test_paged_decode_kernel(sds, hq, hkv, d, block_len, dtype):
     )
 
     itemsize = jnp.dtype(dtype).itemsize
-    assert paged_decode_supported(block_len, d, itemsize)
+    assert paged_decode_supported(block_len, d, itemsize, lanes=hkv * d)
     slots, ctx = 16, 1024
     mb = ctx // block_len
     pool = sds((1, slots * mb, block_len, hkv * d), dtype)
     _compile(
         functools.partial(
             _paged_decode_pallas,
-            block_kv=_default_block_kv(block_len, itemsize),
+            block_kv=_default_block_kv(block_len, itemsize, 2 * hkv * d),
             interpret=False,
         ),
         sds((slots, hq, d), dtype), pool, pool,
         sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32),
     )
 
 
@@ -148,7 +149,7 @@ def _kernel_instructions(text):
 
     return [
         m.group(1) for m in re.finditer(
-            r"^\s*%?(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            r"^\s*(?:ROOT )?%?(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
             text, re.M,
         )
     ]
@@ -197,14 +198,45 @@ def test_paged_decode_kernel_carries_its_name_at_the_chat_cell_width(sds):
     pool = sds((1, 1 + slots * mb, bl, h * d), jnp.bfloat16)
     text = _compile(
         functools.partial(
-            _paged_decode_pallas, block_kv=_default_block_kv(bl, 2),
+            _paged_decode_pallas,
+            block_kv=_default_block_kv(bl, 2, 2 * h * d),
             interpret=False,
         ),
         sds((slots, h, d), jnp.bfloat16), pool, pool,
         sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32),
     )
     names = _kernel_instructions(text)
     assert any("paged_decode" in n for n in names), names
+
+
+def test_latent_decode_kernel_carries_its_name_at_the_docqa_cell_width(sds):
+    """The latent pool of the docqa cell (128 query heads over ONE 640-lane
+    row a token, values its first 512 lanes, 32 slots x 8192 of block 64):
+    the same body with no V array, at the tile nobody pinned."""
+    from rocket_tpu.ops.paged_attention import (
+        _default_block_kv,
+        _paged_decode_pallas,
+        paged_decode_supported,
+    )
+
+    slots, ctx, bl, h, lanes = 32, 8192, 64, 128, 640
+    mb = ctx // bl
+    assert paged_decode_supported(bl, lanes, 2, lanes=lanes)
+    assert _default_block_kv(bl, 2, lanes) == 512
+    text = _compile(
+        functools.partial(
+            _paged_decode_pallas, block_kv=_default_block_kv(bl, 2, lanes),
+            interpret=False, scale=0.1, d_v=512, name="mla_decode",
+        ),
+        sds((slots, h, lanes), jnp.bfloat16),
+        sds((1, 1 + slots * mb, bl, lanes), jnp.bfloat16), None,
+        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32),
+    )
+    names = _kernel_instructions(text)
+    assert any("mla_decode" in n for n in names), names
+    assert not any("paged_decode" in n for n in names), names
 
 
 # -- the KV pool is read and written where it lies ---------------------------

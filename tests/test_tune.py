@@ -541,22 +541,32 @@ def test_paged_decode_space_axes_and_legality():
     assert set(space.axes["impl"]) == {"pallas", "xla"}
     spec = device_spec("TPU v5 lite")
     candidates = space.candidates(PAGED_SHAPE, spec, "bfloat16")
-    # bf16 sublane is 16 and bl=16: block_kv=16 is the only legal tile,
-    # once per impl.
+    # bf16 sublane is 16 and bl=16: a tile is whole pages — 16 rows and
+    # every multiple on the axis — and the XLA path is enumerated once,
+    # at the default.
     assert candidates == [
-        {"block_kv": 16, "impl": "pallas"},
-        {"block_kv": 16, "impl": "xla"},
-    ]
+        {"block_kv": kv, "impl": "pallas"}
+        for kv in (16, 32, 64, 128, 256, 512)
+    ] + [{"block_kv": 512, "impl": "xla"}]
     f32 = space.candidates(PAGED_SHAPE, spec, "float32")
-    assert {"block_kv": 8, "impl": "pallas"} in f32
+    assert {"block_kv": 8, "impl": "pallas"} in f32      # half a page
     assert space.violations(
         {"impl": "pallas", "block_kv": 12}, PAGED_SHAPE, spec, "float32"
     )  # not an axis member
     assert space.violations(
-        {"impl": "pallas", "block_kv": 32}, PAGED_SHAPE, spec, "float32"
-    )  # does not divide bl=16
-    # Default = untuned behavior: the fused kernel, one page per step.
-    assert space.default(PAGED_SHAPE) == {"impl": "pallas", "block_kv": 16}
+        {"impl": "pallas", "block_kv": 32}, dict(PAGED_SHAPE, bl=48), spec,
+        "float32",
+    )  # neither divides bl=48 nor is a multiple of it
+    assert any("128" in v for v in space.violations(
+        {"impl": "pallas", "block_kv": 16}, dict(PAGED_SHAPE, hkv=3), spec,
+        "float32",
+    ))  # rows of 192 lanes: the kernel cannot copy them out of HBM
+    # Default = untuned behavior: the fused kernel, the tile height the
+    # op derives (512 rows here; 128 at the chat cell's 2 x 1280 lanes).
+    assert space.default(PAGED_SHAPE) == {"impl": "pallas", "block_kv": 512}
+    assert space.default(
+        {"s": 32, "mb": 64, "bl": 16, "hkv": 20, "hq": 20, "d": 64}
+    )["block_kv"] == 128
     assert "s" in space.shape_keys and "hq" in space.shape_keys
 
 
@@ -566,7 +576,7 @@ def test_paged_decode_table_resolution(table_dir):
     a geometry the kernel supports."""
     from rocket_tpu.ops.paged_attention import paged_attention
 
-    shape = {"s": 2, "mb": 2, "bl": 16, "hkv": 2, "hq": 2, "d": 16}
+    shape = {"s": 2, "mb": 2, "bl": 16, "hkv": 2, "hq": 2, "d": 64}
     tune.write_table("paged_decode", [{
         "device_kind": "TPU v5 lite", "dtype": "float32",
         "shape": shape,
@@ -574,9 +584,9 @@ def test_paged_decode_table_resolution(table_dir):
         "config": {"impl": "xla", "block_kv": 16},
     }], configs_dir=table_dir)
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(2, 1, 2, 16)).astype(np.float32))
-    kn = jnp.asarray(rng.normal(size=(2, 1, 2, 16)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(1, 5, 16, 2 * 16)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(2, 1, 2, 64)).astype(np.float32))
+    kn = jnp.asarray(rng.normal(size=(2, 1, 2, 64)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(1, 5, 16, 2 * 64)).astype(np.float32))
     table = jnp.asarray(np.asarray([[1, 2], [3, 4]], np.int32))
     pos = jnp.asarray([3, 17], jnp.int32)
     valid = jnp.ones((2,), jnp.int32)
