@@ -86,6 +86,53 @@ def median(values) -> float:
     return float(statistics.median(values))
 
 
+#: A request counts toward ``tpot_p90_ms`` with at least this many gaps.
+TPOT_MIN_GAPS = 8
+
+
+def token_gaps(token_times, lo: float, hi: float) -> list[list[float]]:
+    """Per request (a list of its tokens' delivery times, seconds), the
+    gaps in ms between successive tokens whose LATER token is delivered in
+    ``[lo, hi)``. Requests with no such gap are left out."""
+    per_request = [
+        [(b - a) * 1e3 for a, b in zip(times, times[1:]) if lo <= b < hi]
+        for times in token_times
+    ]
+    return [gaps for gaps in per_request if gaps]
+
+
+def gap_metrics(token_times, seconds: float) -> dict:
+    """The statistics of the gap between tokens, over the window
+    ``[0, seconds)``. ``tpot_p90_ms``: per request the MEAN of its gaps
+    (time per output token), over requests with ``TPOT_MIN_GAPS`` gaps or
+    more, then the 90th percentile over those requests; ``tpot_mean_ms``
+    the mean over the same requests; ``itl_p95_ms`` the 95th percentile of
+    the single gaps of every request. A statistic with nothing under it is
+    None."""
+    per_request = token_gaps(token_times, 0.0, seconds)
+    single = [g for gaps in per_request for g in gaps]
+    means = [sum(g) / len(g) for g in per_request if len(g) >= TPOT_MIN_GAPS]
+    return {
+        "tpot_p90_ms": percentile(means, 90) if means else None,
+        "tpot_mean_ms": sum(means) / len(means) if means else None,
+        "itl_p95_ms": percentile(single, 95) if single else None,
+        "tpot_requests": len(means),
+        "gaps": len(single),
+    }
+
+
+def pick_sample(finished: list, every: int, seed: int = 0) -> list:
+    """The requests the reference runs over: every ``every``-th of
+    ``finished`` (``(prompt, served tokens)`` in submit order), starting at
+    ``seed % every``, and always the longest (prompt + answer)."""
+    every = max(1, int(every))
+    chosen = set(range(int(seed) % every, len(finished), every))
+    if finished:
+        chosen.add(max(range(len(finished)),
+                       key=lambda i: len(finished[i][0]) + len(finished[i][1])))
+    return [finished[i] for i in sorted(chosen)]
+
+
 def span(name: str, on: bool):
     """A host span in the profiler's own trace, or nothing."""
     return jax.profiler.TraceAnnotation(name) if on else contextlib.nullcontext()

@@ -187,25 +187,37 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
     clock = common.Clock(t_zero)
     trace = {}
     if tracing:
-        # The traced stretch is the END of the window.
+        # The traced stretch is the END of the window: ``bench/stretch`` is
+        # a span from its start to the close of the window, which the
+        # readers' trace is cut to. The profiler itself is stopped only
+        # once the loop has drained: stopping it takes a second in docqa
+        # and 85 s after a loaded chat stretch (a million device events),
+        # and inside the loop that blocks the engine. The Python tracer is
+        # off: no reader looks at Python calls, and they cost the host
+        # some 0.7 ms of every traced tick.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         original_turn = loop.turn
 
         def turn(clock_):
             if "t0" not in trace and clock_() >= seconds - trace_seconds:
-                jax.profiler.start_trace(trace_dir)
+                jax.profiler.start_trace(trace_dir, profiler_options=options)
+                trace["span"] = jax.profiler.TraceAnnotation("bench/stretch")
+                trace["span"].__enter__()
                 trace["t0"] = clock_()
             if "t0" in trace and "t1" not in trace and clock_() >= seconds:
-                jax.block_until_ready(engine.engine.k_pages)
                 trace["t1"] = clock_()
-                jax.profiler.stop_trace()
+                trace["span"].__exit__(None, None, None)
             original_turn(clock_)
 
         loop.turn = turn
     try:
         loop.run(clock)
     finally:
-        if "t0" in trace and "t1" not in trace:
-            trace["t1"] = clock()
+        if "t0" in trace:
+            if "t1" not in trace:
+                trace["t1"] = clock()
+                trace["span"].__exit__(None, None, None)
             jax.profiler.stop_trace()
     setup_s = t_zero - setup_clock.start
     common.note(setup_clock, "window closed, first tokens all in")
@@ -219,12 +231,10 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
             ttft.append(float("inf"))
         else:
             ttft.append((times[0] - arrivals[i].due_s) * 1e3)
-    gaps, delivered = [], 0
-    for times in loop.token_at.values():
-        delivered += sum(1 for t in times if 0.0 <= t < seconds)
-        gaps.extend(
-            (b - a) * 1e3 for a, b in zip(times, times[1:]) if 0.0 <= b < seconds
-        )
+    delivered = sum(
+        1 for times in loop.token_at.values() for t in times if 0.0 <= t < seconds
+    )
+    gap = common.gap_metrics(loop.token_at.values(), seconds)
     late = [
         (loop.submit_at[i] - arrivals[i].due_s) * 1e3
         for i in measured if i in loop.submit_at
@@ -235,7 +245,9 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
         "end_to_end": {
             "serve_tokens_per_s": delivered / seconds,
             "ttft_p90_ms": common.percentile(ttft, 90) if ttft else None,
-            "itl_p95_ms": common.percentile(gaps, 95) if gaps else None,
+            "tpot_p90_ms": gap["tpot_p90_ms"],
+            "tpot_mean_ms": gap["tpot_mean_ms"],
+            "itl_p95_ms": gap["itl_p95_ms"],
             "setup_s": setup_s,
         },
         "memory_peak_bytes": common.peak_bytes(devices),
@@ -251,6 +263,9 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
             common.percentile(finite, 50), common.percentile(finite, 75),
             common.percentile(finite, 90), sum(finite) / len(finite), max(finite),
             len(finite)))
+    common.note(setup_clock, "gap_ms tpot_p90 %s tpot_mean %s over %d requests; itl_p95 %s over %d gaps" % (
+        gap["tpot_p90_ms"], gap["tpot_mean_ms"], gap["tpot_requests"],
+        gap["itl_p95_ms"], gap["gaps"]))
     tracer = engine.tracer
     if tracer is not None:
         phases = [
@@ -259,9 +274,14 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
             for ph in [tracer.phases(rid)] if ph is not None
         ]
         result["host"]["queue_ms"] = [ph["queue_s"] * 1e3 for ph in phases]
-        result["host"]["prefill_phase_ms"] = [ph["prefill_s"] * 1e3 for ph in phases]
     if tracing and "t1" in trace:
         result["host"]["traced_s"] = trace["t1"] - trace["t0"]
+        # The same stretch on the clock of the program's span recorder.
+        result["host"]["stretch"] = (t_zero + trace["t0"], t_zero + trace["t1"])
+        result["host"]["itl_gap_ms"] = [
+            g for gaps in common.token_gaps(loop.token_at.values(), trace["t0"], trace["t1"])
+            for g in gaps
+        ]
         result["host"]["traced_flops"] = _flops_between(
             config, loop, arrivals, cell["engine"]["prefill_chunk"], trace["t0"], trace["t1"]
         )
@@ -272,11 +292,11 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
         for rid, i in loop.by_rid.items()
         if engine.result(rid).finished
     ]
-    # Every finished request is compared (some 3,000 served tokens, the
-    # longest request among them): the widest gap of a few hundred tokens
-    # swings too much to separate bfloat16 from the fp8 control.
+    # The float32 reference runs over a sample fixed by the cell's
+    # ``compare`` rule: the whole of a loaded window would take it longer
+    # than the window itself.
     result["finished"] = len(finished)
-    result["sample"] = sample = finished
+    result["sample"] = sample = common.pick_sample(finished, cell["compare"]["every"], seed)
     # Free the program's state before the reference takes the chip.
     del engine, loop, tracer
     t = time.perf_counter()

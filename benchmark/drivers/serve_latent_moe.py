@@ -7,10 +7,11 @@ operation count, the reference's comparison) taken from this
 configuration's own reference module.
 
 ``correct`` compares, like ``serve.py``, the widest gap by which a served
-token's reference logit lies below the reference's best — over a SAMPLE of
-the finished requests fixed by the cell's ``compare`` rule (every
-``every``-th in submit order and always the longest: the float32 reference
-of a 6,000-token request takes seconds), and leaving out the positions at
+token's reference logit lies below the reference's best — over the SAMPLE of
+the finished requests that ``serve.run`` picks by the cell's ``compare``
+rule (``common.pick_sample``: every ``every``-th in submit order and always
+the longest; the float32 reference of a 6,000-token request takes
+seconds), and leaving out the positions at
 which the reference's own router was within ``margin`` of choosing another
 expert (a bfloat16 score that near the boundary picks differently, and the
 logits then differ by more than rounding). Their share is a number of
@@ -103,17 +104,6 @@ def build_engine(cell: dict, config: dict, seed: int):
     ))
 
 
-def pick_sample(finished: list, rule: dict) -> list:
-    """Every ``every``-th finished request in submit order, and always the
-    longest (prompt + answer)."""
-    every = max(1, int(rule["every"]))
-    chosen = set(range(0, len(finished), every))
-    if finished:
-        chosen.add(max(range(len(finished)),
-                       key=lambda i: len(finished[i][0]) + len(finished[i][1])))
-    return [finished[i] for i in sorted(chosen)]
-
-
 def reference_numbers(config: dict, seed: int, sample: list, *, span: int,
                       quant=None, control: bool = False) -> list:
     """For each ``(prompt, served tokens)`` of ``sample``, per answer
@@ -203,7 +193,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
     compared = {}
     before = {id(a) for a in jax.live_arrays()}
 
-    def reference_gaps(config, seed, finished, *, span):
+    def reference_gaps(config, seed, sample, *, span):
         # serve.run has let go of the engine, but the engine's jitted
         # programs and their trace counters hold each other, and a traced
         # run's frame still holds the loop: what this run put on the
@@ -212,7 +202,6 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace_dir=None,
         for a in jax.live_arrays():
             if id(a) not in before:
                 a.delete()
-        sample = pick_sample(finished, rule)
         compared["per_request"] = reference_numbers(config, seed, sample, span=span)
         compared["tokens"] = int(sum(len(served) for _, served in sample))
         compared["requests"] = len(sample)

@@ -42,7 +42,9 @@ STRETCH = ("serve/tick", "train/wave")
 
 def load(ctx):
     """``(spans, (lo, hi))``: the recorded spans and the traced stretch on
-    the recorder's clock, or ``(None, None)`` where either is missing."""
+    the recorder's clock (``host["stretch"]`` where the driver names it,
+    else from the first ``serve/tick`` or ``train/wave`` recorded to the
+    last), or ``(None, None)`` where either is missing."""
     rows = ctx.get("spans")
     if rows is None:
         try:
@@ -51,6 +53,11 @@ def load(ctx):
             return None, None
         rows = recorded()
     spans = [Span(*row) for row in rows]
+    stretch = ctx.get("host", {}).get("stretch")
+    if stretch:
+        # The serving drivers keep the profiler (and so the spans) on until
+        # their loop has drained; the stretch they name ends at the close.
+        return spans, (float(stretch[0]), float(stretch[1]))
     marks = [s for s in spans if s.name in STRETCH]
     if not marks:
         return None, None

@@ -8,6 +8,7 @@ keeps JAX's compile cache at ``$JAX_COMPILATION_CACHE_DIR`` or
 ``<checkout>/.jax_cache``, runs the cell's driver and prints, as the last
 line of standard output, one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` on a traced run),
+``end_to_end`` (every statistic of the host's clock, in a traced run too),
 then ``checks``: every number compared beside its limit.
 
 Everything that belongs to one configuration, cell or per-layer metric is a
@@ -135,8 +136,11 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
     line = {"correct": False, "attempted": result["attempted"],
             "failed": result["failed"], "metrics": {}, "device": device}
     if trace:
-        events = trace_lib.load_xplane(trace_lib.newest_xplane(trace_dir))
+        clock = common.Clock(_PROCESS_START)
+        events = trace_lib.cut_to_span(
+            trace_lib.load_xplane(trace_lib.newest_xplane(trace_dir)))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        common.note(clock, "trace read")
         device["busy_s"] = trace_lib.busy_s(events)
         device["window_s"] = result["host"]["traced_s"]
         ctx = {
@@ -152,6 +156,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
             "device_ops": trace_lib.top_ops(events),
             "idle_gaps": trace_lib.idle_gaps(events),
         }
+        common.note(clock, "per-layer metrics and breakdown read")
     else:
         for metric in metrics_of(bench, "end_to_end", workload):
             value = result["end_to_end"].get(metric["name"])
@@ -159,6 +164,12 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
                 line["metrics"][metric["name"]] = {
                     "value": float(value), "unit": metric["unit"],
                 }
+    # Every statistic the driver took on the host's clock, traced run or
+    # not, beside the metrics the contract asks for (the driver of the
+    # checks ignores this key; a person comparing statistics reads it).
+    line["end_to_end"] = {
+        k: float(v) for k, v in result["end_to_end"].items() if v is not None
+    }
     ok, checks = common.judge(result.get("numbers", {}), cell["limits"])
     line["correct"] = bool(ok and result["failed"] == 0 and result["attempted"] > 0)
     line["checks"] = checks

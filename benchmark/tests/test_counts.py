@@ -43,5 +43,10 @@ def test_mfu_reader_divides_by_the_named_peak_and_refuses_an_unknown_device():
            "peaks": {"bf16_flops_per_s": 197e12}, "device_kind": "TPU v5 lite"}
     assert mfu.read(ctx) == pytest.approx(50.0)
     assert mfu.read(dict(ctx, host={})) is None
+    # Over the device's busy seconds (1 s of the 2): the share while it works.
+    busy = {"/device:TPU:0": {"XLA Ops": [["a fusion", 0, 600_000_000], ["b fusion", 10**9, 400_000_000]]}}
+    assert mfu.read(dict(ctx, trace=busy), over="busy") == pytest.approx(100.0)
+    with pytest.raises(ValueError):
+        mfu.read(ctx, over="wall")
     with pytest.raises(KeyError):
         mfu.read(dict(ctx, peaks=None, device_kind="TPU v9"))

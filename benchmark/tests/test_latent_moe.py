@@ -122,14 +122,6 @@ def test_bias_changes_the_top8_for_most_tokens_at_the_routers_real_width():
     assert 0.85 < differ <= 1.0, differ
 
 
-def test_pick_sample_takes_every_kth_and_always_the_longest():
-    finished = [(np.zeros(p), np.zeros(a)) for p, a in
-                [(5, 2), (9, 9), (3, 1), (40, 2), (7, 7), (8, 1), (2, 2)]]
-    got = driver.pick_sample(finished, {"every": 3})
-    assert [len(p) for p, _ in got] == [5, 40, 2]
-    assert [len(p) for p, _ in driver.pick_sample(finished[:3], {"every": 3})] == [5, 9]
-
-
 def test_expert_pair_readers_on_recorded_spans():
     rows = [
         ("serve/tick", 0.0, 1.0, None, {}, 1, 0, None),
@@ -168,7 +160,7 @@ def test_a_sound_serving_run_is_correct():
     line = execute()
     assert line["correct"] is True, line["checks"]
     assert set(line["metrics"]) == {
-        "serve_tokens_per_s", "ttft_p90_ms", "itl_p95_ms", "setup_s"}
+        "serve_tokens_per_s", "ttft_p90_ms", "tpot_p90_ms", "setup_s"}
     assert line["attempted"] == 20 and line["failed"] == 0
     assert list(line["checks"]) == [
         "routing_ambiguous_share", "token_gap_max", "token_gap_mean"]

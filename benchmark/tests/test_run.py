@@ -68,7 +68,7 @@ def test_a_sound_serving_run_is_correct():
     line = execute("tiny.chat")
     assert line["correct"] is True, line["checks"]
     assert set(line["metrics"]) == {
-        "serve_tokens_per_s", "ttft_p90_ms", "itl_p95_ms", "setup_s"}
+        "serve_tokens_per_s", "ttft_p90_ms", "tpot_p90_ms", "setup_s"}
     assert line["attempted"] == 20 and line["failed"] == 0
     assert list(line["checks"]) == ["token_gap_max"]
 

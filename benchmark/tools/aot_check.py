@@ -68,9 +68,9 @@ def main():
     report("gpt2-medium loss+grads B=8 T=1024",
            jax.jit(loss_and_grads).lower(params, tokens).compile())
 
-    # -- gpt2-large.chat: the engine's two programs ---------------------------
+    # -- gpt2-large.chat-busy: the engine's two programs ---------------------------
     config = load_json(HERE / "configs" / "gpt2-large.json")
-    cell = load_json(HERE / "workloads" / "gpt2-large.chat.json")["engine"]
+    cell = load_json(HERE / "workloads" / "gpt2-large.chat-busy.json")["engine"]
     model = TransformerLM(common.transformer_config(config))
     sc = ServeConfig(max_slots=cell["max_slots"], block_len=cell["block_len"],
                      prefill_chunk=cell["prefill_chunk"],

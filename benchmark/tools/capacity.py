@@ -1,7 +1,7 @@
 """The sweep for C, once: the whole supply of a cell queued at the start,
 completed requests per second over the steady middle of the run.
 
-    python -m benchmark.tools.capacity --workload gpt2-large.chat --seed 1 [--supply-s 30]
+    python -m benchmark.tools.capacity --workload gpt2-large.chat-busy --seed 1 [--supply-s 30]
 
 Prints one JSON object; the cell's ``rate_per_s`` is 0.8 x its ``C``.
 """

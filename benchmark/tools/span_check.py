@@ -1,7 +1,7 @@
 """One traced run of a cell with its ``.xplane.pb`` KEPT, to show once, on the
 chip, that the program's spans and the device's events lie on one clock:
 
-    python -m benchmark.tools.span_check --workload gpt2-large.chat --seed N
+    python -m benchmark.tools.span_check --workload gpt2-large.chat-busy --seed N
 
 ``run.py`` deletes the trace before its readers run, so this tool drives the
 cell's driver itself and then reads the profiler's file. It prints, as JSON
@@ -14,8 +14,6 @@ lines on standard output (and to ``chiprun_out/span_check.<cell>.json``):
   distance from its end to the nearest end of a ``jit_decode_wave`` event
   on the device's ``XLA Modules`` line (the fetch returns when the wave it
   waited for is done);
-* ``legs``: per request, how far ``req/prefill_wait + req/prefill_run +
-  req/first_token`` lies from the driver's ``prefill_phase_ms`` value;
 * ``tick``: the median period of the driver's ticks beside
   ``tick_host_ms.p50 + harvest_wait_ms.p50``;
 * ``tick_split``: the mean ``serve/tick`` of the traced stretch by child
@@ -23,8 +21,8 @@ lines on standard output (and to ``chiprun_out/span_check.<cell>.json``):
   ``serve/replay``, ``serve/grow``, ``serve/dispatch``) and its self time, in
   ms per tick: they add up to the mean tick, and all but the wait are where
   ``tick_host_ms`` goes;
-* ``end_to_end``: the traced run's own end-to-end metrics (``run.py`` prints
-  them only for untraced runs), for the cost of tracing ON;
+* ``end_to_end``: the traced run's own end-to-end metrics, for the cost of
+  tracing ON;
 * every per-layer metric of the cell through its own reader;
 * ``parse_trace``: whether ``rocket_tpu.obs.prof.parse_trace`` finds device
   slices and step windows in a perfetto capture of a few steps on this chip
@@ -110,27 +108,6 @@ def harvest_vs_device(lines, data) -> dict | None:
     }
 
 
-def legs_vs_phase(ctx) -> dict | None:
-    from benchmark.readers import program_spans
-
-    spans, _ = program_spans.load(ctx)
-    phases = ctx["host"].get("prefill_phase_ms")
-    if spans is None or not phases:
-        return None
-    sums: dict = {}
-    for s in spans:
-        if s.name in ("req/prefill_wait", "req/prefill_run", "req/first_token"):
-            rid = s.ids.get("rid")
-            sums[rid] = sums.get(rid, 0.0) + round(s.end - s.start, 6) * 1e3
-    values = sorted(sums.values())
-    worst = 0.0
-    for p in phases:
-        i = bisect.bisect_left(values, p)
-        worst = max(worst, min(abs(v - p) for v in values[max(i - 1, 0):i + 1]))
-    return {"requests_with_legs": len(values), "phases_compared": len(phases),
-            "worst_gap_us": worst * 1e3}
-
-
 def tick_split(ctx) -> dict | None:
     from benchmark.readers import program_spans
 
@@ -200,7 +177,8 @@ def main(argv=None) -> int:
     path = trace.newest_xplane(trace_dir)
     data = jax.profiler.ProfileData.from_file(path)
     ctx = {
-        "trace": trace.load_xplane(path), "host": result["host"], "config": config,
+        "trace": trace.cut_to_span(trace.load_xplane(path)), "host": result["host"],
+        "config": config,
         "cell": cell, "chips": len(devices), "cell_seconds": args.seconds,
         "peaks": run.load_json(run.HERE / "peaks.json").get(devices[0].device_kind),
         "device_kind": devices[0].device_kind,
@@ -209,12 +187,11 @@ def main(argv=None) -> int:
     report = {
         "workload": args.workload, "seed": args.seed,
         "xplane_bytes": os.path.getsize(path),
-        # What run.py leaves out of a traced line: the end-to-end metrics of
-        # THIS traced run, to set beside an untraced run of the same seed.
+        # The end-to-end metrics of THIS traced run, to set beside an
+        # untraced run of the same seed.
         "end_to_end": result["end_to_end"],
         "nesting": nesting(lines),
         "harvest_vs_device": harvest_vs_device(lines, data),
-        "legs": legs_vs_phase(ctx),
         "tick_split": tick_split(ctx),
         "metrics": {
             name: value["value"]

@@ -9,11 +9,16 @@ Device planes are those named ``/device:TPU:<n>``. On each, the line
 ``XLA Ops`` holds one event per executed operation (the busy time is the
 UNION of their intervals) and ``XLA Modules`` one event per executed jitted
 program. Host planes (``/host:CPU``) carry the benchmark's own
-``TraceAnnotation`` spans, named ``bench/...``.
+``TraceAnnotation`` spans, named ``bench/...``, and the program's
+(``serve/...``, ``train/...``, ``data/...``: ``rocket_tpu.obs.spans``). A
+serving run marks its traced stretch with one span, ``bench/stretch``;
+:func:`cut_to_span` cuts the whole trace to it.
 """
 
 from __future__ import annotations
 
+import bisect
+import functools
 import glob
 import os
 import re
@@ -22,10 +27,13 @@ from typing import Iterable, Optional
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "bench/"
+#: Host spans kept: the benchmark's own and the program's.
+HOST_SPAN_PREFIXES = ("bench/", "serve/", "train/", "data/")
+STRETCH_SPAN = "bench/stretch"
 _OPCODE = re.compile(r" ([a-z][\w\-]*)\(")
 
 
+@functools.lru_cache(maxsize=None)
 def short_op_name(text: str) -> str:
     """The profiler names an operation by its whole HLO line,
     ``%name = shape opcode(operands...)``: keep ``name opcode``."""
@@ -47,7 +55,8 @@ def newest_xplane(trace_dir: str) -> str:
 
 def load_xplane(path: str) -> dict:
     """Read an ``.xplane.pb`` into the normalised structure, keeping the
-    device planes whole and, of the host planes, only ``bench/`` spans."""
+    device planes whole and, of the host planes, the spans whose names
+    start with one of ``HOST_SPAN_PREFIXES``."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
@@ -63,12 +72,35 @@ def load_xplane(path: str) -> dict:
                 [short_op_name(e.name) if ops else e.name,
                  int(e.start_ns), int(e.duration_ns)]
                 for e in line.events
-                if device or e.name.startswith(HOST_SPAN_PREFIX)
+                if device or e.name.startswith(HOST_SPAN_PREFIXES)
             ]
             if events:
                 lines.setdefault(line.name, []).extend(events)
         if lines:
             out[plane.name] = lines
+    return out
+
+
+def cut_to_span(trace: dict, name: str = STRETCH_SPAN) -> dict:
+    """The trace cut to the host span ``name``: events that start inside
+    it, one that straddles its end shortened to it. The serving drivers
+    stop the profiler after their loop has drained, some time past the
+    close of the window; what the readers see is ``[t0, t1]``. A trace
+    without the span is returned as it is."""
+    found = [(s, e) for n, s, e in host_spans(trace) if n == name]
+    if not found:
+        return trace
+    lo, hi = found[0]
+    out: dict = {}
+    for plane, lines in trace.items():
+        kept = {}
+        for line, events in lines.items():
+            events = [[n, s, min(d, hi - s)] for n, s, d in events
+                      if lo <= s < hi and n != name]
+            if events:
+                kept[line] = events
+        if kept:
+            out[plane] = kept
     return out
 
 
@@ -170,21 +202,23 @@ def top_ops(trace: dict, n: int = 10) -> list[list]:
 
 
 def host_spans(trace: dict) -> list[tuple]:
-    """``(name, start_ns, end_ns)`` of the benchmark's own host spans."""
+    """``(name, start_ns, end_ns)`` of the host spans kept."""
     return sorted(
         (name, s, s + d)
         for plane, lines in trace.items() if not DEVICE_PLANE.match(plane)
         for line in lines.values()
         for name, s, d in line
-        if name.startswith(HOST_SPAN_PREFIX)
+        if name.startswith(HOST_SPAN_PREFIXES)
     )
 
 
 def idle_gaps(trace: dict, n: int = 10, window: Optional[tuple] = None) -> list[list]:
     """``[[what, seconds], ...]``: idle time of the first device plane
-    inside the window, summed by what the host was doing in it — the
-    ``bench/`` span covering most of each gap, ``host/other`` where none
-    does."""
+    inside the window, summed by what the host was doing in it: the
+    INNERMOST host span around the gap (the shortest of the spans that
+    cover half of it or more: ``serve/harvest_wait`` inside ``serve/tick``
+    inside ``bench/step``), else the span that covers most of it,
+    ``host/other`` where none touches it."""
     planes = device_planes(trace)
     if not planes:
         return []
@@ -197,15 +231,22 @@ def idle_gaps(trace: dict, n: int = 10, window: Optional[tuple] = None) -> list[
         cursor = max(cursor, e)
     if hi > cursor:
         gaps.append((cursor, hi))
-    spans = host_spans(trace)
+    spans = sorted((s, e, name) for name, s, e in host_spans(trace))
+    starts = [s for s, _, _ in spans]
+    longest = max((e - s for s, e, _ in spans), default=0)
     sums: dict = {}
     for gs, ge in gaps:
-        cover: dict = {}
-        for name, s, e in spans:
-            if e <= gs or s >= ge:
+        around, most = None, None       # (span length, name), (cover, name)
+        first = bisect.bisect_left(starts, gs - longest)
+        for s, e, name in spans[first:bisect.bisect_left(starts, ge)]:
+            cover = min(e, ge) - max(s, gs)
+            if cover <= 0:
                 continue
-            cover[name] = cover.get(name, 0) + min(e, ge) - max(s, gs)
-        what = max(cover, key=cover.get) if cover else "host/other"
+            if 2 * cover >= ge - gs and (around is None or e - s < around[0]):
+                around = (e - s, name)
+            if most is None or cover > most[0]:
+                most = (cover, name)
+        what = (around or most or (0, "host/other"))[1]
         sums[what] = sums.get(what, 0) + (ge - gs)
     ranked = sorted(sums.items(), key=lambda kv: -kv[1])[:n]
     return [[name, d / 1e9] for name, d in ranked]
