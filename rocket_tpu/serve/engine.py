@@ -152,8 +152,10 @@ def build_decode_wave(model, on_trace: Optional[Callable] = None,
             # samples exactly as k dispatched single waves would (int32
             # wraparound is deterministic; fold_in takes any int32).
             salts = seeds * jnp.int32(1000003) + lengths
+            # ``run``: a slot still prefilling carries its request's knobs
+            # and must not make a wave of greedy decoders sort.
             nxt = sample_tokens(
-                logits, key, salts, temp, top_k, top_p
+                logits, key, salts, temp, top_k, top_p, run=run
             ).astype(jnp.int32)
             done = jnp.zeros(nxt.shape, bool)
             nxt, done = freeze_after_eos(nxt, done, eos)

@@ -50,6 +50,7 @@ from typing import Optional
 
 import numpy as np
 
+from rocket_tpu.models.sampling import SAMPLE_BRANCHES, sample_branch
 from rocket_tpu.obs.spans import span, timed
 from rocket_tpu.serve.engine import SlotEngine
 from rocket_tpu.serve.kv_pool import BlockAllocator
@@ -221,13 +222,16 @@ class Scheduler:
         if run.any():
             # The counter serve/decode_pages: how much of the block table
             # this wave's running slots hold, which is all the decode
-            # kernel walks (``live`` pages of ``table`` entries).
+            # kernel walks (``live`` pages of ``table`` entries), and
+            # ``sample``, the branch its sampling takes on the device.
             with span("serve/decode_pages", tick=self.ticks) as sp:
                 if sp.on:
                     sp.set(
                         live=int((self.lengths[run] // self.block_len
                                   + 1).sum()),
                         table=int(self.block_table.size),
+                        sample=SAMPLE_BRANCHES[int(sample_branch(
+                            self.temp, self.top_k, self.top_p, run))],
                     )
             self.pending = self.engine.decode_dispatch(
                 self.block_table, self.lengths, self.last_tok, run,

@@ -910,6 +910,270 @@ def test_sampling_core_array_scalar_parity():
     np.testing.assert_array_equal(np.asarray(done2), [True, True, False])
 
 
+# ---------------------------------------------------------------------------
+# Sampling core: a call pays for what its rows ask for (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+def _sample_rows_frozen(logits, key, salt, temperature, top_k, top_p):
+    """The per-row path of ``sample_tokens`` as it stood before ISSUE 34,
+    kept as the plain reference: a vocabulary sort for ``top_k``, another
+    for ``top_p``, a draw for every row, and greedy rows taken last."""
+    logits = logits.astype(jnp.float32)
+    vocab = logits.shape[-1]
+    k = jnp.asarray(top_k, jnp.int32)
+    ranked = jnp.sort(logits, axis=-1)[..., ::-1]
+    kth = jnp.take_along_axis(
+        ranked, (jnp.clip(k, 1, vocab) - 1)[..., None], axis=-1
+    )
+    logits = jnp.where((k[..., None] > 0) & (logits < kth), -jnp.inf, logits)
+    t = jnp.asarray(temperature, jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.where(t > 0, t, 1.0)[..., None]
+    ranked = jnp.sort(scaled, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(ranked, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    p = jnp.asarray(top_p, jnp.float32)[..., None]
+    keep = cum - probs < p
+    cutoff = jnp.min(jnp.where(keep, ranked, jnp.inf), axis=-1, keepdims=True)
+    cutoff = jnp.where(p < 1.0, cutoff, -jnp.inf)
+    scaled = jnp.where(scaled < cutoff, -jnp.inf, scaled)
+    keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        key, jnp.asarray(salt)
+    )
+    sampled = jax.vmap(
+        lambda k_row, l_row: jax.random.categorical(k_row, l_row)
+    )(keys, scaled)
+    return jnp.where(t > 0, sampled, greedy)
+
+
+_ROWS, _VOCAB = 6, 96
+
+
+def _knobs(temp, top_k=0, top_p=1.0, run=True):
+    """Per-row knob arrays as the scheduler keeps them (a scalar fills)."""
+    return (
+        np.broadcast_to(np.asarray(temp, np.float32), (_ROWS,)).copy(),
+        np.broadcast_to(np.asarray(top_k, np.int32), (_ROWS,)).copy(),
+        np.broadcast_to(np.asarray(top_p, np.float32), (_ROWS,)).copy(),
+        np.broadcast_to(np.asarray(run, bool), (_ROWS,)).copy(),
+    )
+
+
+#: name -> (knobs, the branch the call must take, logits with ties?)
+_SAMPLING_GRID = {
+    "all_greedy": (_knobs(0.0), "argmax", False),
+    "greedy_rows_carry_filters": (
+        _knobs(0.0, [0, 5, 0, 9, 0, 1], [1.0, 0.5, 0.3, 1.0, 1.0, 0.9]),
+        "argmax", False),
+    "all_sampled_no_filter": (
+        _knobs([0.7, 1.0, 1.3, 0.2, 2.0, 0.9]), "sample", False),
+    "top_k_only": (_knobs(0.8, [3, 1, 10, 40, 2, 7]), "filter", False),
+    "top_p_only": (
+        _knobs(0.9, 0, [0.9, 0.5, 0.3, 0.95, 0.7, 0.1]), "filter", False),
+    "top_k_and_top_p": (
+        _knobs([0.7, 1.1, 0.9, 1.5, 0.6, 1.0], [5, 20, 3, 50, 8, 2],
+               [0.9, 0.8, 0.5, 0.95, 0.3, 0.99]), "filter", False),
+    "mixed_rows_of_each_kind": (
+        _knobs([0.0, 0.8, 0.8, 0.0, 1.2, 0.9], [0, 0, 4, 7, 0, 6],
+               [1.0, 1.0, 1.0, 0.4, 0.6, 0.8]), "filter", False),
+    "greedy_beside_plain_sampled": (
+        _knobs([0.0, 0.8, 0.0, 1.1, 0.0, 0.0]), "sample", False),
+    "idle_row_carries_sampling_knobs": (
+        _knobs([0.0, 0.8, 0.0, 0.0, 0.9, 0.0], [0, 5, 0, 0, 0, 0],
+               [1.0, 0.9, 1.0, 1.0, 1.0, 1.0],
+               [True, False, True, True, False, True]), "argmax", False),
+    "idle_row_filters_running_row_samples": (
+        _knobs([0.0, 0.8, 0.7, 0.0, 0.9, 0.0], [0, 5, 0, 0, 0, 0],
+               [1.0, 0.9, 1.0, 1.0, 1.0, 1.0],
+               [True, False, True, True, False, True]), "sample", False),
+    "ties_at_the_kth_logit": (
+        _knobs([0.8, 1.0, 0.7, 0.0, 1.2, 0.9], [3, 5, 2, 4, 8, 1],
+               [1.0, 0.9, 1.0, 1.0, 0.5, 1.0]), "filter", True),
+    "top_k_at_and_past_the_vocabulary": (
+        _knobs(0.9, [_VOCAB, _VOCAB + 1, 10 * _VOCAB, _VOCAB - 1, 2**30, 3]),
+        "filter", False),
+    "top_p_one_beside_a_third": (
+        _knobs(1.0, 0, [1.0, 0.3, 1.0, 0.3, 1.0, 0.3]), "filter", False),
+}
+
+
+def _grid_logits(ties):
+    logits = 3.0 * jax.random.normal(jax.random.key(34), (_ROWS, _VOCAB))
+    # Ties: a few distinct values a row, so the k-th value repeats.
+    return jnp.round(logits) if ties else logits
+
+
+@pytest.mark.parametrize("case", sorted(_SAMPLING_GRID))
+def test_sampling_core_row_path_returns_the_frozen_tokens(case):
+    """Whatever its rows ask for, the per-row path returns exactly the
+    tokens of the function as it stood (greedy rows the argmax, sampled
+    rows the same draw from the same per-row key, filtered rows the same
+    cutoffs), over several keys and salts; a row outside ``run`` is a
+    greedy row."""
+    from rocket_tpu.models.sampling import sample_tokens
+
+    (temp, top_k, top_p, run), _, ties = _SAMPLING_GRID[case]
+    logits = _grid_logits(ties)
+    new = jax.jit(sample_tokens)
+    old = jax.jit(_sample_rows_frozen)
+    calm = np.where(run, temp, np.float32(0.0))
+    for seed in (0, 7, 2**31 - 1):
+        for salt0 in (0, 1000003):
+            salts = np.arange(salt0, salt0 + 31 * _ROWS, 31, dtype=np.int32)
+            key = jax.random.key(seed)
+            got = np.asarray(
+                new(logits, key, salts, temp, top_k, top_p, run=run))
+            want = np.asarray(old(logits, key, salts, calm, top_k, top_p))
+            np.testing.assert_array_equal(got, want)
+            if run.all():  # without the mask: the same call
+                np.testing.assert_array_equal(np.asarray(
+                    new(logits, key, salts, temp, top_k, top_p)), want)
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    np.testing.assert_array_equal(got[calm <= 0], greedy[calm <= 0])
+
+
+@pytest.mark.parametrize("case", sorted(_SAMPLING_GRID))
+def test_sample_branch_agrees_on_host_and_device(case):
+    """ONE predicate: numpy arrays (the scheduler's counter) and jax arrays
+    under ``jit`` (the device's ``switch``) name the same branch."""
+    from rocket_tpu.models.sampling import SAMPLE_BRANCHES, sample_branch
+
+    (temp, top_k, top_p, run), branch, _ = _SAMPLING_GRID[case]
+    host = sample_branch(temp, top_k, top_p, run)
+    assert isinstance(host, np.integer)  # no device touched
+    device = jax.jit(sample_branch)(temp, top_k, top_p, run)
+    assert SAMPLE_BRANCHES[int(host)] == SAMPLE_BRANCHES[int(device)] == branch
+    # The device neutralises the idle rows first and passes no mask.
+    calm = np.where(run, temp, np.float32(0.0))
+    assert int(jax.jit(sample_branch)(calm, top_k, top_p)) == int(host)
+
+
+def _primitives(jaxpr, inside=()):
+    """``(primitive name, names of the enclosing primitives)`` for every
+    equation of ``jaxpr`` and of the jaxprs its equations carry."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub, inside + (eqn.primitive.name,))
+
+
+def test_sampling_core_row_path_sorts_once_inside_a_branch():
+    """The per-row path holds ONE vocabulary sort, inside a branch of the
+    ``switch`` (a ``cond`` in the jaxpr): a call whose rows do not filter
+    never runs it. The scalar path of ``generate()`` keeps its own."""
+    from rocket_tpu.models.sampling import sample_tokens
+
+    temp, top_k, top_p, run = _knobs(0.8, 5, 0.9)
+    salts = np.arange(_ROWS, dtype=np.int32)
+    logits, key = _grid_logits(False), jax.random.key(0)
+    for kwargs in ({}, {"run": run}):
+        jaxpr = jax.make_jaxpr(
+            lambda *a: sample_tokens(*a, **kwargs)
+        )(logits, key, salts, temp, top_k, top_p).jaxpr
+        sorts = [inside for name, inside in _primitives(jaxpr)
+                 if name == "sort"]
+        assert len(sorts) == 1 and "cond" in sorts[0], sorts
+    # A scalar among per-row arrays is broadcast: still the one sort.
+    jaxpr = jax.make_jaxpr(
+        lambda l, k, s, t: sample_tokens(l, k, s, t, 5, 0.9)
+    )(logits, key, salts, temp).jaxpr
+    sorts = [inside for name, inside in _primitives(jaxpr) if name == "sort"]
+    assert len(sorts) == 1 and "cond" in sorts[0], sorts
+    # Scalars all: the static path, no conditional.
+    jaxpr = jax.make_jaxpr(
+        lambda l, k: sample_tokens(l, k, 3, 0.8, None, 0.9))(logits, key).jaxpr
+    names = [name for name, _ in _primitives(jaxpr)]
+    assert names.count("sort") == 1 and "cond" not in names
+
+
+def _sample_records(engine, submit):
+    """The ``sample=`` ids of the ``serve/decode_pages`` records that
+    ``engine`` writes while ``submit(engine)``'s requests drain."""
+    from rocket_tpu.obs import spans
+
+    recorder = spans.SpanRecorder()
+    spans.install(recorder)
+    try:
+        submit(engine)
+        engine.drain()
+    finally:
+        spans.uninstall(recorder)
+    return [e.ids["sample"] for e in recorder.events()
+            if e.name == "serve/decode_pages"]
+
+
+def test_scheduler_records_the_sampling_branch_of_each_wave(tiny_lm):
+    """``serve/decode_pages`` carries ``sample=``: ``argmax`` while the
+    RUNNING slots are greedy (a sampling request still prefilling does not
+    count), ``filter`` once a running request has ``top_p`` 0.9, ``sample``
+    for a temperature alone."""
+    model, variables = tiny_lm
+    engine = ServeEngine(
+        model, variables["params"],
+        ServeConfig(max_slots=4, block_len=4, prefill_chunk=4,
+                    max_model_len=48),
+    )
+    short = np.arange(1, 4, dtype=np.int32)
+
+    def greedy(engine):
+        for _ in range(3):
+            engine.submit(short, max_new_tokens=4, temperature=0.0)
+
+    assert set(_sample_records(engine, greedy)) == {"argmax"}
+
+    def one_filters(engine):
+        greedy(engine)
+        # Five chunks of prefill: the greedy slots decode meanwhile.
+        engine.submit(np.arange(1, 19, dtype=np.int32), max_new_tokens=3,
+                      temperature=0.8, top_p=0.9)
+
+    records = _sample_records(engine, one_filters)
+    assert records[0] == "argmax" and records[-1] == "filter"
+    assert set(records) == {"argmax", "filter"}
+    assert records == sorted(records)  # it filters from its first wave on
+
+    def one_samples(engine):
+        engine.submit(short, max_new_tokens=3, temperature=0.8)
+
+    assert set(_sample_records(engine, one_samples)) == {"sample"}
+    assert engine.engine.decode_traces == 1  # one program, every branch
+
+
+def test_scanned_waves_choose_their_sampling_wave_by_wave(tiny_lm):
+    """The branch sits inside the scan's wave and reads the carried run
+    mask: with one sampled request that finishes mid-scan, every request
+    (the sampled one too: its salt is its seed and length) streams the
+    same tokens at 1 and at 2 waves a dispatch."""
+    model, variables = tiny_lm
+
+    def run(k):
+        engine = ServeEngine(
+            model, variables["params"],
+            ServeConfig(max_slots=4, block_len=4, prefill_chunk=4,
+                        max_model_len=48, decode_waves_per_dispatch=k),
+        )
+        rng = np.random.default_rng(34)
+        rids = [
+            engine.submit(rng.integers(0, 64, size=n).astype(np.int32),
+                          max_new_tokens=new, temperature=0.0)
+            for n, new in ((3, 8), (6, 5), (2, 10))
+        ]
+        # Three tokens: the second dispatch of two waves ends it mid-scan.
+        rids.append(engine.submit(
+            np.asarray([4, 5, 6], np.int32), max_new_tokens=3,
+            temperature=0.9, top_k=8, top_p=0.9))
+        engine.drain()
+        assert engine.engine.decode_traces == 1
+        return [engine.result(rid).tokens for rid in rids]
+
+    one, two = run(1), run(2)
+    assert [len(t) for t in one] == [8, 5, 10, 3]
+    assert one == two
+
+
 def test_reset_metrics_windows_registry_histograms(tiny_lm):
     """reset_metrics() windows the registry-side serve histograms too:
     the /metrics endpoint and telemetry.json percentiles must describe

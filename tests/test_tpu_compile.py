@@ -402,3 +402,59 @@ def _assert_pool_in_place(sds, model, sc, decode_kernel):
         assert aliases and aliases.group(1).count("alias") == len(pool_types), (
             name, text[:300])
     return found
+
+
+# -- the decode wave sorts the vocabulary only where a row filters -----------
+
+
+def test_decode_program_sorts_only_inside_a_conditional(sds, monkeypatch):
+    """The decode program at the chat cell's widths (GPT-2 large's 20 heads
+    x 64 and its 50,257 tokens, 32 slots; two layers), compiled for the
+    described chip: the sampling core's ONE vocabulary sort lies in a
+    computation that a ``conditional`` calls, and the entry computation
+    holds none. A wave whose running slots are greedy (both serve cells)
+    takes the branch without it. (XLA may flatten a conditional into both
+    branches and a select; this asserts it did not.)"""
+    import re
+
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=50257, max_seq_len=1024, dim=1280, num_layers=2,
+        num_heads=20, dropout=0.0, activation_dtype="bfloat16",
+    ))
+    sc = ServeConfig(max_slots=32, block_len=16, prefill_chunk=128)
+    spec, mb, _, waves = sc.resolve(model.config)
+    decode_args, _ = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    text = jax.jit(
+        build_decode_wave(model, waves=waves), donate_argnums=DECODE_DONATE
+    ).lower(*decode_args).compile().as_text()
+
+    sorts, branches, entry, inside = [], set(), None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(2)
+            entry = inside if head.group(1) else entry
+        elif re.search(r" sort\(", line):
+            sorts.append(inside)
+        elif " conditional(" in line:
+            called = re.search(r"branch_computations=\{([^}]*)\}", line)
+            branches.update(
+                name.strip(" %") for name in called.group(1).split(","))
+    assert entry and len(sorts) == 1, sorts
+    assert sorts[0] != entry and sorts[0] in branches, (sorts, branches)
