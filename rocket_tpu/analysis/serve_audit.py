@@ -251,7 +251,10 @@ class RecordingEngine:
         """Dispatch-and-wait convenience, mirroring SlotEngine."""
         return self.harvest(self.decode_dispatch(*args))
 
-    def prefill(self, block_table_row, tokens, position, valid) -> None:
+    def prefill(self, block_table_row, tokens, position, valid,
+                slot: Optional[int] = None) -> None:
+        # ``slot`` reaches the program as a fixed (1,) int32 only for a
+        # model with per-slot state: a value, never a shape.
         self.prefill_chunks += 1
         self._record("prefill", (block_table_row, tokens, position, valid))
 
@@ -595,15 +598,16 @@ def decode_floor_bytes(
     """Analytic HBM floor of ONE decode wave: master params (read) +
     the active-KV gather (every slot's mapped blocks, of every pool
     array: K and V, or the one latent) + the one-new-row-per-slot pool
-    scatter. What a perfectly fused wave streams — the RKT602
-    denominator."""
+    scatter + every slot's per-slot state read and written (a model with
+    state layers; ``KVPoolSpec.state_bytes``). What a perfectly fused
+    wave streams — the RKT602 denominator."""
     row = sum(spec.lanes) * np.dtype(spec.dtype).itemsize
     kv_gather = (
         spec.num_layers * max_slots * max_blocks_per_seq
         * spec.block_len * row
     )
     scatter = spec.num_layers * max_slots * row
-    return int(params_bytes + kv_gather + scatter)
+    return int(params_bytes + kv_gather + scatter + 2 * spec.state_bytes)
 
 
 def fused_decode_bytes(
@@ -649,7 +653,9 @@ def estimate_serve_hbm(
     temp = max((p.temp_bytes for p in programs), default=0)
     total = spec.pool_bytes + params_bytes + temp
     capacity = int(device.hbm_bytes) if device is not None else 0
-    headroom = capacity - params_bytes - temp
+    # The per-slot state is held whatever the blocks: it comes off the
+    # room the blocks could fill.
+    headroom = capacity - params_bytes - temp - spec.state_bytes
     max_blocks = max(0, headroom // spec.block_bytes) if capacity else 0
     frontier = {
         "max_num_blocks": int(max_blocks),

@@ -119,8 +119,9 @@ class TransformerConfig:
     #: ENTIRE model to f32 compute (≈2x MXU time). Params stay f32 masters;
     #: layernorm/softmax math stays f32 internally.
     activation_dtype: Optional[str] = None
-    #: Positional encoding: "learned" (GPT-2 wpe table) or "rope" (rotary,
-    #: applied to q/k inside attention; no wpe params). RoPE is the
+    #: Positional encoding: "learned" (GPT-2 wpe table), "rope" (rotary,
+    #: applied to q/k inside attention; no wpe params) or "none" (no
+    #: positional signal outside the mixers: Jamba). RoPE is the
     #: Llama-family default and composes with num_kv_heads (GQA).
     pos_embedding: str = "learned"
     rope_base: float = 10000.0
@@ -158,6 +159,18 @@ class TransformerConfig:
     mlp_bias: bool = True
     #: Epsilon of every normalizer (None = the normalizer's default).
     norm_eps: Optional[float] = None
+    #: Biases on the attention projections (False: the Llama/Jamba kind).
+    attn_bias: bool = True
+    #: State-space mixers (``nn.ssm.MambaMixer``) in place of attention:
+    #: an ``SSMConfig``. Layer ``i`` keeps attention where ``i %
+    #: attn_layer_period == attn_layer_offset`` (Jamba's rule; period 0 =
+    #: every layer is a mixer) and is a mixer elsewhere. A mixer caches no
+    #: pages: what it carries is a fixed-size state a SLOT
+    #: (:meth:`slot_state_shapes`). Such a stack cannot be scanned
+    #: (``scan_layers``) or pipelined: its layers are not alike.
+    ssm: Optional[Any] = None
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
     #: Label smoothing for ``next_token_loss``: the target distribution is
     #: (1-eps) one-hot + eps uniform. Lives on the CONFIG (not the
     #: objective) so the fused (loss_chunk) and full-logits paths apply the
@@ -198,7 +211,7 @@ class TransformerConfig:
             raise ValueError(f"TransformerConfig: unknown norm {self.norm!r}")
         if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(f"TransformerConfig: unknown mlp {self.mlp!r}")
-        if self.pos_embedding not in ("learned", "rope"):
+        if self.pos_embedding not in ("learned", "rope", "none"):
             raise ValueError(
                 f"TransformerConfig: unknown pos_embedding {self.pos_embedding!r}"
             )
@@ -237,6 +250,32 @@ class TransformerConfig:
         elif self.first_dense_layers:
             raise ValueError(
                 "TransformerConfig: first_dense_layers without routed_experts"
+            )
+        if self.ssm is not None:
+            if self.attn_layer_period < 0 or (
+                self.attn_layer_period
+                and not 0 <= self.attn_layer_offset < self.attn_layer_period
+            ):
+                raise ValueError(
+                    f"TransformerConfig: attn_layer_offset "
+                    f"{self.attn_layer_offset} outside [0, attn_layer_period "
+                    f"{self.attn_layer_period})"
+                )
+            if self.scan_layers or self.pipeline_axis:
+                raise ValueError(
+                    "TransformerConfig: scan_layers and pipeline_axis need "
+                    "every block alike; state-space and attention layers "
+                    "run as a Python loop"
+                )
+            if self.latent_attention is not None:
+                raise ValueError(
+                    "TransformerConfig: ssm beside latent_attention has no "
+                    "serving pool (one kind of page a model)"
+                )
+        elif self.attn_layer_period or self.attn_layer_offset:
+            raise ValueError(
+                "TransformerConfig: attn_layer_period / attn_layer_offset "
+                "without ssm"
             )
         if self.latent_attention is not None and self.pos_embedding != "rope":
             raise ValueError(
@@ -286,6 +325,39 @@ class TransformerConfig:
             self.dim // self.num_heads
         )
         return (lanes, lanes)
+
+    def is_state_layer(self, layer_idx: int) -> bool:
+        """Whether layer ``layer_idx`` is a state-space mixer (else it is
+        attention and caches pages)."""
+        if self.ssm is None:
+            return False
+        period = self.attn_layer_period
+        return not (period and layer_idx % period == self.attn_layer_offset)
+
+    @property
+    def state_layers(self) -> int:
+        """How many layers carry a per-slot state."""
+        return sum(self.is_state_layer(i) for i in range(self.num_layers))
+
+    @property
+    def cache_layers(self) -> int:
+        """How many layers cache pages: the layer rows of the serving
+        pool. An attention layer's ``layer=`` coordinate in the pool is its
+        index among these."""
+        return self.num_layers - self.state_layers
+
+    @property
+    def slot_state_shapes(self) -> tuple:
+        """What ONE slot carries beside its pages, for every array of the
+        per-slot state: ``((state layers, per-slot shape, dtype), ...)`` —
+        empty for a model whose every layer caches pages. The one
+        description ``serve/kv_pool.py`` sizes the state arrays from."""
+        if not self.state_layers:
+            return ()
+        return tuple(
+            (self.state_layers, shape, dtype) for shape, dtype in
+            self.ssm.state_shapes(self.activation_dtype or "float32")
+        )
 
     @staticmethod
     def char_lm(vocab_size: int = 128, max_seq_len: int = 256) -> "TransformerConfig":
@@ -341,14 +413,25 @@ class TransformerConfig:
 
 
 class Block(Layer):
-    """Pre-LN transformer block: x += attn(ln1(x)); x += mlp(ln2(x))."""
+    """Pre-LN transformer block: x += mixer(ln1(x)); x += mlp(ln2(x)) —
+    the mixer attention (``attn``, parameters ``attn``) or, in a state
+    layer (``TransformerConfig.is_state_layer``), a state-space mixer
+    (``mixer``, parameters ``mixer``)."""
 
     def __init__(self, config: TransformerConfig, layer_idx: int):
         c = config
         c.validate()
         self.ln1 = c.make_norm(c.dim)
         self.latent = c.latent_attention is not None
-        if self.latent:
+        self.attn = self.mixer = None
+        if c.is_state_layer(layer_idx):
+            from rocket_tpu.nn.ssm import MambaMixer
+
+            self.mixer = MambaMixer(
+                c.dim, c.ssm,
+                norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
+            )
+        elif self.latent:
             from rocket_tpu.nn.attention import LatentAttention
 
             self.attn = LatentAttention(
@@ -359,7 +442,8 @@ class Block(Layer):
         else:
             self.attn = MultiHeadAttention(
                 c.dim, c.num_heads, num_kv_heads=c.num_kv_heads,
-                causal=c.causal, dropout=c.dropout, impl=c.attention_impl,
+                causal=c.causal, dropout=c.dropout, use_bias=c.attn_bias,
+                impl=c.attention_impl,
                 seq_axis=c.seq_axis, rope=c.pos_embedding == "rope",
                 rope_base=c.rope_base,
             )
@@ -411,8 +495,9 @@ class Block(Layer):
         # on the reference chain statically.
         self._block_attn_ok = (
             not self.latent
+            and self.mixer is None
             and c.norm == "layernorm"
-            and c.pos_embedding != "rope"
+            and c.pos_embedding == "learned"
             and c.causal
             and (c.num_kv_heads is None or c.num_kv_heads == c.num_heads)
             and c.attention_impl != "ring"
@@ -425,11 +510,17 @@ class Block(Layer):
         keys = jax.random.split(key, 4)
         params = {
             "ln1": self.ln1.init(keys[0])["params"],
-            "attn": self.attn.init(keys[1])["params"],
             "ln2": self.ln2.init(keys[2])["params"],
         }
-        # Residual-output scaling (attn.proj and the FFN output kernel).
-        params["attn"]["proj"]["w"] = params["attn"]["proj"]["w"] * self._resid_scale
+        # Residual-output scaling (the mixer's output projection and the
+        # FFN output kernel).
+        if self.mixer is not None:
+            params["mixer"] = self.mixer.init(keys[1])["params"]
+            out = params["mixer"]["out_proj"]
+        else:
+            params["attn"] = self.attn.init(keys[1])["params"]
+            out = params["attn"]["proj"]
+        out["w"] = out["w"] * self._resid_scale
         if self.routed is not None:
             params["moe"] = self.routed.init_params(keys[3])
         elif self.moe is not None:
@@ -575,6 +666,10 @@ class Block(Layer):
                 )
             return out
         h, _ = self.ln1.apply({"params": p["ln1"], "state": {}}, x)
+        if self.mixer is not None:
+            return self.mixer.apply(
+                {"params": p["mixer"], "state": {}}, h, mode=mode
+            )[0]
         h, _ = self.attn.apply(
             {"params": p["attn"], "state": {}}, h, mode=mode, rng=rng
         )
@@ -618,12 +713,32 @@ class Block(Layer):
             params["attn"], h, *pages, block_table, positions, valid,
             layer=layer,
         )
-        x = x + h
+        y, counts = self._ffn_half(params, x + h, valid)
+        return y, tuple(pages), counts
+
+    def apply_state(self, params, x, state, positions, valid, slots=None,
+                    layer=0):
+        """:meth:`apply_paged` for a state layer: ``state`` the WHOLE
+        per-slot state arrays (``nn.ssm.MambaMixer.apply_state``), read and
+        written at ``(layer, slots)`` — ``layer`` this block's index among
+        the state layers, ``slots`` (S,) the slot of each row of ``x``
+        (None: row ``s`` is slot ``s``, the decode wave). Returns ``(y,
+        state', counts)``."""
+        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
+        h, state = self.mixer.apply_state(
+            params["mixer"], h, state, positions, valid, layer=layer,
+            slots=slots,
+        )
+        y, counts = self._ffn_half(params, x + h, valid)
+        return y, state, counts
+
+    def _ffn_half(self, params, x, valid):
+        """ln2 + the FFN of a paged or stateful chunk ``x`` (S, C, D)."""
         h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
         # Padding rows and idle slots are no tokens: they route nowhere.
         real = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :] < valid[:, None]
         h, counts = self._ffn_eval(params, h, token_mask=real)
-        return x + h, tuple(pages), counts
+        return x + h, counts
 
     def _mlp_tp_spec(self, h):
         """Overlap spec when the MLP can take the collective-matmul
@@ -695,9 +810,9 @@ class TransformerLM(Model):
         config.validate()
         # RoPE encodes positions inside attention — no learned wpe table.
         self.wpe = (
-            None
-            if config.pos_embedding == "rope"
-            else Embedding(config.max_seq_len, config.dim)
+            Embedding(config.max_seq_len, config.dim)
+            if config.pos_embedding == "learned"
+            else None
         )
         self.blocks = [Block(config, i) for i in range(config.num_layers)]
         self.ln_f = config.make_norm(config.dim)
@@ -805,7 +920,7 @@ class TransformerLM(Model):
         return logits, k_pages, v_pages
 
     def paged_step(self, params, tokens, pages, block_table, positions,
-                   valid):
+                   valid, slots=None):
         """Decode/prefill chunk against an EXTERNAL paged pool — the
         cache is indexed by slot, not owned by the call
         (``rocket_tpu.serve``; pool layout in ``ops/paged_attention.py``).
@@ -814,8 +929,16 @@ class TransformerLM(Model):
         positions ``[positions[s], positions[s]+C)`` with the first
         ``valid[s]`` rows real; ``pages`` is the whole pool, a tuple of
         ``(L, NB, BL, lanes)`` arrays as ``config.kv_pool_lanes`` declares
-        (K and V, or one latent array); ``block_table`` (S, MB) maps slot
-        positions onto pool blocks. Returns ``(logits (S, V) of the
+        (K and V, or one latent array; ``L`` = ``config.cache_layers``)
+        and, behind them, the per-slot state arrays that
+        ``config.slot_state_shapes`` declares (``(state layers, max_slots,
+        ...)``; none for a model whose every layer caches pages);
+        ``block_table`` (S, MB) maps slot positions onto pool blocks;
+        ``slots`` (S,) int32 names the slot of each row, which a state
+        layer needs where row ``s`` is not slot ``s`` (the prefill chunk;
+        None = the decode wave over every slot). A slot's state starts
+        from zeros wherever its chunk starts at position 0. Returns
+        ``(logits (S, V) of the
         chunk's LAST position, pages', expert_pairs)`` — C=1 is the decode
         wave, C=chunk the prefill step, one code path for both. Every
         layer reads and writes the whole pool at its own layer coordinate:
@@ -853,14 +976,26 @@ class TransformerLM(Model):
             )
         else:
             pairs = []
+            n_pool = len(self.config.kv_pool_lanes)
+            pages, state = pages[:n_pool], pages[n_pool:]
+            cached = stateful = 0   # each kind's own layer coordinate
             for i, block in enumerate(self.blocks):
-                x, pages, counts = block.apply_paged(
-                    p["blocks"][str(i)], x, pages, block_table, positions,
-                    valid, layer=i,
-                )
+                if block.mixer is not None:
+                    x, state, counts = block.apply_state(
+                        p["blocks"][str(i)], x, state, positions, valid,
+                        slots, layer=stateful,
+                    )
+                    stateful += 1
+                else:
+                    x, pages, counts = block.apply_paged(
+                        p["blocks"][str(i)], x, pages, block_table,
+                        positions, valid, layer=cached,
+                    )
+                    cached += 1
                 if counts is not None:
                     pairs.append(counts)
             pairs = jnp.stack(pairs) if pairs else None
+            pages = pages + tuple(state)
 
         x = x[:, -1:]  # only the last position's logits are consumed
         x, _ = self.ln_f.apply({"params": p["ln_f"], "state": {}}, x)
@@ -1387,8 +1522,12 @@ def generate(
     """
     import numpy as np
 
-    if use_cache and model.config.attention_impl == "ring":
-        use_cache = False  # see docstring — no dense KV cache to fill
+    if use_cache and (
+        model.config.attention_impl == "ring" or model.config.ssm is not None
+    ):
+        # See docstring — no dense KV cache to fill; a state layer's cache
+        # is its state, which only the serving path carries.
+        use_cache = False
     prompt = jnp.asarray(prompt_tokens, jnp.int32)
     if prompt.ndim == 1:
         prompt = prompt[None, :]

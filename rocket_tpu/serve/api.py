@@ -92,7 +92,10 @@ class ServeConfig:
         h_kv = (mc.num_kv_heads or mc.num_heads) if len(lanes) == 2 else 1
         num_blocks = self.num_blocks or (1 + self.max_slots * mb)
         spec = KVPoolSpec(
-            num_layers=mc.num_layers,
+            # The layers that cache pages; the others carry a state a slot.
+            num_layers=mc.cache_layers,
+            slot_state=tuple(mc.slot_state_shapes),
+            max_slots=self.max_slots,
             num_blocks=num_blocks,
             block_len=self.block_len,
             # What a layer caches per token is the model's to declare: K

@@ -38,6 +38,17 @@ is served.
 
 Pool buffers are DONATED through both programs (:data:`DECODE_DONATE` /
 :data:`PREFILL_DONATE`), so the pool is updated in place wave over wave.
+**What the donated tuple holds**: the page arrays, indexed by block
+through the block table, and — for a model with state layers
+(``TransformerConfig.slot_state_shapes``) — the per-slot state arrays
+``(state layers, max_slots, ...)`` behind them, indexed by slot. The
+decode wave's row ``s`` IS slot ``s``; the prefill program of such a model
+takes one more argument, the slot its chunk belongs to (a model without
+state layers keeps the six-argument program). A slot that does not run in
+a wave (``run_mask`` false) keeps its state bitwise; a chunk or a wave at
+position 0 starts its slot's state from zeros, so slot reuse and an
+evicted request's re-prefill inherit nothing; the k waves of a dispatch
+carry the state through the scan with the pages.
 The scan SPLITS dispatch from harvest: :meth:`SlotEngine.decode_dispatch`
 enqueues the k-wave program and returns immediately with device handles,
 :meth:`SlotEngine.harvest` performs the one explicit ``jax.device_get``
@@ -52,6 +63,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from rocket_tpu.models.sampling import freeze_after_eos, sample_tokens
 from rocket_tpu.obs.spans import span, timed
@@ -69,7 +81,9 @@ __all__ = [
 
 #: Donated argument positions of the two compiled programs — the pool,
 #: ONE argument: the tuple of arrays the model's layers declare
-#: (``(k_pages, v_pages)``, or one latent array). One definition shared by
+#: (``(k_pages, v_pages)``, or one latent array: pages by BLOCK; then,
+#: for a model with state layers, its state arrays by SLOT,
+#: ``KVPoolSpec.arrays``). One definition shared by
 #: the engine's jit and the static auditor's AOT compile, so they cannot
 #: disagree.
 DECODE_DONATE = (1,)
@@ -181,15 +195,18 @@ def build_prefill_step(model, on_trace: Optional[Callable] = None) -> Callable:
     :func:`build_decode_wave` for the builder contract. Signature::
 
         prefill_chunk(params, pages, block_table_row, tokens, positions,
-                      valid) -> (pages, expert_pairs (layers, held) or None)
+                      valid[, slot]) -> (pages, expert_pairs (layers, held)
+                                         or None)
     """
 
     def prefill_chunk_fn(params, pages, block_table, tokens, positions,
-                         valid):
+                         valid, *slot):
+        # ``slot`` (1,) int32: given by the engine only to a model with
+        # state layers, whose chunk must find its slot's state.
         if on_trace is not None:
             on_trace()  # trace-time: counts (re)traces only
         _, pages, pairs = model.paged_step(
-            params, tokens, pages, block_table, positions, valid,
+            params, tokens, pages, block_table, positions, valid, *slot,
         )
         return pages, pairs
 
@@ -225,8 +242,8 @@ def abstract_wave_inputs(
     )
     s, mb, c = int(max_slots), int(max_blocks_per_seq), int(prefill_chunk)
     pool = tuple(
-        jax.ShapeDtypeStruct(shape, jnp.dtype(spec.dtype))
-        for shape in spec.pages_shapes
+        jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+        for shape, dtype in spec.arrays
     )
     i32 = jnp.int32
     f32 = jnp.float32
@@ -254,6 +271,8 @@ def abstract_wave_inputs(
         jax.ShapeDtypeStruct((1,), i32),      # position
         jax.ShapeDtypeStruct((1,), i32),      # valid
     )
+    if spec.slot_state:
+        prefill_args += (jax.ShapeDtypeStruct((1,), i32),)  # slot
     return decode_args, prefill_args
 
 
@@ -414,15 +433,26 @@ class SlotEngine:
             top_k, top_p, eos, seeds,
         ))
 
-    def prefill(self, block_table_row, tokens, position, valid) -> None:
+    def prefill(self, block_table_row, tokens, position, valid,
+                slot: Optional[int] = None) -> None:
         """One prefill chunk for ONE slot: ``block_table_row`` ``(1, MB)``,
         ``tokens`` ``(1, prefill_chunk)`` (tail-padded), ``position``/
-        ``valid`` ``(1,)``. Fire-and-forget — nothing is fetched, so
-        chunks pipeline behind decode waves."""
+        ``valid`` ``(1,)``; ``slot`` is handed to the program of a model
+        with per-slot state, which reads and writes that slot's, and is
+        required there. Fire-and-forget — nothing is fetched, so chunks
+        pipeline behind decode waves."""
+        where = ()
+        if self.spec.slot_state:
+            if slot is None:
+                raise ValueError(
+                    "SlotEngine.prefill: a model with per-slot state needs "
+                    "the slot its chunk belongs to"
+                )
+            where = (np.asarray([slot], np.int32),)
         self.prefill_chunks += 1
         self.pages, pairs = self._prefill(
             self._params, self.pages, block_table_row, tokens, position,
-            valid,
+            valid, *where,
         )
         if pairs is not None:
             self._chunk_pairs.append((self.tick, int(valid[0]), pairs))

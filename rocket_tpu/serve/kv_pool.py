@@ -3,7 +3,9 @@
 The pool is the serving engine's only model-state memory: the arrays the
 model's layers declare (``TransformerConfig.kv_pool_lanes``), each
 ``(L, num_blocks, block_len, lanes)``, allocated ONCE and sized
-independently of how many requests ever flow through the engine. A
+independently of how many requests ever flow through the engine. ``L``
+counts the layers that CACHE PAGES (``TransformerConfig.cache_layers``: 2
+of a 28-layer hybrid's, not 28). A
 multi-head model declares two, K and V, of ``Hkv*D`` lanes; a latent-
 attention model ONE, of ``kv_lora_rank + qk_rope_head_dim`` lanes (the
 normed latent and the rotated shared key side by side: there is no V). A
@@ -16,6 +18,16 @@ own *blocks*, not cache rows: the allocator hands out integer block ids on
 the host and the compiled step indexes the pool through per-slot block
 tables, so admitting a request is a few host list operations and never
 touches compiled code.
+
+**State by slot, beside the pages by block.** A layer that caches no
+rows but carries a fixed-size state (a state-space mixer,
+``nn/ssm.py``) declares it in ``TransformerConfig.slot_state_shapes``;
+the pool then holds, behind its page arrays and in the SAME donated tuple,
+one array per declared state, ``(state layers, max_slots, ...)``, indexed
+by SLOT: no allocator hands it out, a request has it for as long as it has
+its slot. Nothing on the host resets it: both programs start a slot's
+state from zeros wherever its chunk starts at position 0, which is where a
+new request, a reused slot and an evicted request's re-prefill all start.
 
 Block 0 is RESERVED as the trash sink: masked writes (prompt padding,
 inactive slots) land there and unmapped block-table entries point at it,
@@ -30,6 +42,7 @@ only waste is internal (the tail of a sequence's last block, bounded by
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional
 
@@ -42,7 +55,10 @@ __all__ = ["KVPoolSpec", "BlockAllocator"]
 class KVPoolSpec:
     """Shape of the paged pool for one model. ``lanes`` holds, for each
     pool array, what a layer caches per token in it; left empty it is the
-    K and V of ``num_kv_heads * head_dim`` lanes each."""
+    K and V of ``num_kv_heads * head_dim`` lanes each. ``slot_state``
+    (``TransformerConfig.slot_state_shapes``: ``(layers, per-slot shape,
+    dtype)`` an array) and ``max_slots`` size the per-slot state arrays
+    that follow the page arrays in the engine's donated tuple."""
 
     num_layers: int
     num_blocks: int
@@ -51,6 +67,8 @@ class KVPoolSpec:
     head_dim: int = 0
     dtype: str = "float32"
     lanes: tuple = ()
+    slot_state: tuple = ()
+    max_slots: int = 0
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -65,6 +83,11 @@ class KVPoolSpec:
             object.__setattr__(self, "lanes", (kv, kv))
         if not all(int(n) > 0 for n in self.lanes):
             raise ValueError(f"KVPoolSpec: lanes {self.lanes} must be > 0")
+        if self.slot_state and self.max_slots < 1:
+            raise ValueError(
+                "KVPoolSpec: per-slot state needs max_slots >= 1, got "
+                f"{self.max_slots}"
+            )
 
     @property
     def block_bytes(self) -> int:
@@ -74,10 +97,28 @@ class KVPoolSpec:
         return self.num_layers * self.block_len * sum(self.lanes) * itemsize
 
     @property
+    def state_shapes(self) -> tuple:
+        """``(shape, dtype)`` of each per-slot state array: ``(state
+        layers, max_slots) + per-slot shape``."""
+        return tuple(
+            ((int(layers), self.max_slots) + tuple(shape), dtype)
+            for layers, shape, dtype in self.slot_state
+        )
+
+    @property
+    def state_bytes(self) -> int:
+        """HBM of the per-slot state arrays (0 for a model without)."""
+        return sum(
+            math.prod(shape) * jnp.dtype(dtype).itemsize
+            for shape, dtype in self.state_shapes
+        )
+
+    @property
     def pool_bytes(self) -> int:
-        """Total pool HBM: ``num_blocks * block_bytes`` — the serving
-        engine's peak cache memory regardless of request count."""
-        return self.num_blocks * self.block_bytes
+        """Total HBM of the donated tuple: ``num_blocks * block_bytes`` of
+        pages plus :attr:`state_bytes` — the serving engine's peak cache
+        memory regardless of request count."""
+        return self.num_blocks * self.block_bytes + self.state_bytes
 
     @property
     def pages_shapes(self) -> tuple:
@@ -93,11 +134,21 @@ class KVPoolSpec:
         the same): ``(L, NB, BL, Hkv*D)``."""
         return self.pages_shapes[0]
 
+    @property
+    def arrays(self) -> tuple:
+        """``(shape, dtype)`` of every array of the donated tuple, in its
+        order: the page arrays, then the per-slot state arrays."""
+        return tuple(
+            (shape, self.dtype) for shape in self.pages_shapes
+        ) + self.state_shapes
+
     def init_pages(self) -> tuple:
         """The zeroed device pool: one array per entry of ``lanes`` —
-        ``(k_pages, v_pages)`` for a K/V pool."""
-        dt = jnp.dtype(self.dtype)
-        return tuple(jnp.zeros(shape, dt) for shape in self.pages_shapes)
+        ``(k_pages, v_pages)`` for a K/V pool — then the per-slot state
+        arrays, if the model declares any."""
+        return tuple(
+            jnp.zeros(shape, jnp.dtype(dtype)) for shape, dtype in self.arrays
+        )
 
 
 class BlockAllocator:

@@ -337,6 +337,7 @@ class Scheduler:
                 chunk[None, :].astype(np.int32),
                 np.asarray([start], np.int32),
                 np.asarray([valid], np.int32),
+                slot,
             )
         st.prefill_pos = start + valid
         self.lengths[slot] = st.prefill_pos

@@ -458,3 +458,102 @@ def test_decode_program_sorts_only_inside_a_conditional(sds, monkeypatch):
                 name.strip(" %") for name in called.group(1).split(","))
     assert entry and len(sorts) == 1, sorts
     assert sorts[0] != entry and sorts[0] in branches, (sorts, branches)
+
+
+# -- per-slot state beside the pages: both are updated where they lie --------
+
+
+def test_hybrid_serve_programs_update_pages_and_state_in_place(sds, monkeypatch):
+    """The engine's two programs for a hybrid of state-space and attention
+    layers at the reason cell's widths (d 2560, mixer 5120 x 16 with dt
+    rank 160, MLP 8192, 20 query heads over 1 K/V head of 128; 64 slots x
+    4096 positions in blocks of 64, chunks of 512; one Mamba and one
+    attention layer and a small vocabulary, so that it compiles in
+    seconds): the decode program holds ``paged_decode`` at 20 heads over 1
+    and ``ssm_step``, the prefill program ``ssm_scan``; the four donated
+    arrays — K and V pages by block, ``h`` and the convolution's tail by
+    slot — are aliased input to output, and nothing but a program's own
+    update of them (the kernel that owns ``h``, a row scatter, a slot's
+    dynamic-update-slice) produces an array of their types: no copy. (One
+    kind of move is the compiler's own and is let through: an asynchronous
+    ``copy-start``/``copy-done`` of a small array into the chip's fast
+    memory, ``S(1)`` in the layout, and back. At this test's one state layer
+    ``h`` is small enough for it; at the cell's 26 only the convolution's
+    51 MB tail is, in the decode program: PERF.md.)"""
+    import re
+
+    import rocket_tpu.nn.ssm as ssm
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.nn.ssm import SSMConfig
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    monkeypatch.setattr(ssm, "_on_cpu", lambda: False)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=262144, dim=2560, num_layers=2,
+        num_heads=20, num_kv_heads=1, dropout=0.0, activation_dtype="bfloat16",
+        pos_embedding="none", norm="rmsnorm", norm_eps=1e-6, mlp="swiglu",
+        mlp_hidden=8192, mlp_bias=False, attn_bias=False,
+        ssm=SSMConfig(d_inner=5120, dt_rank=160, d_state=16, d_conv=4),
+        attn_layer_period=2, attn_layer_offset=1,
+    ))
+    sc = ServeConfig(max_slots=64, block_len=64, prefill_chunk=512,
+                     max_model_len=4096)
+    spec, mb, _, waves = sc.resolve(model.config)
+    assert spec.pages_shapes == ((1, 4097, 64, 128),) * 2
+    assert spec.state_shapes == (((1, 64, 16, 5120), "float32"),
+                                 ((1, 64, 15360), "bfloat16"))
+    donated = ["bf16[1,4097,64,128]", "f32[1,64,16,5120]", "bf16[1,64,15360]"]
+    decode_args, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    programs = {
+        "decode": (build_decode_wave(model, waves=waves), decode_args,
+                   DECODE_DONATE, ("paged_decode", "ssm_step")),
+        "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE,
+                    ("ssm_scan",)),
+    }
+    for name, (fn, args, donate, wanted) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        text = compiled.as_text()
+        kernels = _kernel_instructions(text)
+        for kernel in wanted:
+            assert any(kernel in k for k in kernels), (name, kernel, kernels)
+        materialised = _materialised(text)
+        # Asynchronous moves into or out of the chip's fast memory.
+        moves = {inst for op, inst, _, line in materialised
+                 if op == "copy-start" and "S(1)" in line}
+        made = [
+            (op, inst) for op, inst, _, line in materialised
+            if any(t in line.split(" = ")[1].split("(")[0] for t in donated)
+            and op not in ("parameter", "tuple", "get-tuple-element", "bitcast",
+                           "while", "custom-call", "dynamic-update-slice",
+                           "scatter")
+            and not (op == "copy-start" and inst in moves)
+            and not (op == "copy-done" and any(
+                f"copy-done(%{m})" in line or f"copy-done({m})" in line
+                for m in moves))
+            and not (op == "fusion" and re.search(
+                r"/scatter\"|dynamic_update_slice|dynamic-update-slice", line))
+        ]
+        assert not made, (name, made)
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= spec.pool_bytes, (
+            name, memory.alias_size_in_bytes, spec.pool_bytes)
+        # Temporaries: activations of a chunk, never a copy of the state.
+        assert memory.temp_size_in_bytes < spec.state_bytes // 2, (
+            name, memory.temp_size_in_bytes)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
