@@ -138,7 +138,7 @@ class WaveObservation:
 #: vacuate the check.
 SCHEDULER_WAVE_ARGS = (
     "block_table", "lengths", "last_tok", "run_mask", "limits",
-    "temp", "top_k", "top_p", "eos", "seeds",
+    "temp", "top_k", "top_p", "eos", "seeds", "fresh",
 )
 
 #: State labels :func:`enumerate_admission_lattice` must observe for the
@@ -163,11 +163,14 @@ class RecordingEngine:
     dispatching to a device.
 
     The scheduler's host logic (mirror mutation, admission, eviction,
-    pipelined dispatch-then-harvest) runs for real; only the device half
+    two dispatches in flight) runs for real; only the device half
     is simulated: ``decode_dispatch`` replays the k-wave scan's carry
     exactly the way the compiled program does (per-wave ``done`` from
     ``lengths + active >= limits``, the run mask freezing mid-scan
-    finishes), and ``force_eos`` lets the lattice driver finish a chosen
+    finishes) and KEEPS it for the next dispatch, which takes the host's
+    ``lengths`` / ``last_tok`` only where ``fresh`` says so — the
+    scheduler enqueues that dispatch before it has harvested this one.
+    ``force_eos`` lets the lattice driver finish a chosen
     slot early — the EOS-mid-wave state.
     """
 
@@ -193,6 +196,12 @@ class RecordingEngine:
         self.harvest_wait_s = 0.0
         self.prefill_chunks = 0
         self.observations: list[WaveObservation] = []
+        #: The scan's last (lengths, last_tok, run): the device's carry.
+        self.carry = (
+            np.zeros((self.max_slots,), np.int32),
+            np.zeros((self.max_slots,), np.int32),
+            np.zeros((self.max_slots,), bool),
+        )
         self.state = "init"
         #: slot -> remaining waves before a forced EOS finish.
         self.force_eos: dict[int, int] = {}
@@ -209,20 +218,23 @@ class RecordingEngine:
         return wave_signature(args)
 
     def decode_dispatch(self, block_table, lengths, last_tok, run_mask,
-                        limits, temp, top_k, top_p, eos, seeds):
+                        limits, temp, top_k, top_p, eos, seeds, fresh=None):
         from rocket_tpu.serve.engine import WaveHandle
 
+        if fresh is None:
+            fresh = np.ones((self.max_slots,), bool)
         seq = self.decode_dispatches
         self.decode_dispatches += 1
         self.decode_waves += self.waves_per_dispatch
         self.last_dispatch_at = time.perf_counter()
         args = (block_table, lengths, last_tok, run_mask, limits,
-                temp, top_k, top_p, eos, seeds)
+                temp, top_k, top_p, eos, seeds, fresh)
         assert len(args) == len(SCHEDULER_WAVE_ARGS)
         self._record("decode", args)
-        lengths = np.asarray(lengths).copy()
-        last = np.asarray(last_tok).copy()
-        run = np.asarray(run_mask).copy()
+        held_lengths, held_last, held_run = self.carry
+        lengths = np.where(fresh, lengths, held_lengths)
+        last = np.where(fresh, last_tok, held_last)
+        run = np.asarray(run_mask) & (fresh | held_run)
         toks, done, emitted = [], [], []
         for _wave in range(self.waves_per_dispatch):
             valid = run.astype(np.int32)
@@ -239,6 +251,7 @@ class RecordingEngine:
             lengths = lengths + valid
             last = nxt
             run = run & ~d
+        self.carry = (lengths, last, run)
         return WaveHandle(np.stack(toks), np.stack(done), np.stack(emitted),
                           seq=seq)
 

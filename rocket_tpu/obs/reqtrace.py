@@ -31,7 +31,7 @@ Time to first token is partitioned further: a residency's ``prefill_s``
 ``prefill_wait_s`` (admit → the request's first chunk enqueued: the wait
 in the prefill FIFO behind other requests' chunks), ``prefill_run_s``
 (first chunk → last chunk enqueued) and ``first_token_s`` (last chunk
-enqueued → first harvested wave: the dispatch-then-harvest pipeline). The
+enqueued → first harvested wave: its dispatch is fetched a tick later). The
 record keeps them under ``prefill_legs``; ``phases`` stays the four that
 partition ``[submit, finish]``, so its values still sum to the wall time. When
 a request finishes its legs also go to the span sink

@@ -45,15 +45,25 @@ through the block table, and — for a model with state layers
 decode wave's row ``s`` IS slot ``s``; the prefill program of such a model
 takes one more argument, the slot its chunk belongs to (a model without
 state layers keeps the six-argument program). A slot that does not run in
-a wave (``run_mask`` false) keeps its state bitwise; a chunk or a wave at
+a wave (run mask false, from the host or frozen by the device's own carry)
+keeps its state bitwise; a chunk or a wave at
 position 0 starts its slot's state from zeros, so slot reuse and an
 evicted request's re-prefill inherit nothing; the k waves of a dispatch
 carry the state through the scan with the pages.
-The scan SPLITS dispatch from harvest: :meth:`SlotEngine.decode_dispatch`
-enqueues the k-wave program and returns immediately with device handles,
-:meth:`SlotEngine.harvest` performs the one explicit ``jax.device_get``
-— the scheduler dispatches wave N, then admits/prefills/detokenizes
-wave N−1's results while N runs (dispatch-then-harvest pipelining).
+
+**The carry stays on the device.** The scan's last ``(lengths, last token,
+run mask)`` is an OUTPUT of the decode program that is never fetched:
+:class:`SlotEngine` keeps it beside the pool (``self.carry``) and hands it
+to the next dispatch, which takes each slot's three values from it unless
+the host marks the slot ``fresh`` — a slot that joins (its prefill just
+finished), every slot of a first dispatch. The merge is by VALUE
+(``where(fresh, host, carry)``), one program for every mix. So dispatch N+1
+does not need wave N's tokens on the host: :meth:`SlotEngine.decode_dispatch`
+enqueues it while N still runs, :meth:`SlotEngine.harvest` performs the one
+explicit ``jax.device_get`` of N afterwards, and a slot that finished inside
+N is frozen in N+1 by the carried mask although the host did not know.
+The scheduler keeps up to two dispatches in flight this way, and does its
+fetch, replay, admission and the next jit call under a running wave.
 """
 
 from __future__ import annotations
@@ -137,23 +147,35 @@ def build_decode_wave(model, on_trace: Optional[Callable] = None,
     ``on_trace`` is invoked at TRACE time inside the body (the engine
     passes its retrace counter; the auditor passes its own). Signature::
 
-        decode_wave(params, pages, block_table, lengths,
+        decode_wave(params, pages, carry, block_table, lengths,
                     last_tok, run_mask, limits, temp, top_k, top_p,
-                    eos, seeds, key)
-            -> (pages, tokens (k, S), done (k, S), emitted (k, S),
+                    eos, seeds, fresh, key)
+            -> (pages, carry, tokens (k, S), done (k, S), emitted (k, S),
                 expert_pairs (k, layers, held) or None)
 
     ``pages`` is the pool, a tuple of arrays (``TransformerLM.paged_step``).
+    ``carry`` is the ``(lengths, last_tok, run)`` the PREVIOUS dispatch's
+    scan ended with, still on the device; ``fresh`` (S,) bool marks the
+    slots whose ``lengths`` / ``last_tok`` the host sets this dispatch.
+    Every other slot continues from the carry, and runs only if the carry
+    has not frozen it: the host's ``lengths`` and ``last_tok`` may be one
+    dispatch stale there, and a slot that finished in the dispatch before
+    emits nothing though the host asked for it. ``run_mask`` still stops
+    any slot.
     """
     k = int(waves)
     if k < 1:
         raise ValueError(f"build_decode_wave: waves {k} < 1")
 
-    def decode_wave(params, pages, block_table, lengths,
+    def decode_wave(params, pages, carry, block_table, lengths,
                     last_tok, run_mask, limits, temp, top_k, top_p,
-                    eos, seeds, key):
+                    eos, seeds, fresh, key):
         if on_trace is not None:
             on_trace()  # trace-time: counts (re)traces only
+        held_lengths, held_tok, held_run = carry
+        lengths = jnp.where(fresh, lengths, held_lengths)
+        last_tok = jnp.where(fresh, last_tok, held_tok)
+        run_mask = run_mask & (fresh | held_run)
 
         def one_wave(carry, _):
             pages, lengths, last_tok, run = carry
@@ -182,10 +204,10 @@ def build_decode_wave(model, on_trace: Optional[Callable] = None,
             return carry, (nxt, done, run, pairs)
 
         init = (tuple(pages), lengths, last_tok, run_mask)
-        (pages, _, _, _), (toks, done, emitted, pairs) = jax.lax.scan(
+        (pages, *carry), (toks, done, emitted, pairs) = jax.lax.scan(
             one_wave, init, None, length=k
         )
-        return pages, toks, done, emitted, pairs
+        return pages, tuple(carry), toks, done, emitted, pairs
 
     return decode_wave
 
@@ -249,19 +271,22 @@ def abstract_wave_inputs(
     f32 = jnp.float32
     vec_i = jax.ShapeDtypeStruct((s,), i32)
     vec_f = jax.ShapeDtypeStruct((s,), f32)
+    vec_b = jax.ShapeDtypeStruct((s,), jnp.bool_)
     key = jax.eval_shape(lambda: jax.random.key(0))
     decode_args = (
         abs_params, pool,
+        (vec_i, vec_i, vec_b),                # carry: lengths, last_tok, run
         jax.ShapeDtypeStruct((s, mb), i32),   # block_table
         vec_i,                                # lengths
         vec_i,                                # last_tok
-        jax.ShapeDtypeStruct((s,), jnp.bool_),  # run_mask
+        vec_b,                                # run_mask
         vec_i,                                # limits
         vec_f,                                # temp
         vec_i,                                # top_k
         vec_f,                                # top_p
         vec_i,                                # eos
         vec_i,                                # seeds
+        vec_b,                                # fresh
         key,
     )
     prefill_args = (
@@ -322,6 +347,15 @@ class SlotEngine:
         #: The device pool: the arrays the model's layers declare, as
         #: ONE tuple handed whole to both programs and taken back whole.
         self.pages = spec.init_pages()
+        #: The ``(lengths, last_tok, run)`` the last decode dispatch's scan
+        #: ended with: an output that stays on the device and is the next
+        #: dispatch's input, never fetched. Before the first dispatch it
+        #: holds nothing a slot could continue from (no slot runs).
+        self.carry = (
+            jnp.zeros((self.max_slots,), jnp.int32),
+            jnp.zeros((self.max_slots,), jnp.int32),
+            jnp.zeros((self.max_slots,), bool),
+        )
         #: Expert pair counts of the prefill chunks enqueued since the
         #: last decode dispatch (a model with routed layers only): the
         #: next dispatch's handle takes them to its own fetch.
@@ -375,23 +409,36 @@ class SlotEngine:
     # -- compiled-step drivers ---------------------------------------------
 
     def decode_dispatch(self, block_table, lengths, last_tok, run_mask,
-                        limits, temp, top_k, top_p, eos, seeds) -> WaveHandle:
+                        limits, temp, top_k, top_p, eos, seeds,
+                        fresh=None) -> WaveHandle:
         """Enqueue one k-wave decode dispatch over every slot. All inputs
         are host arrays of shape ``(max_slots, ...)`` with fixed dtypes
         (the scheduler's mirrors); returns a :class:`WaveHandle` of
         device arrays WITHOUT synchronizing — the host keeps scheduling
-        while the device runs, and :meth:`harvest` fetches the results."""
+        while the device runs, and :meth:`harvest` fetches the results.
+
+        ``fresh`` (S,) bool: the slots that take ``lengths`` and
+        ``last_tok`` from the host; the others continue from the carry the
+        dispatch before left on the device (see :func:`build_decode_wave`),
+        so this may be called before that dispatch was harvested. ``None``
+        is a caller that keeps no dispatch in flight (:meth:`decode`):
+        every slot is the host's."""
+        if fresh is None:
+            fresh = np.ones((self.max_slots,), bool)
         seq = self.decode_dispatches
         self.decode_dispatches += 1
         self.decode_waves += self.waves_per_dispatch
         with timed("serve/dispatch", seq=seq) as sp:
             if sp.on:
-                sp.set(occupancy=int(run_mask.sum()))
+                # ``inflight``: the dispatches not yet harvested when this
+                # one was enqueued (1 = it queued behind a running wave).
+                sp.set(occupancy=int(run_mask.sum()),
+                       inflight=seq - self.device_gets)
             self.last_dispatch_at = sp.start
-            self.pages, toks, done, emitted, pairs = self._decode(
-                self._params, self.pages, block_table,
+            self.pages, self.carry, toks, done, emitted, pairs = self._decode(
+                self._params, self.pages, self.carry, block_table,
                 lengths, last_tok, run_mask, limits, temp, top_k, top_p,
-                eos, seeds, self._key,
+                eos, seeds, fresh, self._key,
             )
         if pairs is not None:
             pairs = (pairs, self.tick, self._chunk_pairs)
@@ -427,7 +474,7 @@ class SlotEngine:
     def decode(self, block_table, lengths, last_tok, run_mask, limits,
                temp, top_k, top_p, eos, seeds):
         """Dispatch-and-wait convenience (tests, simple drivers):
-        one k-wave dispatch harvested immediately."""
+        one k-wave dispatch harvested immediately, every slot the host's."""
         return self.harvest(self.decode_dispatch(
             block_table, lengths, last_tok, run_mask, limits, temp,
             top_k, top_p, eos, seeds,

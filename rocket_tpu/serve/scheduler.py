@@ -1,44 +1,65 @@
 """Continuous-batching request scheduler — the host-side policy half.
 
-Every ``tick()`` is one serving step, PIPELINED against the in-flight
-device dispatch (dispatch-then-harvest):
+Every ``tick()`` is one serving step with up to TWO decode dispatches in
+flight: dispatch N+1 is enqueued BEFORE dispatch N is fetched, so it sits
+in the device's queue while N runs and starts the instant N ends, and the
+host does its fetch, replay, admission, growing and the next jit call under
+a running wave instead of between two:
 
 1. **admit** queued requests into free slots while the block pool can
    cover their prompts (all-or-nothing — a request never half-admits);
-   free slots were free at the previous dispatch, so admission never
-   touches a slot with results in flight;
+   a free slot was freed at an earlier tick's harvest, and the dispatch
+   still in flight either did not run it or holds it frozen (below);
 2. **prefill** one fixed-size chunk of the oldest still-prefilling slot
    (chunked prefill: long prompts trickle in a chunk per tick and never
    stall the decode latency of running requests). Prefill is
    fire-and-forget and still-prefilling slots are never in a decode
-   wave, so the chunk dispatch OVERLAPS the in-flight decode — the pool
+   wave, so the chunk queues behind the dispatch in flight — the pool
    buffers thread program-order through both, so dataflow serializes
    them on device without a host sync;
-3. **harvest** the PREVIOUS tick's decode dispatch: one
+3. **grow** each decode-ready slot's block table ONE DISPATCH AHEAD: the
+   mirrors of a slot that the dispatch in flight runs are k rows behind
+   the device, so the table must cover that dispatch's rows and those of
+   the one about to be made (``lengths + 2k - 1``, capped at the slot's
+   limit);
+4. **dispatch** the next k-wave decode over all decode-ready slots. The
+   device carries ``(lengths, last token, run mask)`` from one dispatch
+   to the next (``serve/engine.py``); the host sets them only for the
+   slots it marks ``fresh`` — a slot that just finished prefill and
+   joins. A slot that finished INSIDE the dispatch in flight (limit or
+   EOS) is frozen in this one by the device's own carried mask: it
+   writes to the trash block and emits nothing, although the host did
+   not know yet;
+5. **harvest** the dispatch made one tick EARLIER: one
    ``jax.device_get`` fetches its k waves of tokens; emitted tokens
    stream out, finished slots free their blocks and are refillable on
-   the very next tick;
-4. **grow** each decode-ready slot's block table to cover the next k
-   tokens; when the pool is exhausted the YOUNGEST active request is
-   evicted — its blocks return to the pool and it re-queues at the
-   FRONT with its generated tokens folded into the prompt, so it
-   resumes exactly where it stopped after re-prefill (back-pressure,
-   never OOM). Eviction runs strictly AFTER harvest, so a preempted
-   slot never has tokens in flight to lose;
-5. **dispatch** the next k-wave decode over all decode-ready slots and
-   return step 3's events — the caller detokenizes/streams them while
-   the new dispatch runs on device.
+   the very next tick — the caller detokenizes/streams the events while
+   the newer dispatch runs on device.
+
+**Pool exhaustion drains first.** When the allocator cannot cover step 3,
+the tick harvests the dispatch in flight BEFORE it grows (the old order,
+for that tick): the mirrors are then exact, finished slots have returned
+their blocks, and only if the pool is still short is the YOUNGEST active
+request evicted — its blocks return to the pool and it re-queues at the
+FRONT with its generated tokens folded into the prompt, so it resumes
+exactly where it stopped after re-prefill (back-pressure, never OOM).
+Eviction runs strictly AFTER the harvest of everything in flight, so a
+preempted slot never has tokens in flight to lose. The scheduler decides
+this from the allocator alone; there is no setting.
 
 The scheduler owns host-side numpy mirrors of every per-slot array the
 compiled wave consumes (block table, lengths, sampling vectors, masks).
 Admission/eviction mutate the mirrors only — shapes and dtypes are fixed
 at construction, which is what keeps the engine's compiled-once guarantee
 (asserted via the trace counters in ``serve/engine.py``). The pipelining
-invariant: between a dispatch and its harvest, the only mutations are
-admission into slots the dispatch did not run and prefill of slots the
-dispatch did not run — every mirror a dispatch read was copied to device
-at dispatch time, and harvest replays the device's own per-wave length
-bookkeeping onto the mirrors before anything else can read them.
+invariant: a slot's ``lengths`` / ``last_tok`` mirrors are read by the
+device only at the dispatch the slot is ``fresh`` in, and are exact then;
+for every other running slot they trail the device by the dispatch in
+flight, and harvest replays the device's own per-wave bookkeeping onto
+them. Between a dispatch and its harvest the host touches only slots that
+dispatch did not run or holds frozen (admission, prefill, the clearing of
+a slot that finished in the dispatch before), and every mirror a dispatch
+read was copied to device at dispatch time.
 """
 
 from __future__ import annotations
@@ -132,10 +153,15 @@ class Scheduler:
         self.top_p = np.ones((s,), np.float32)
         self.eos = np.full((s,), -1, np.int32)
         self.seeds = np.zeros((s,), np.int32)
+        #: Slots whose ``lengths`` / ``last_tok`` the NEXT dispatch takes
+        #: from the mirrors: empty, prefilling and just-joined slots. A
+        #: slot that a dispatch ran continues from the device's carry
+        #: until it is cleared.
+        self.fresh = np.ones((s,), bool)
         self.slots: list[Optional[_Slot]] = [None] * s
         self.queue: deque[Request] = deque()
-        #: The in-flight decode dispatch, harvested at the NEXT tick
-        #: (dispatch-then-harvest pipelining).
+        #: The newest decode dispatch, harvested AFTER the next one is
+        #: enqueued (two in flight for that moment, else this one).
         self.pending = None
         #: Optional :class:`~rocket_tpu.obs.reqtrace.RequestTracer` —
         #: every hook below is guarded, so a bare scheduler (tests,
@@ -203,27 +229,38 @@ class Scheduler:
     # -- the serving step --------------------------------------------------
 
     def tick(self) -> list[TickEvent]:
-        """One scheduling round: admit / prefill one chunk / harvest the
-        in-flight dispatch / grow tables (evicting on exhaustion) /
-        dispatch the next k waves. Returns the tokens the HARVESTED
+        """One scheduling round: admit / prefill one chunk / grow tables
+        one dispatch ahead / dispatch the next k waves / harvest the
+        dispatch made one tick earlier. Returns the tokens the HARVESTED
         dispatch emitted (one tick behind the device — the pipelining);
-        an idle engine returns []."""
+        an idle engine returns []. A tick that finds the pool short
+        harvests first, and evicts only then."""
         # The engine dates its ``moe/expert_pairs`` records by this.
         self.engine.tick = self.ticks
         if self.queue:
             with span("serve/admit", tick=self.ticks) as sp:
                 sp.set(admitted=self._admit())
         self._prefill_one()
-        events = self._harvest_pending()
+        older, events = self.pending, []
+        drained = older is not None and self._pool_short()
+        if drained:
+            # The old order, for this tick: with nothing in flight
+            # (``pending`` None is what _last_row reads) the mirrors are
+            # exact and an eviction strands no token.
+            self.pending = None
+            events = self._harvest(older)
+            older = None
         with span("serve/grow") as sp:
             evicted = self.preemptions
             run = self._grow_tables()
-            sp.set(evicted=self.preemptions - evicted)
+            sp.set(evicted=self.preemptions - evicted, drained=int(drained))
         if run.any():
             # The counter serve/decode_pages: how much of the block table
             # this wave's running slots hold, which is all the decode
             # kernel walks (``live`` pages of ``table`` entries), and
-            # ``sample``, the branch its sampling takes on the device.
+            # ``sample``, the branch its sampling takes on the device. The
+            # host's view at dispatch: a slot that finishes in flight still
+            # counts.
             with span("serve/decode_pages", tick=self.ticks) as sp:
                 if sp.on:
                     sp.set(
@@ -233,10 +270,13 @@ class Scheduler:
                         sample=SAMPLE_BRANCHES[int(sample_branch(
                             self.temp, self.top_k, self.top_p, run))],
                     )
+            # The dispatched ``fresh`` is never written again (the device
+            # may still read it): the mirror moves on to a new array.
+            fresh, self.fresh = self.fresh, self.fresh & ~run
             self.pending = self.engine.decode_dispatch(
                 self.block_table, self.lengths, self.last_tok, run,
                 self.limits, self.temp, self.top_k, self.top_p, self.eos,
-                self.seeds,
+                self.seeds, fresh,
             )
             if self.tracer is not None:
                 # One shared wave record per dispatch (O(waves), not
@@ -247,6 +287,10 @@ class Scheduler:
                     waves=self.engine.waves_per_dispatch,
                     seq=self.pending.seq,
                 )
+        else:
+            self.pending = None
+        if older is not None:
+            events = self._harvest(older)
         elif self.pending is None and not events:
             self.waves_idle += 1
         self.ticks += 1
@@ -347,25 +391,53 @@ class Scheduler:
             # or not spans are on.
             self.tracer.on_prefill(st.req.id, sp.end, start, valid)
 
+    def _last_row(self, slot: int) -> int:
+        """The highest row the NEXT dispatch may write for ``slot``: its
+        k-th token lands at ``lengths + k - 1``, and the final token ever
+        at ``limits - 1`` (see _admit's limit math). A slot the dispatch
+        in flight runs (not ``fresh``) has a mirror k rows behind the
+        device; -1 where those rows already reach its limit — it finishes
+        in flight and does not run again."""
+        k = self.engine.waves_per_dispatch
+        length, limit = int(self.lengths[slot]), int(self.limits[slot])
+        if self.pending is not None and not self.fresh[slot]:
+            if length + k >= limit:
+                return -1
+            length += k
+        return min(length + k - 1, max(limit - 1, length))
+
+    def _pool_short(self) -> bool:
+        """Whether the allocator cannot cover what :meth:`_grow_tables`
+        is about to ask for. A slot's table covers the row its mirror
+        names, so a tick asks at most the blocks of 2k more rows a slot:
+        with that many free for every slot the count is skipped."""
+        free = self.allocator.num_free
+        k = self.engine.waves_per_dispatch
+        if free >= len(self.slots) * (2 * k // self.block_len + 1):
+            return False
+        want = sum(
+            max(0, self._last_row(slot) // self.block_len + 1 - len(st.blocks))
+            for slot, st in enumerate(self.slots)
+            if st is not None and st.prefill_done
+        )
+        return want > free
+
     def _grow_tables(self) -> np.ndarray:
         """Cover every position the next dispatch may write — up to
-        ``waves_per_dispatch`` tokens per decode-ready slot, capped at
-        the slot's length limit — evicting the youngest active request
-        on pool exhaustion. Returns the dispatch's run mask. Runs only
-        with no dispatch in flight (tick() harvests first), so eviction
-        never strands in-flight tokens."""
-        k = self.engine.waves_per_dispatch
+        ``waves_per_dispatch`` tokens per decode-ready slot beyond what
+        the dispatch in flight writes, capped at the slot's length limit
+        — evicting the youngest active request on pool exhaustion.
+        Returns the dispatch's run mask. It meets an empty allocator only
+        with no dispatch in flight (tick() harvests first when
+        :meth:`_pool_short`), so eviction never strands in-flight
+        tokens."""
         run = np.zeros((self.engine.max_slots,), bool)
         for slot, st in enumerate(self.slots):
             if st is None or not st.prefill_done:
                 continue
-            # Highest row this dispatch can write: the k-th token lands
-            # at lengths + k - 1, and the final token ever lands at
-            # limits - 1 (see _admit's limit math).
-            last_pos = min(
-                int(self.lengths[slot]) + k - 1,
-                max(int(self.limits[slot]) - 1, int(self.lengths[slot])),
-            )
+            last_pos = self._last_row(slot)
+            if last_pos < 0:
+                continue
             need_idx = last_pos // self.block_len
             while need_idx >= len(st.blocks):
                 got = self.allocator.alloc(1)
@@ -404,12 +476,9 @@ class Scheduler:
             self.tracer.on_evict(st.req.id, time.perf_counter())
         self._clear(slot)
 
-    def _harvest_pending(self) -> list[TickEvent]:
-        """Fetch the in-flight dispatch (ONE ``jax.device_get`` for its
-        k waves) and replay it onto the host mirrors."""
-        if self.pending is None:
-            return []
-        handle, self.pending = self.pending, None
+    def _harvest(self, handle) -> list[TickEvent]:
+        """Fetch one dispatch (ONE ``jax.device_get`` for its k waves)
+        and replay it onto the host mirrors."""
         toks, done, emitted = self.engine.harvest(handle)
         with span("serve/replay", seq=handle.seq) as sp:
             # `now` is the instant the fetch returned, as harvest read it.
@@ -461,6 +530,7 @@ class Scheduler:
 
     def _clear(self, slot: int) -> None:
         self.slots[slot] = None
+        self.fresh[slot] = True
         self.block_table[slot] = 0
         self.lengths[slot] = 0
         self.last_tok[slot] = 0

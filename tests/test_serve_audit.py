@@ -253,9 +253,11 @@ def test_lattice_enumeration_is_complete_and_single_signature():
     assert len(decode_sigs) == 1
     assert len(prefill_sigs) == 1
     assert check_retrace_surface(observations) == []
-    # The decode signature is the scheduler's 10 fixed-shape mirrors.
+    # The decode signature is the scheduler's 11 fixed-shape mirrors
+    # (``fresh`` the last: which slots the host sets this dispatch).
     (sig,) = decode_sigs
-    assert len(sig) == 10 and all(leaf[0] == "array" for leaf in sig)
+    assert len(sig) == 11 and all(leaf[0] == "array" for leaf in sig)
+    assert sig[-1] == ("array", (engine.max_slots,), "bool")
 
 
 def test_lattice_respects_non_block_multiple_max_seq_len():
@@ -465,6 +467,63 @@ def test_recording_engine_scan_freezes_mid_dispatch():
     assert not emitted[:, 2].any() and not emitted[:, 3].any()
     assert engine.device_gets == 1 and engine.decode_dispatches == 1
     assert engine.decode_waves == 4
+
+
+def test_recording_engine_carries_its_scan_state_across_dispatches():
+    """As the compiled program does: a dispatch takes ``lengths`` and
+    ``last_tok`` from the host only where ``fresh`` says so, and a slot
+    the dispatch before finished stays frozen though the host's mask
+    still names it — the scheduler enqueues before it has harvested."""
+    engine = _tiny_engine()
+    block_table = np.zeros((4, 8), np.int32)
+    z_i, z_f = np.zeros((4,), np.int32), np.zeros((4,), np.float32)
+    knobs = (z_f, z_i, np.ones((4,), np.float32), np.full((4,), -1, np.int32), z_i)
+    run = np.asarray([True, True, False, False])
+    limits = np.asarray([1, 10, 0, 0], np.int32)     # slot 0 ends in the first
+    first = engine.decode_dispatch(
+        block_table, z_i, np.asarray([1, 2, 3, 4], np.int32), run, limits,
+        *knobs, np.ones((4,), bool))
+    # The host's mirrors are stale (still 0 / the old tokens) and say so.
+    second = engine.decode_dispatch(
+        block_table, z_i, np.asarray([1, 2, 3, 4], np.int32), run, limits,
+        *knobs, np.zeros((4,), bool))
+    assert engine.decode_dispatches - engine.device_gets == 2
+    toks1, done1, emitted1 = engine.harvest(first)
+    toks2, _, emitted2 = engine.harvest(second)
+    np.testing.assert_array_equal(emitted1[0], [True, True, False, False])
+    np.testing.assert_array_equal(done1[0], [True, False, False, False])
+    np.testing.assert_array_equal(emitted2[0], [False, True, False, False])
+    assert toks2[0, 1] == (toks1[0, 1] + 1) % 7      # continued, not restarted
+    np.testing.assert_array_equal(engine.carry[0], [1, 2, 0, 0])
+    # A fresh slot takes the host's values whatever the carry holds.
+    third = engine.decode_dispatch(
+        block_table, np.asarray([5, 0, 0, 0], np.int32),
+        np.asarray([3, 0, 0, 0], np.int32), run, np.full((4,), 10, np.int32),
+        *knobs, np.asarray([True, False, False, False]))
+    toks3, _, emitted3 = engine.harvest(third)
+    np.testing.assert_array_equal(emitted3[0], [True, True, False, False])
+    assert toks3[0, 0] == 4
+    np.testing.assert_array_equal(engine.carry[0], [6, 3, 0, 0])
+
+
+def test_the_lattice_is_driven_with_two_dispatches_in_flight():
+    """The drive that sees all nine states runs the scheduler's real
+    order: a decode dispatch is enqueued while the one before it is
+    unharvested, and the drained ticks of the eviction phase are the only
+    ones that harvest first."""
+    engine = _tiny_engine()
+    outstanding = []
+    dispatch = engine.decode_dispatch
+
+    def counted(*args):
+        outstanding.append(engine.decode_dispatches - engine.device_gets)
+        return dispatch(*args)
+
+    engine.decode_dispatch = counted
+    _, findings, states = enumerate_admission_lattice(engine)
+    assert findings == [] and REQUIRED_LATTICE_STATES <= states
+    assert set(outstanding) == {0, 1}
+    assert outstanding.count(1) > outstanding.count(0)
 
 
 # -- target hygiene ----------------------------------------------------------

@@ -324,13 +324,14 @@ def test_serve_tick_children_lie_inside_their_tick_and_do_not_overlap(tiny_lm, s
             kinds.add(child.name)
         for a, b in zip(children, children[1:]):
             assert a.end <= b.start, (a.name, b.name)
-        # The order of a tick: admit, enqueue a chunk, harvest, replay,
-        # grow, the wave's page count, dispatch.
+        # The order of a tick: admit, enqueue a chunk, grow, the wave's
+        # page count, dispatch the next, THEN harvest and replay the one
+        # before it (the pool is roomy: no tick drains first).
         order = [c.name for c in children]
         assert order == sorted(order, key=[
-            "serve/admit", "serve/prefill_enqueue", "serve/harvest_wait",
-            "serve/replay", "serve/grow", "serve/decode_pages",
-            "serve/dispatch",
+            "serve/admit", "serve/prefill_enqueue", "serve/grow",
+            "serve/decode_pages", "serve/dispatch", "serve/harvest_wait",
+            "serve/replay",
         ].index)
     assert kinds == {
         "serve/admit", "serve/prefill_enqueue", "serve/harvest_wait",
@@ -353,6 +354,18 @@ def test_serve_tick_children_lie_inside_their_tick_and_do_not_overlap(tiny_lm, s
     assert set(waves) <= set(by_seq)
     dispatched = [ev for ev in events if ev.name == "serve/dispatch"]
     assert all(1 <= ev.ids["occupancy"] <= 2 for ev in dispatched)
+    # ``inflight``: the dispatches unharvested when this one was enqueued.
+    # The first finds none; in steady decoding each queues behind one.
+    inflight = [ev.ids["inflight"] for ev in dispatched]
+    assert inflight[0] == 0 and set(inflight) == {0, 1}
+    assert inflight.count(1) >= len(inflight) - 2
+    starts = {ev.ids["seq"]: ev.start for ev in dispatched}
+    waited = {ev.ids["seq"]: ev.start for ev in events
+              if ev.name == "serve/harvest_wait"}
+    for seq, behind in zip(sorted(starts)[1:], inflight[1:]):
+        assert (starts[seq] < waited[seq - 1]) == bool(behind), seq
+    grows = [ev.ids for ev in events if ev.name == "serve/grow"]
+    assert grows and all(ids == {"evicted": 0, "drained": 0} for ids in grows)
     admitted = sum(ev.ids["admitted"] for ev in events if ev.name == "serve/admit")
     assert admitted == len(rids)
     # A request's legs share its rid with its chunks' spans.

@@ -161,6 +161,36 @@ def test_a_reused_slot_inherits_nothing(hybrid):
     assert both[0] == _serve(_engine(hybrid, max_slots=1), [a])[0]
 
 
+def test_a_slot_frozen_in_flight_and_refilled_starts_from_zeros(hybrid):
+    """One slot. A ends on an EOS the host learns only after it enqueued
+    the next dispatch with A's slot in its run mask: the device's carried
+    mask freezes the slot there (its state stays as A left it), then B is
+    admitted into it. B's tokens are a fresh engine's, and A's end at the
+    EOS."""
+    a, b = _prompts(2, seed=5)
+    alone = _serve(_engine(hybrid, max_slots=1), [a])[0]
+    eos = alone[4]
+    engine = _engine(hybrid, max_slots=1)
+    asked = []
+    dispatch = engine.engine.decode_dispatch
+
+    def logged(*args):
+        asked.append((bool(args[3][0]), bool(args[-1][0])))   # run, fresh
+        return dispatch(*args)
+
+    engine.engine.decode_dispatch = logged
+    rids = [engine.submit(a, max_new_tokens=12, temperature=0.0, eos_token_id=eos),
+            engine.submit(b, max_new_tokens=12, temperature=0.0)]
+    engine.drain()
+    got_a, got_b = (engine.result(r).tokens for r in rids)
+    assert got_a == alone[:alone.index(eos) + 1] and len(got_a) < 12
+    # A ran fresh once, then from the carry: one of those dispatches came
+    # after its EOS (asked for, frozen); B joined fresh.
+    assert asked[:len(got_a) + 1] == [(True, True)] + [(True, False)] * len(got_a)
+    assert asked[len(got_a) + 1] == (True, True)
+    assert got_b == _serve(_engine(hybrid, max_slots=1), [b])[0]
+
+
 def test_evict_and_reprefill_gives_the_undisturbed_tokens(hybrid):
     """A pool too small for the load preempts and re-prefills (its blocks
     go, its slot's state stays behind as garbage): every request ends with
@@ -321,7 +351,7 @@ def test_the_pool_counts_caching_layers_and_holds_state_by_slot(hybrid):
     assert [a.shape for a in spec.init_pages()] == [s for s, _ in spec.arrays]
     decode_args, prefill_args = abstract_wave_inputs(
         model, spec, max_slots=4, max_blocks_per_seq=mb, prefill_chunk=8)
-    assert len(decode_args) == 13 and len(prefill_args) == 7   # + the slot
+    assert len(decode_args) == 15 and len(prefill_args) == 7   # + the slot
     assert [a.shape for a in decode_args[1]] == [s for s, _ in spec.arrays]
     with pytest.raises(ValueError, match="max_slots"):
         KVPoolSpec(num_layers=1, num_blocks=2, block_len=4, lanes=(8, 8),
@@ -388,7 +418,7 @@ def test_models_without_state_layers_are_what_they_were(name, fixture):
     decode_args, prefill_args = abstract_wave_inputs(
         model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
         prefill_chunk=sc.prefill_chunk)
-    assert len(decode_args) == 13 and len(prefill_args) == 6
+    assert len(decode_args) == 15 and len(prefill_args) == 6
     engine = ServeEngine(model, params, sc)
     assert [list(a.shape) for a in engine.engine.pages] == [list(s) for s in pages]
     rng = np.random.default_rng(7)

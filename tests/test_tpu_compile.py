@@ -344,6 +344,25 @@ def test_latent_serve_programs_update_the_pool_in_place(sds, monkeypatch):
         assert any(name in k for k in kernels["decode"]), kernels
 
 
+def _assert_carry_stays_on_device(text, slots):
+    """The decode program's carry — ``(lengths, last_tok, run)``, what lets
+    the scheduler enqueue a dispatch before it has fetched the one before —
+    compiled for the described chip: three ``(slots,)`` vectors among the
+    entry computation's parameters AND among its results (the next
+    dispatch's inputs, which the engine never fetches), and no instruction
+    that moves anything to the host from inside the program."""
+    import re
+
+    entry = re.search(r"^ENTRY [^\n]*?\((.*)\) -> \((.*)\) \{$", text, re.M)
+    assert entry, text[:300]
+    params, results = entry.groups()
+    for side in (params, results):
+        assert len(re.findall(rf"\bs32\[{slots}\]", side)) >= 2, side
+        assert len(re.findall(rf"\bpred\[{slots}\]", side)) >= 1, side
+    moved = re.findall(r" (outfeed|send|send-done|recv|recv-done)\(", text)
+    assert not moved and "MoveToHost" not in text, moved
+
+
 def _assert_pool_in_place(sds, model, sc, decode_kernel):
     """Both programs of ``model`` under ``sc``, compiled for the described
     chip: see the tests above. Returns the kernels of each program."""
@@ -382,6 +401,7 @@ def _assert_pool_in_place(sds, model, sc, decode_kernel):
         found[name] = kernels
         if name == "decode":
             assert any(decode_kernel in k for k in kernels), (name, kernels)
+            _assert_carry_stays_on_device(text, sc.max_slots)
         large = [
             (op, inst) for op, inst, elements, line in _materialised(text)
             if elements >= layer_slice
@@ -531,6 +551,8 @@ def test_hybrid_serve_programs_update_pages_and_state_in_place(sds, monkeypatch)
         kernels = _kernel_instructions(text)
         for kernel in wanted:
             assert any(kernel in k for k in kernels), (name, kernel, kernels)
+        if name == "decode":
+            _assert_carry_stays_on_device(text, sc.max_slots)
         materialised = _materialised(text)
         # Asynchronous moves into or out of the chip's fast memory.
         moves = {inst for op, inst, _, line in materialised
