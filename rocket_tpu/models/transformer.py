@@ -171,6 +171,23 @@ class TransformerConfig:
     ssm: Optional[Any] = None
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
+    #: Gated DeltaNet mixers (``nn.gdn.GatedDeltaNet``) as the state
+    #: layers, in place of ``ssm``: a ``GatedDeltaNetConfig``. The same
+    #: period-and-offset rule says which layers keep attention; what a slot
+    #: carries is a MATRIX a head (:meth:`slot_state_shapes`).
+    gdn: Optional[Any] = None
+    #: Attention head width where it is not ``dim // num_heads``.
+    head_dim: Optional[int] = None
+    #: Gated attention (``nn.attention.MultiHeadAttention``): the query
+    #: projection twice as wide, ``sigmoid(gate) * attention`` before the
+    #: output projection.
+    attn_gate: bool = False
+    #: A per-head RMSNorm of q and of k (one weight over the head's lanes).
+    qk_norm: bool = False
+    #: Share of each head's lanes that rotate (the first ones).
+    rope_fraction: float = 1.0
+    #: RMSNorm weights stored about zero and applied as ``1 + w``.
+    norm_zero_centered: bool = False
     #: Label smoothing for ``next_token_loss``: the target distribution is
     #: (1-eps) one-hot + eps uniform. Lives on the CONFIG (not the
     #: objective) so the fused (loss_chunk) and full-logits paths apply the
@@ -251,7 +268,14 @@ class TransformerConfig:
             raise ValueError(
                 "TransformerConfig: first_dense_layers without routed_experts"
             )
-        if self.ssm is not None:
+        if self.ssm is not None and self.gdn is not None:
+            raise ValueError(
+                "TransformerConfig: ssm and gdn are two kinds of state "
+                "layer; give one")
+        if self.norm_zero_centered and self.norm != "rmsnorm":
+            raise ValueError(
+                "TransformerConfig: norm_zero_centered is RMSNorm's")
+        if self.state_mixer is not None:
             if self.attn_layer_period < 0 or (
                 self.attn_layer_period
                 and not 0 <= self.attn_layer_offset < self.attn_layer_period
@@ -275,7 +299,7 @@ class TransformerConfig:
         elif self.attn_layer_period or self.attn_layer_offset:
             raise ValueError(
                 "TransformerConfig: attn_layer_period / attn_layer_offset "
-                "without ssm"
+                "without ssm or gdn"
             )
         if self.latent_attention is not None and self.pos_embedding != "rope":
             raise ValueError(
@@ -309,9 +333,17 @@ class TransformerConfig:
     def make_norm(self, features: int):
         """A normalizer of the configured class and epsilon."""
         cls = self.norm_cls()
-        if self.norm_eps is None:
-            return cls(features)
-        return cls(features, eps=self.norm_eps)
+        how = {} if self.norm_eps is None else {"eps": self.norm_eps}
+        if self.norm_zero_centered:
+            how["zero_centered"] = True
+        return cls(features, **how)
+
+    @property
+    def state_mixer(self):
+        """The state layers' mixer configuration, of whichever kind is
+        given (``ssm`` or ``gdn``), or None: it declares what a slot
+        carries (``state_shapes``) and builds the layer (``make_mixer``)."""
+        return self.ssm if self.ssm is not None else self.gdn
 
     @property
     def kv_pool_lanes(self) -> tuple:
@@ -322,14 +354,14 @@ class TransformerConfig:
         if self.latent_attention is not None:
             return (self.latent_attention.pool_lanes,)
         lanes = (self.num_kv_heads or self.num_heads) * (
-            self.dim // self.num_heads
+            self.head_dim or self.dim // self.num_heads
         )
         return (lanes, lanes)
 
     def is_state_layer(self, layer_idx: int) -> bool:
         """Whether layer ``layer_idx`` is a state-space mixer (else it is
         attention and caches pages)."""
-        if self.ssm is None:
+        if self.state_mixer is None:
             return False
         period = self.attn_layer_period
         return not (period and layer_idx % period == self.attn_layer_offset)
@@ -356,7 +388,7 @@ class TransformerConfig:
             return ()
         return tuple(
             (self.state_layers, shape, dtype) for shape, dtype in
-            self.ssm.state_shapes(self.activation_dtype or "float32")
+            self.state_mixer.state_shapes(self.activation_dtype or "float32")
         )
 
     @staticmethod
@@ -425,11 +457,8 @@ class Block(Layer):
         self.latent = c.latent_attention is not None
         self.attn = self.mixer = None
         if c.is_state_layer(layer_idx):
-            from rocket_tpu.nn.ssm import MambaMixer
-
-            self.mixer = MambaMixer(
-                c.dim, c.ssm,
-                norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
+            self.mixer = c.state_mixer.make_mixer(
+                c.dim, norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
             )
         elif self.latent:
             from rocket_tpu.nn.attention import LatentAttention
@@ -445,7 +474,10 @@ class Block(Layer):
                 causal=c.causal, dropout=c.dropout, use_bias=c.attn_bias,
                 impl=c.attention_impl,
                 seq_axis=c.seq_axis, rope=c.pos_embedding == "rope",
-                rope_base=c.rope_base,
+                rope_base=c.rope_base, head_dim=c.head_dim, gate=c.attn_gate,
+                qk_norm=c.qk_norm, rope_fraction=c.rope_fraction,
+                norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
+                norm_zero_centered=c.norm_zero_centered,
             )
         self.ln2 = c.make_norm(c.dim)
         self.routed = None
@@ -496,6 +528,7 @@ class Block(Layer):
         self._block_attn_ok = (
             not self.latent
             and self.mixer is None
+            and not self.attn.extended
             and c.norm == "layernorm"
             and c.pos_embedding == "learned"
             and c.causal
@@ -1523,7 +1556,9 @@ def generate(
     import numpy as np
 
     if use_cache and (
-        model.config.attention_impl == "ring" or model.config.ssm is not None
+        model.config.attention_impl == "ring"
+        or model.config.state_mixer is not None
+        or any(getattr(b.attn, "extended", False) for b in model.blocks)
     ):
         # See docstring — no dense KV cache to fill; a state layer's cache
         # is its state, which only the serving path carries.

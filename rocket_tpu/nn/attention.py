@@ -337,6 +337,19 @@ class MultiHeadAttention(Layer):
     group from its one kv head — ``ops/flash_native.py``). The XLA
     fallback is a grouped einsum; cached decode always runs grouped on the
     small cache. The ring variant requires equal head counts.
+
+    **Gated attention** (Qwen3-Next's), four options, all off by default
+    and then inert — the same parameters, the same programs: ``head_dim``
+    (a head width that is not ``features // num_heads``: the projections
+    are ``features -> heads * head_dim -> features``), ``gate`` (the query
+    projection twice as wide, ``[q | gate | k | v]``; ``sigmoid(gate) *
+    attention`` before the output projection), ``qk_norm`` (an RMSNorm over
+    each head of q and of k before the rotation, one weight for all heads:
+    ``q_norm``, ``k_norm``), ``rope_fraction`` (rotary over the first
+    ``rope_dim = head_dim * rope_fraction`` lanes of each head, the rest
+    untouched). A layer with any of them (:attr:`extended`) runs the XLA
+    path in :meth:`apply` and the paged path in serving; it has no dense
+    cache (:meth:`apply_cached` raises) and no overlapped TP path.
     """
 
     def __init__(
@@ -351,8 +364,14 @@ class MultiHeadAttention(Layer):
         seq_axis: str = "seq",
         rope: bool = False,
         rope_base: float = 10000.0,
+        head_dim: Optional[int] = None,
+        gate: bool = False,
+        qk_norm: bool = False,
+        rope_fraction: float = 1.0,
+        norm_eps: float = 1e-6,
+        norm_zero_centered: bool = False,
     ):
-        if features % num_heads != 0:
+        if head_dim is None and features % num_heads != 0:
             raise ValueError(
                 f"MultiHeadAttention: features {features} not divisible by "
                 f"num_heads {num_heads}"
@@ -370,38 +389,106 @@ class MultiHeadAttention(Layer):
                 "MultiHeadAttention: impl='ring' requires num_kv_heads == "
                 "num_heads"
             )
-        if rope and (features // num_heads) % 2 != 0:
+        own_width = head_dim is not None and head_dim * num_heads != features
+        head_dim = features // num_heads if head_dim is None else int(head_dim)
+        #: Lanes of each head that rotate (the first ``rope_dim``).
+        self.rope_dim = int(head_dim * rope_fraction)
+        if rope and self.rope_dim % 2 != 0:
             raise ValueError("MultiHeadAttention: rope needs an even head_dim")
+        #: Any of the gated-attention options (a head width of its own, the
+        #: output gate, per-head q/k norms, rotary over part of the head):
+        #: such a layer runs the XLA path in :meth:`apply`, the paged path
+        #: in serving, and has no dense cache and no overlapped TP path.
+        self.extended = bool(
+            own_width or gate or qk_norm or self.rope_dim != head_dim)
+        if self.extended and impl == "ring":
+            raise ValueError(
+                "MultiHeadAttention: impl='ring' takes none of head_dim, "
+                "gate, qk_norm, rope_fraction")
         self.rope = rope
         self.rope_base = rope_base
         self.features = features
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads
-        self.head_dim = features // num_heads
+        self.head_dim = head_dim
+        self.gate = gate
         self.causal = causal
         self.dropout = dropout
         self.impl = impl
         self.seq_axis = seq_axis
         self._ring_mesh = None  # pinned at first ring trace
         self._flash_mesh = None  # pinned at first multi-device flash trace
+        # Columns ``[q | gate | k | v]`` (``gate`` only where asked for),
+        # each head by head.
         self.qkv = Dense(
             features,
-            (num_heads + 2 * num_kv_heads) * self.head_dim,
+            ((2 if gate else 1) * num_heads + 2 * num_kv_heads) * self.head_dim,
             use_bias=use_bias,
         )
         self.proj = Dense(
-            features,
+            num_heads * self.head_dim,
             features,
             use_bias=use_bias,
             # GPT-2 style residual-scaled init is applied at the model level.
         )
+        self.qk_norm = None
+        if qk_norm:
+            from rocket_tpu.nn.layers import RMSNorm
+
+            # One weight over the head's lanes, for every head.
+            self.qk_norm = RMSNorm(
+                self.head_dim, eps=norm_eps, zero_centered=norm_zero_centered)
 
     def init_params(self, key):
         k1, k2 = jax.random.split(key)
-        return {
+        params = {
             "qkv": self.qkv.init(k1)["params"],
             "proj": self.proj.init(k2)["params"],
         }
+        if self.qk_norm is not None:
+            params["q_norm"] = self.qk_norm.init_params(None)
+            params["k_norm"] = self.qk_norm.init_params(None)
+        return params
+
+    def _project(self, params, x, positions):
+        """The front of :meth:`apply_paged` and of a layer with the
+        gated-attention options (:attr:`extended`): ``x`` (B, T, D)
+        -> ``q`` (B, T, H, Dh), ``k``, ``v`` (B, T, Hkv, Dh) and ``gate``
+        (B, T, H * Dh) or None — q and k through their per-head norms and
+        rotated over their first ``rope_dim`` lanes at ``positions[b] ..
+        positions[b] + T``."""
+        b, t, _ = x.shape
+        fused, _ = self.qkv.apply({"params": params["qkv"], "state": {}}, x)
+        d = self.head_dim
+        hw, kvw = self.num_heads * d, self.num_kv_heads * d
+        q = fused[..., :hw].reshape(b, t, self.num_heads, d)
+        gate = None
+        if self.gate:
+            gate, fused = fused[..., hw:2 * hw], fused[..., hw:]
+        k = fused[..., hw:hw + kvw].reshape(b, t, self.num_kv_heads, d)
+        v = fused[..., hw + kvw:].reshape(b, t, self.num_kv_heads, d)
+        if self.qk_norm is not None:
+            norm = lambda name, a: self.qk_norm.apply(
+                {"params": params[name], "state": {}}, a)[0]
+            q, k = norm("q_norm", q), norm("k_norm", k)
+        if self.rope:
+            r = self.rope_dim
+
+            def rotate(a):
+                if r == d:
+                    return apply_rope_offsets(a, positions, self.rope_base)
+                turned = apply_rope_offsets(a[..., :r], positions, self.rope_base)
+                return jnp.concatenate([turned, a[..., r:]], axis=-1)
+
+            q, k = rotate(q), rotate(k)
+        return q, k, v, gate
+
+    def _gated_out(self, params, out, gate):
+        """``proj(out * sigmoid(gate))`` — ``out`` (B, T, H * Dh)."""
+        if gate is not None:
+            out = (out.astype(jnp.float32)
+                   * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
+        return self.proj.apply({"params": params["proj"], "state": {}}, out)[0]
 
     def _split_heads(self, fused, b, t):
         """(B, T, (H+2Hkv)*Dh) -> q (B, H, T, D), k/v (B, Hkv, T, D)."""
@@ -513,7 +600,7 @@ class MultiHeadAttention(Layer):
         and the attention core keeps whole heads per device. The ring
         impl is excluded — it shards the SEQUENCE through attention,
         which is the opposite layout."""
-        if self.impl == "ring":
+        if self.impl == "ring" or self.extended:
             return None
         from rocket_tpu.parallel import collectives as coll
 
@@ -609,6 +696,13 @@ class MultiHeadAttention(Layer):
         spec = self._tp_spec(t)
         if spec is not None:
             return self._apply_tp(spec, p, x, mode, rng), variables["state"]
+        if self.extended:
+            q, k, v, gate = self._project(p, x, jnp.zeros((b,), jnp.int32))
+            out = grouped_dot_product_attention(
+                *(jnp.moveaxis(a, 1, 2) for a in (q, k, v)), causal=self.causal)
+            out = self._attn_dropout(jnp.moveaxis(out, 1, 2), mode, rng)
+            out = out.reshape(b, t, self.num_heads * self.head_dim)
+            return self._gated_out(p, out, gate), variables["state"]
         fused, _ = self.qkv.apply({"params": p["qkv"], "state": {}}, x)
         impl = resolve_impl(
             self.impl, t, self.head_dim, b, self.num_heads, self.num_kv_heads,
@@ -698,6 +792,10 @@ class MultiHeadAttention(Layer):
         attention in ONE kernel instead of ~8 — decode throughput is
         launch-count-bound (docs/performance.md). Prefill (S > 1) and CPU
         keep the einsum path."""
+        if self.extended:
+            raise NotImplementedError(
+                "MultiHeadAttention: head_dim / gate / qk_norm / "
+                "rope_fraction have no dense-cache path (serving is paged)")
         b, s, _ = x.shape
         fused, _ = self.qkv.apply({"params": params["qkv"], "state": {}}, x)
         q, k, v = self._split_heads(fused, b, s)
@@ -766,28 +864,16 @@ class MultiHeadAttention(Layer):
         :meth:`init_cache`."""
         from rocket_tpu.ops.paged_attention import paged_attention
 
-        s, c, _ = x.shape
-        fused, _ = self.qkv.apply({"params": params["qkv"], "state": {}}, x)
-        hw = self.num_heads * self.head_dim
-        kvw = self.num_kv_heads * self.head_dim
-        q2 = fused[..., :hw].reshape(s, c, self.num_heads, self.head_dim)
-        k2 = fused[..., hw:hw + kvw].reshape(
-            s, c, self.num_kv_heads, self.head_dim
-        )
-        v2 = fused[..., hw + kvw:].reshape(
-            s, c, self.num_kv_heads, self.head_dim
-        )
-        if self.rope:
-            # Per-slot absolute positions; keys enter the pool already
-            # rotated, so cached rows never need re-rotation.
-            q2 = apply_rope_offsets(q2, positions, self.rope_base)
-            k2 = apply_rope_offsets(k2, positions, self.rope_base)
+        # One front for every option: with all of them off it lowers to the
+        # plain split and whole-head rotation, operation for operation.
+        # Keys enter the pool already rotated at their slot's absolute
+        # positions, so cached rows never need re-rotation.
+        q2, k2, v2, gate = self._project(params, x, positions)
         out, k_pages, v_pages = paged_attention(
             q2, k2, v2, k_pages, v_pages, block_table, positions, valid,
             layer=layer,
         )
-        out, _ = self.proj.apply({"params": params["proj"], "state": {}}, out)
-        return out, k_pages, v_pages
+        return self._gated_out(params, out, gate), k_pages, v_pages
 
     def __repr__(self):
         kv = (

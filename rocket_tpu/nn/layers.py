@@ -26,6 +26,7 @@ __all__ = [
     "BatchNorm",
     "bn_act_train",
     "LayerNorm",
+    "gated_rms_norm",
     "Embedding",
     "Dropout",
     "Flatten",
@@ -444,24 +445,42 @@ class LayerNorm(Layer):
 
 class RMSNorm(Layer):
     """Root-mean-square norm (no centering, no bias) — the Llama-family
-    normalizer. f32 statistics inside any compute dtype, like LayerNorm."""
+    normalizer. f32 statistics inside any compute dtype, like LayerNorm.
+    ``zero_centered``: the weight is stored about zero and applied as ``1 +
+    scale`` (Qwen3-Next's, Gemma's), so that it starts at zeros."""
 
-    def __init__(self, num_features: int, eps: float = 1e-6):
+    def __init__(self, num_features: int, eps: float = 1e-6,
+                 zero_centered: bool = False):
         self.num_features = num_features
         self.eps = eps
+        self.zero_centered = zero_centered
 
     def init_params(self, key):
-        return {"scale": jnp.ones((self.num_features,), jnp.float32)}
+        init = jnp.zeros if self.zero_centered else jnp.ones
+        return {"scale": init((self.num_features,), jnp.float32)}
 
     def apply(self, variables, x, *, mode="train", rng=None):
         p = variables["params"]
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + self.eps) * p["scale"]
+        scale = p["scale"]
+        if self.zero_centered:
+            scale = 1.0 + scale.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(ms + self.eps) * scale
         return y.astype(x.dtype), variables["state"]
 
     def __repr__(self):
         return f"RMSNorm({self.num_features})"
+
+
+def gated_rms_norm(x, gate, scale, eps: float = 1e-6):
+    """``RMSNorm(x) * scale * silu(gate)`` over the last axis, in float32
+    (the output norm of a gated linear-attention head: plain weight, the
+    gate applied after the norm). Returns float32."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
+    return y * jax.nn.silu(gate.astype(jnp.float32))
 
 
 class Embedding(Layer):

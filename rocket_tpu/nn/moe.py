@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from rocket_tpu.nn.layers import Dense
 from rocket_tpu.nn.module import Layer
 
-__all__ = ["MoE", "RoutedExperts", "RoutedExpertsConfig", "route_sigmoid_grouped"]
+__all__ = ["MoE", "RoutedExperts", "RoutedExpertsConfig",
+           "route_sigmoid_grouped", "route_softmax"]
 
 
 def _gmm_config(m: int, k: int, n: int, dtype) -> dict:
@@ -57,8 +58,12 @@ def _gmm_config(m: int, k: int, n: int, dtype) -> dict:
 def _gmm_tiling(m: int, k: int, n: int, dtype) -> tuple:
     """Clamped megablox tile triple (see :func:`_gmm_config`)."""
     config = _gmm_config(m, k, n, dtype)
-    return (min(config["tile_m"], m), min(config["tile_k"], k),
-            min(config["tile_n"], n))
+    tile_m = min(config["tile_m"], m)
+    if m % tile_m:
+        # megablox wants whole row tiles: the tallest that divides ``m`` in
+        # whole sublane tiles (640 rows — 64 slots x 10 choices — take 320).
+        tile_m = max((d for d in range(8, tile_m, 8) if m % d == 0), default=tile_m)
+    return (tile_m, min(config["tile_k"], k), min(config["tile_n"], n))
 
 
 def _grouped_matmul(lhs, rhs, group_sizes):
@@ -464,9 +469,14 @@ class MoE(Layer):
 
 @dataclass(frozen=True)
 class RoutedExpertsConfig:
-    """The routed FFN of a DeepSeek-V3-style layer (arXiv 2412.19437,
-    §2.1.2 and its auxiliary-loss-free balancing) as ONE chip of an
-    expert-parallel deployment holds it."""
+    """A routed FFN as ONE chip of an expert-parallel deployment holds it.
+    Two routers (``scoring``): ``"sigmoid"`` — DeepSeek-V3's (arXiv
+    2412.19437, §2.1.2 and its auxiliary-loss-free balancing): sigmoid
+    scores, a selection bias, group-limited choice
+    (:func:`route_sigmoid_grouped`); ``"softmax"`` — Qwen3-Next's and
+    Qwen3-MoE's: a softmax over all experts, the ``top_k`` largest,
+    renormalised over the chosen (:func:`route_softmax`; no bias, no
+    groups)."""
 
     num_experts: int            # the router's outputs (all chips' experts)
     top_k: int
@@ -477,6 +487,11 @@ class RoutedExpertsConfig:
     routed_scaling_factor: float = 1.0
     #: Width of the shared expert (``n_shared_experts * hidden``); 0 = none.
     shared_hidden: int = 0
+    #: The shared expert behind a gate of its own: ``sigmoid(w_sg . x) *
+    #: E_shared(x)`` (parameter ``shared/w_sg`` (D, 1)).
+    shared_gate: bool = False
+    #: "sigmoid" | "softmax": see the class docstring.
+    scoring: str = "sigmoid"
     #: ``(offset, count)``: the experts THIS chip holds, ``offset ..
     #: offset + count``. The router scores all ``num_experts``; only the
     #: held experts' part of the sum is computed here. None = all.
@@ -514,11 +529,26 @@ def route_sigmoid_grouped(scores_logits, bias, cfg: RoutedExpertsConfig):
     return weights * cfg.routed_scaling_factor, experts.astype(jnp.int32)
 
 
+def route_softmax(scores_logits, cfg: RoutedExpertsConfig):
+    """``(weights (N, k) f32, experts (N, k) int32)`` from router logits
+    ``(N, E)`` in float32: ``p = softmax(logits)`` over ALL ``E`` experts;
+    the ``top_k`` largest are chosen; the weights are their ``p``,
+    normalised (``norm_topk_prob``) over all ``top_k`` — held here or not
+    — and times ``routed_scaling_factor``."""
+    p = jax.nn.softmax(scores_logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(p, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=1, keepdims=True)
+    return weights * cfg.routed_scaling_factor, experts.astype(jnp.int32)
+
+
 class RoutedExperts(Layer):
     """One chip's share of a routed expert layer: gated, bias-free experts
-    ``E(x) = W_down(silu(W_gate x) * W_up x)``, the router of
-    :func:`route_sigmoid_grouped`, a shared expert every chip computes
-    alike, and ``experts_held``::
+    ``E(x) = W_down(silu(W_gate x) * W_up x)``, the router the
+    configuration's ``scoring`` names (:func:`route_sigmoid_grouped` or
+    :func:`route_softmax`), a shared expert every chip computes alike
+    (behind ``sigmoid(w_sg . x)`` with ``shared_gate``), and
+    ``experts_held``::
 
         y = sum_{i chosen and held} w_i * E_i(x) + E_shared(x)
 
@@ -534,9 +564,9 @@ class RoutedExperts(Layer):
     treated as absent too, and its output is only the shared expert's.
 
     Parameters: ``router`` ``{w (D, E), bias (E,)}`` (the bias is
-    ``e_score_correction_bias``), ``experts`` ``{w_gate_up (held, D, 2H),
-    w_down (held, H, D)}`` (gate and up side by side: one matmul),
-    ``shared`` ``{w_gate, w_up, w_down}``."""
+    ``e_score_correction_bias``; the softmax router has none), ``experts``
+    ``{w_gate_up (held, D, 2H), w_down (held, H, D)}`` (gate and up side
+    by side: one matmul), ``shared`` ``{w_gate, w_up, w_down[, w_sg]}``."""
 
     def __init__(self, dim: int, config: RoutedExpertsConfig):
         c = config
@@ -551,6 +581,12 @@ class RoutedExperts(Layer):
                 f"RoutedExperts: {c.num_experts} experts in {c.n_group} "
                 f"groups, {c.topk_group} kept"
             )
+        if c.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"RoutedExperts: unknown scoring {c.scoring!r}")
+        if c.scoring == "softmax" and c.n_group != 1:
+            raise ValueError("RoutedExperts: the softmax router has no groups")
+        if c.shared_gate and not c.shared_hidden:
+            raise ValueError("RoutedExperts: shared_gate without a shared expert")
         self.dim = dim
         self.config = c
 
@@ -560,10 +596,7 @@ class RoutedExperts(Layer):
         ks = jax.random.split(key, 6)
         normal = jax.random.normal
         params = {
-            "router": {
-                "w": normal(ks[0], (d, c.num_experts)) * d ** -0.5,
-                "bias": jnp.zeros((c.num_experts,)),
-            },
+            "router": {"w": normal(ks[0], (d, c.num_experts)) * d ** -0.5},
             "experts": {
                 "w_gate_up": normal(ks[1], (held, d, 2 * c.hidden)) * d ** -0.5,
                 "w_down": normal(ks[2], (held, c.hidden, d)) * c.hidden ** -0.5,
@@ -576,6 +609,10 @@ class RoutedExperts(Layer):
                 "w_up": normal(ks[4], (d, hs)) * d ** -0.5,
                 "w_down": normal(ks[5], (hs, d)) * hs ** -0.5,
             }
+            if c.shared_gate:
+                params["shared"]["w_sg"] = jnp.zeros((d, 1))
+        if c.scoring == "sigmoid":
+            params["router"]["bias"] = jnp.zeros((c.num_experts,))
         return params
 
     def apply(self, variables, x, *, mode="eval", rng=None, token_mask=None):
@@ -596,9 +633,12 @@ class RoutedExperts(Layer):
                 x2.astype(jnp.float32), p["router"]["w"].astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            weights, experts = route_sigmoid_grouped(
-                logits, p["router"]["bias"], c
-            )
+            if c.scoring == "softmax":
+                weights, experts = route_softmax(logits, c)
+            else:
+                weights, experts = route_sigmoid_grouped(
+                    logits, p["router"]["bias"], c
+                )
             # Held pairs to the front, by expert; absent pairs behind them.
             local = experts.reshape(n * k) - offset
             here = (local >= 0) & (local < held)
@@ -631,7 +671,11 @@ class RoutedExperts(Layer):
                 hid = jax.nn.silu(x2 @ sh["w_gate"].astype(dt)) * (
                     x2 @ sh["w_up"].astype(dt)
                 )
-                y = y + (hid @ sh["w_down"].astype(dt)).astype(jnp.float32)
+                out = (hid @ sh["w_down"].astype(dt)).astype(jnp.float32)
+                if c.shared_gate:
+                    out = out * jax.nn.sigmoid(
+                        (x2 @ sh["w_sg"].astype(dt)).astype(jnp.float32))
+                y = y + out
         return y.astype(x.dtype).reshape(shape), counts
 
     def __repr__(self):

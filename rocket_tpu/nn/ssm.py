@@ -94,6 +94,9 @@ class SSMConfig:
             (((self.d_conv - 1) * self.d_inner,), str(jnp.dtype(dtype))),
         )
 
+    def make_mixer(self, features: int, *, norm_eps: float = 1e-6):
+        return MambaMixer(features, self, norm_eps=norm_eps)
+
 
 def _advance(h, dt, u, b, c, a):
     """One token of the recurrence, for any leading shape: ``h`` (...,
@@ -315,6 +318,30 @@ def ssm_step(h_all, delta, u, b, c, a, layer, valid, fresh, *,
 
 # -- the layer ----------------------------------------------------------------
 
+def causal_conv(conv_all, u, w, layer, slots, valid, fresh):
+    """The depthwise causal convolution of a chunk ``u`` (S, C, W) behind
+    each slot's carried tail, shared by the state mixers (``nn/gdn.py``
+    too): ``conv_all`` ``(state layers, max_slots, taps * W)`` holds every
+    slot's last ``taps = len(w) - 1`` inputs side by side, read and written
+    at ``(layer, slots[s])`` (zeros where ``fresh``). Returns ``(the sums
+    (S, C, W) float32 — before any bias or activation — and conv_all')``:
+    a slot's new tail is its last ``taps`` inputs before row ``valid[s]``
+    (with nothing real in the chunk, the tail as it was)."""
+    s, t, width = u.shape
+    taps = w.shape[0] - 1
+    tail = conv_all[layer, slots]                               # (S, taps*W)
+    tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
+    window = jnp.concatenate(
+        [tail[:, None, k * width:(k + 1) * width] for k in range(taps)]
+        + [u.astype(tail.dtype)], axis=1)                       # (S, taps+C, W)
+    w = w.astype(jnp.float32)
+    out = sum(w[k] * window[:, k:k + t] for k in range(taps + 1))
+    tail = jax.vmap(
+        lambda rows, v: jax.lax.dynamic_slice_in_dim(rows, v, taps)
+    )(window, valid).reshape(s, taps * width)
+    return out, conv_all.at[layer, slots].set(tail)
+
+
 class MambaMixer(Layer):
     """The mixer of the module docstring. Parameters: ``in_proj`` ``{w (D,
     2 Di)}``, ``conv`` ``{w (d_conv, Di), b (Di,)}``, ``x_proj`` ``{w (Di,
@@ -405,7 +432,7 @@ class MambaMixer(Layer):
         p, c = params, self.config
         h_all, conv_all = state
         s, t, _ = x.shape
-        di, n, taps = c.d_inner, c.d_state, c.d_conv - 1
+        di, n = c.d_inner, c.d_state
         wave = slots is None and t == 1
         slots = jnp.arange(s, dtype=jnp.int32) if slots is None else slots
         fresh = (positions == 0) & (valid > 0)
@@ -414,22 +441,11 @@ class MambaMixer(Layer):
             uz = self._sub(self.in_proj, p["in_proj"], x)
             u, z = uz[..., :di], uz[..., di:]
         with jax.named_scope("ssm/conv"):
-            tail = conv_all[layer, slots]                       # (S, taps*Di)
-            tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
-            window = jnp.concatenate(
-                [tail[:, None, k * di:(k + 1) * di] for k in range(taps)]
-                + [u.astype(tail.dtype)], axis=1)               # (S, taps+C, Di)
-            w = p["conv"]["w"].astype(jnp.float32)
-            u = sum(w[k] * window[:, k:k + t] for k in range(taps + 1))
+            u, conv_all = causal_conv(
+                conv_all, u, p["conv"]["w"], layer, slots, valid, fresh)
             if c.conv_bias:
                 u = u + p["conv"]["b"].astype(jnp.float32)
             u = jax.nn.silu(u).astype(x.dtype)
-            # The last ``taps`` inputs before row ``valid``: with nothing
-            # real in the chunk, the tail as it was.
-            tail = jax.vmap(
-                lambda rows, v: jax.lax.dynamic_slice_in_dim(rows, v, taps)
-            )(window, valid).reshape(s, taps * di)
-            conv_all = conv_all.at[layer, slots].set(tail)
         with jax.named_scope("ssm/scan"):
             dbc = self._sub(self.x_proj, p["x_proj"], u)
             parts = {"dt_norm": dbc[..., :c.dt_rank],

@@ -476,6 +476,68 @@ def _attend_xla(q, k_pages, v_pages, block_table, positions, layer):
     ).reshape(s, c, hq * d)
 
 
+#: Most float32 scores (slots x heads x chunk rows x table positions) the
+#: one-shot chunk attention may materialise: 256 MiB. A chunk against a
+#: longer table walks its LIVE context in tiles instead
+#: (:func:`_attend_chunk_live`).
+_CHUNK_SCORES_MAX = 1 << 26
+#: Context rows a tile of that walk holds.
+_CHUNK_TILE_ROWS = 2048
+
+
+def _attend_chunk_live(q, k_pages, v_pages, block_table, positions, valid,
+                       layer):
+    """:func:`_attend_xla` for a prefill chunk against a LONG table: the
+    context is gathered a tile of pages at a time and folded into a running
+    softmax, over the tiles that hold a live row only (a loop whose trip
+    count is a traced value: ``max(positions + valid)`` rows), so neither
+    the whole table's context nor its ``(C, table)`` scores materialise.
+    Same numbers as the one-shot form up to the order of the sums."""
+    s, c, hq, d = q.shape
+    h_kv = k_pages.shape[3] // d
+    g = hq // h_kv
+    bl = k_pages.shape[2]
+    mb = block_table.shape[1]
+    per = max(n for n in range(1, mb + 1)
+              if mb % n == 0 and n * bl <= max(_CHUNK_TILE_ROWS, bl))
+    tile = per * bl
+    scale = 1.0 / math.sqrt(d)
+    q5 = q.reshape(s, c, h_kv, g, d)
+    q_pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    live = jnp.max(positions + jnp.maximum(valid, 1))
+    f32 = jnp.float32
+
+    def body(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(block_table, j * per, per, axis=1)
+        k_ctx = k_pages[layer, ids].reshape(s, tile, h_kv, d)
+        v_ctx = v_pages[layer, ids].reshape(s, tile, h_kv, d)
+        logits = jnp.einsum(
+            "sckgd,stkd->skgct", q5, k_ctx, preferred_element_type=f32
+        ) * scale
+        key_pos = j * tile + jnp.arange(tile, dtype=jnp.int32)
+        mask = key_pos[None, None, :] <= q_pos[:, :, None]      # (S, C, T)
+        logits = jnp.where(mask[:, None, None, :, :], logits, -jnp.inf)
+        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
+        w = jnp.exp(logits - m2[..., None])
+        fade = jnp.exp(m - m2)
+        l = l * fade + jnp.sum(w, axis=-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "skgct,stkd->skgcd", w.astype(v_ctx.dtype), v_ctx,
+            preferred_element_type=f32)
+        return m2, l, acc
+
+    shape = (s, h_kv, g, c)
+    # Position 0 is visible to every row, so after tile 0 the running
+    # maximum is finite everywhere.
+    m, l, acc = jax.lax.fori_loop(
+        0, -(-live // tile), body,
+        (jnp.full(shape, -jnp.inf, f32), jnp.zeros(shape, f32),
+         jnp.zeros(shape + (d,), f32)))
+    out = (acc / l[..., None]).astype(q.dtype)                  # (S, Hkv, G, C, D)
+    return jnp.moveaxis(out, 3, 1).reshape(s, c, hq * d)
+
+
 def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
                     positions, valid, *, layer=0,
                     impl: Optional[str] = None,
@@ -585,5 +647,9 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
             block_kv=int(block_kv), interpret=on_cpu or bool(interpret),
         ).reshape(s, 1, hq * d)
         return out, k_pages, v_pages
-    out = _attend_xla(q, k_pages, v_pages, block_table, positions, layer)
+    if c > 1 and s * hq * c * mb * bl > _CHUNK_SCORES_MAX:
+        out = _attend_chunk_live(
+            q, k_pages, v_pages, block_table, positions, valid, layer)
+    else:
+        out = _attend_xla(q, k_pages, v_pages, block_table, positions, layer)
     return out, k_pages, v_pages

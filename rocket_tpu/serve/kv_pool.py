@@ -20,12 +20,15 @@ tables, so admitting a request is a few host list operations and never
 touches compiled code.
 
 **State by slot, beside the pages by block.** A layer that caches no
-rows but carries a fixed-size state (a state-space mixer,
-``nn/ssm.py``) declares it in ``TransformerConfig.slot_state_shapes``;
-the pool then holds, behind its page arrays and in the SAME donated tuple,
-one array per declared state, ``(state layers, max_slots, ...)``, indexed
-by SLOT: no allocator hands it out, a request has it for as long as it has
-its slot. Nothing on the host resets it: both programs start a slot's
+rows but carries a fixed-size state declares it in
+``TransformerConfig.slot_state_shapes`` — a VECTOR a channel (a
+state-space mixer, ``nn/ssm.py``: ``(d_state, d_inner)``) or a MATRIX a
+head (a Gated DeltaNet, ``nn/gdn.py``: ``(value heads, dk, dv)``, so the
+array is of rank 5), each with its convolution's tail; the pool then
+holds, behind its page arrays and in the SAME donated tuple, one array per
+declared state, ``(state layers, max_slots, ...)``, indexed by SLOT: no
+allocator hands it out, a request has it for as long as it has its slot.
+The pool reads the shapes and nothing else of the mixer. Nothing on the host resets it: both programs start a slot's
 state from zeros wherever its chunk starts at position 0, which is where a
 new request, a reused slot and an evicted request's re-prefill all start.
 

@@ -579,3 +579,109 @@ def test_hybrid_serve_programs_update_pages_and_state_in_place(sds, monkeypatch)
             name, memory.temp_size_in_bytes)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
         assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
+
+
+def test_linear_moe_serve_programs_update_pages_and_state_in_place(sds, monkeypatch):
+    """The engine's two programs for Gated DeltaNet layers beside gated
+    attention with a routed feed-forward at the longchat cell's widths (d
+    2048; 16 key and 32 value heads of 128; 16 query heads over 2 K/V heads
+    of 256, rotary over 64 lanes; 512 experts scored, 128 of width 512 held,
+    10 chosen; 64 slots x 16384 positions in blocks of 64, chunks of 1024;
+    one DeltaNet and one attention layer and a small vocabulary, so that it
+    compiles in seconds): the decode program holds ``paged_decode`` at 16
+    heads over 2 of 256, ``gdn_step`` and the grouped matmuls, the prefill
+    program ``gdn_chunk``; the four donated arrays — K and V pages by
+    block, the rank-5 matrix state ``S`` and the convolution's tail by
+    slot — are aliased input to output, and nothing but a program's own
+    update of them (the kernel that owns ``S``, a row scatter, a slot's
+    dynamic-update-slice) produces an array of their types: no copy."""
+    import re
+
+    import rocket_tpu.nn.gdn as gdn
+    import rocket_tpu.nn.moe as moe
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.nn.gdn import GatedDeltaNetConfig
+    from rocket_tpu.nn.moe import RoutedExpertsConfig
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    monkeypatch.setattr(gdn, "_on_cpu", lambda: False)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=16384, dim=2048, num_layers=2,
+        num_heads=16, num_kv_heads=2, head_dim=256, dropout=0.0,
+        tied_embeddings=False, activation_dtype="bfloat16",
+        pos_embedding="rope", rope_base=1e7, rope_fraction=0.25,
+        norm="rmsnorm", norm_eps=1e-6, norm_zero_centered=True,
+        attn_bias=False, attn_gate=True, qk_norm=True,
+        gdn=GatedDeltaNetConfig(16, 32, 128, 128),
+        attn_layer_period=2, attn_layer_offset=1,
+        routed_experts=RoutedExpertsConfig(
+            num_experts=512, top_k=10, hidden=512, shared_hidden=512,
+            shared_gate=True, scoring="softmax", experts_held=(0, 128)),
+    ))
+    sc = ServeConfig(max_slots=64, block_len=64, prefill_chunk=1024,
+                     max_model_len=16384)
+    spec, mb, _, waves = sc.resolve(model.config)
+    assert spec.pages_shapes == ((1, 16385, 64, 512),) * 2
+    assert spec.state_shapes == (((1, 64, 32, 128, 128), "float32"),
+                                 ((1, 64, 24576), "bfloat16"))
+    donated = ["bf16[1,16385,64,512]", "f32[1,64,32,128,128]", "bf16[1,64,24576]"]
+    decode_args, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    programs = {
+        "decode": (build_decode_wave(model, waves=waves), decode_args,
+                   DECODE_DONATE, ("paged_decode", "gdn_step", "moe_gmm_gate_up",
+                                   "moe_gmm_down")),
+        "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE,
+                    ("gdn_chunk", "moe_gmm_gate_up")),
+    }
+    for name, (fn, args, donate, wanted) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        text = compiled.as_text()
+        kernels = _kernel_instructions(text)
+        for kernel in wanted:
+            assert any(kernel in k for k in kernels), (name, kernel, kernels)
+        if name == "decode":
+            _assert_carry_stays_on_device(text, sc.max_slots)
+        materialised = _materialised(text)
+        moves = {inst for op, inst, _, line in materialised
+                 if op == "copy-start" and "S(1)" in line}
+        made = [
+            (op, inst) for op, inst, _, line in materialised
+            if any(t in line.split(" = ")[1].split("(")[0] for t in donated)
+            and op not in ("parameter", "tuple", "get-tuple-element", "bitcast",
+                           "while", "custom-call", "dynamic-update-slice",
+                           "scatter")
+            and not (op == "copy-start" and inst in moves)
+            and not (op == "copy-done" and any(
+                f"copy-done(%{m})" in line or f"copy-done({m})" in line
+                for m in moves))
+            and not (op == "fusion" and re.search(
+                r"/scatter\"|dynamic_update_slice|dynamic-update-slice", line))
+        ]
+        assert not made, (name, made)
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= spec.pool_bytes, (
+            name, memory.alias_size_in_bytes, spec.pool_bytes)
+        # Temporaries: activations of a chunk (a tile of its attention
+        # scores alone is 134 MB, as much as this test's one layer of
+        # state: ``made`` above is what says no state array is copied),
+        # never a copy of a page array (1 GB).
+        assert memory.temp_size_in_bytes < 2 * spec.state_bytes, (
+            name, memory.temp_size_in_bytes)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
