@@ -909,9 +909,10 @@ class LatentAttention(Layer):
     ``o_head = (softmax . c_kv) . W_uv`` — the pool is read once for all
     heads, by the paged kernel under the name ``mla_decode``
     (``ops/paged_attention.py``). A prefill chunk runs **non-absorbed**:
-    the slot's latent pages are gathered a few pages at a time, up-projected
-    to ``k_nope`` and ``v`` and folded into a running softmax, over the
-    LIVE context only (a loop whose trip count is a traced value); per
+    the slot's latent rows are up-projected a tile at a time to ``k_nope``
+    and ``v`` and folded into a running softmax, over the LIVE context
+    only (a traced bound) — by the Pallas kernel ``mla_prefill``
+    (``ops/latent_prefill.py``) on a TPU, by an XLA loop elsewhere; per
     attended row that is 3.4 times fewer operations than the absorbed
     form, which pays ``kv_lora_rank`` lanes a head instead of ``nope``.
 
@@ -1086,24 +1087,44 @@ class LatentAttention(Layer):
             with jax.named_scope("mla/attend"):
                 heads = self._attend_chunk(
                     params, q_nope, q_rope, pages, block_table, positions,
-                    layer,
+                    layer, interpret,
                 )
         return self._out(params, heads), pages
 
     def _attend_chunk(self, p, q_nope, q_rope, pages, block_table,
-                      positions, layer):
-        """Non-absorbed attention of a chunk over the slot's LIVE pages:
-        a few pages at a time are gathered, up-projected and folded into a
-        running softmax (float32 statistics). The loop runs as far as the
-        furthest query sees, a traced bound, so a chunk early in a long
-        pool pays for its own context and not for ``max_blocks_per_seq``.
-        Query row ``i`` of slot ``s`` sees key positions ``<= positions[s]
-        + i`` (its own row was scattered first). Returns (S, C, H*v)."""
+                      positions, layer, interpret=None):
+        """Non-absorbed attention of a chunk over the slot's LIVE context:
+        a tile of rows at a time is up-projected and folded into a running
+        softmax (float32 statistics), as far as the furthest query sees, a
+        traced bound, so a chunk early in a long pool pays for its own
+        context and not for ``max_blocks_per_seq``. Query row ``i`` of
+        slot ``s`` sees key positions ``<= positions[s] + i`` (its own row
+        was scattered first). Returns (S, C, H*v).
+
+        Where the kernel can run (a TPU, or ``interpret=True``; shapes of
+        whole lane tiles, ``ops.latent_prefill.mla_prefill_supported``)
+        the slot's table is gathered once and ONE Pallas call,
+        ``mla_prefill``, keeps the fold in fast memory. Elsewhere — the CPU
+        backend, a tiny width — the same fold is an XLA loop over a few
+        gathered pages a step."""
+        from rocket_tpu.ops import latent_prefill
+        from rocket_tpu.ops.paged_attention import _on_cpu, paged_gather
+
         s, c, h, _ = q_nope.shape
         bl, mb = int(pages.shape[2]), int(block_table.shape[1])
+        w_ukv = self._up_weights(p, q_nope.dtype)
+        on_cpu = _on_cpu()
+        if (not on_cpu or interpret) and pages.dtype == q_nope.dtype \
+                and latent_prefill.mla_prefill_supported(
+                    c, h, self.kv_lora_rank, self.nope, self.rope, self.v_dim,
+                    mb * bl, jnp.dtype(pages.dtype).itemsize):
+            return latent_prefill.mla_prefill(
+                q_nope, q_rope, paged_gather(pages, block_table, layer=layer),
+                w_ukv, positions, scale=self.scale,
+                interpret=on_cpu or bool(interpret),
+            )
         ppc = math.gcd(mb, max(1, 512 // bl))      # pages a step
         tk = ppc * bl
-        w_ukv = self._up_weights(p, q_nope.dtype)
         q_pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         steps = jnp.minimum((jnp.max(positions) + c + tk - 1) // tk, mb // ppc)
 

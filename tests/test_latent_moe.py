@@ -319,6 +319,176 @@ def test_latent_decode_kernel_interpreted_matches_xla(g, dk, dv, wave):
         assert np.isfinite(got).all()
 
 
+# -- (g) the prefill chunk kernel, interpreted ---------------------------------
+
+def _chunk_layer(heads=8, wide=True):
+    """A latent-attention layer alone: the docqa cell's head shape (128 + 64
+    / 128 over a 512 + 64 latent in 640 lanes) at a cut head count, or a
+    tiny one the kernel cannot take."""
+    from rocket_tpu.nn.attention import LatentAttention, LatentAttentionConfig
+
+    sizes = (512, 128, 64, 128) if wide else (32, 16, 8, 16)
+    cfg = LatentAttentionConfig(
+        q_lora_rank=48, kv_lora_rank=sizes[0], qk_nope_head_dim=sizes[1],
+        qk_rope_head_dim=sizes[2], v_head_dim=sizes[3])
+    attn = LatentAttention(96, heads, cfg)
+    return attn, attn.init_params(jax.random.key(3))
+
+
+def _chunk_operands(attn, p, positions, dtype, *, c=32, bl=16, mb=32, poison_from=None):
+    """``x`` (S, C, D) and a pool whose first ``positions[s]`` rows a slot
+    are a real prefix (written through the layer itself), the rest noise —
+    or NaN from row ``poison_from`` on."""
+    from rocket_tpu.ops.paged_attention import write_pages
+
+    s = len(positions)
+    nb = 1 + s * mb
+    lanes = attn.config.pool_lanes
+    p = jax.tree.map(lambda a: a.astype(dtype), p)
+    ks = jax.random.split(jax.random.key(5), 3)
+    pages = (jax.random.normal(ks[0], (2, nb, bl, lanes)) * 0.3).astype(dtype)
+    table = jnp.asarray(
+        np.random.default_rng(1).permutation(np.arange(1, nb)).reshape(s, mb), jnp.int32)
+    t = mb * bl
+    prefix = jax.random.normal(ks[1], (s, t, attn.features)).astype(dtype)
+    _, _, latent = attn._down(p, prefix, jnp.zeros((s,), jnp.int32))
+    pos = jnp.asarray(positions, jnp.int32)
+    pages = write_pages(pages, table, jnp.zeros((s,), jnp.int32), pos, latent, layer=1)
+    if poison_from is not None:
+        dead = jnp.arange(t)[None, :, None] >= poison_from
+        rows = jnp.where(dead, jnp.nan, pages[1, table].reshape(s, t, lanes))
+        pages = pages.at[1, table].set(rows.reshape(s, mb, bl, lanes))
+    x = jax.random.normal(ks[2], (s, c, attn.features)).astype(dtype)
+    return p, x, pages, table, pos, prefix
+
+
+#: ``positions`` of two slots against a table of 512 rows, a chunk of 32
+#: rows, key tiles of 128 (and the tile nobody pinned, 512).
+_CHUNKS = {
+    "first_chunk": [0, 0],
+    "deep_ends_inside_a_tile": [200, 71],        # live 232 and 103 rows
+    "exactly_one_tile": [96, 96],                # 96 + 32 = 128
+    "one_tile_plus_a_row": [97, 0],
+    "whole_table": [480, 480],
+    "ragged": [480, 0],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_CHUNKS))
+def test_latent_prefill_kernel_interpreted_matches_the_loop(case, dtype):
+    """``mla_prefill`` interpreted against the XLA loop it stands in for
+    (the portable path, so the oracle) through ``apply_paged``, pool
+    updates included; float32 to rounding, bfloat16 no further from the
+    float32 loop than the bfloat16 loop is."""
+    from rocket_tpu.ops.latent_prefill import mla_prefill
+
+    dtype = jnp.dtype(dtype)
+    attn, p32 = _chunk_layer()
+    p, x, pages, table, pos, _ = _chunk_operands(attn, p32, _CHUNKS[case], dtype)
+    valid = jnp.full(pos.shape, x.shape[1], jnp.int32)
+    want, want_pages = attn.apply_paged(p, x, pages, table, pos, valid, layer=1)
+    got, got_pages = attn.apply_paged(p, x, pages, table, pos, valid, layer=1, interpret=True)
+    np.testing.assert_array_equal(got_pages, want_pages)
+    # The same call at tiles of 128 rows and two heads a step: several key
+    # tiles, several head blocks.
+    q_nope, q_rope, _ = attn._down(p, x, pos)
+    rows = got_pages[1, table].reshape(len(pos), -1, pages.shape[-1])
+    tiled = attn._out(p, mla_prefill(
+        q_nope, q_rope, rows, attn._up_weights(p, dtype), pos, scale=attn.scale,
+        block_kv=128, heads=2, interpret=True))
+    scale = float(jnp.std(want.astype(jnp.float32)))
+    if dtype == jnp.float32:
+        for out in (got, tiled):
+            np.testing.assert_allclose(out, want, atol=2e-5 * scale)
+        return
+    p32, x32, pages32 = (jax.tree.map(lambda a: a.astype(jnp.float32), t) for t in (p, x, pages))
+    exact, _ = attn.apply_paged(p32, x32, pages32, table, pos, valid, layer=1)
+    loop_err = float(jnp.abs(want.astype(jnp.float32) - exact).max())
+    for out in (got, tiled):
+        err = float(jnp.abs(out.astype(jnp.float32) - exact).max())
+        assert err <= 1.5 * loop_err + 1e-3 * scale, (err, loop_err, scale)
+
+
+@_highest
+@pytest.mark.parametrize("positions", [[0], [96], [180]])
+def test_latent_prefill_kernel_matches_the_whole_sequence_apply(positions):
+    """A chunk through the kernel (interpreted) after a prefix written to
+    the pool = the same rows of ``LatentAttention.apply`` over the whole
+    sequence, float32."""
+    attn, p32 = _chunk_layer()
+    p, x, pages, table, pos, prefix = _chunk_operands(attn, p32, positions, jnp.float32)
+    n, c = positions[0], x.shape[1]
+    valid = jnp.full(pos.shape, c, jnp.int32)
+    got, _ = attn.apply_paged(p, x, pages, table, pos, valid, layer=1, interpret=True)
+    whole, _ = attn.apply(
+        {"params": p, "state": {}}, jnp.concatenate([prefix[:, :n], x], axis=1), mode="eval")
+    np.testing.assert_allclose(got, whole[:, n:], atol=2e-5 * float(jnp.std(whole)))
+
+
+@_highest
+def test_latent_prefill_kernel_ignores_padded_query_rows():
+    """A short last chunk: the rows past ``valid`` are padding, their
+    latents land in the trash block, and the real rows' outputs are what
+    they are with any other padding."""
+    attn, p32 = _chunk_layer()
+    p, x, pages, table, pos, _ = _chunk_operands(attn, p32, [200, 40], jnp.float32)
+    valid = jnp.asarray([7, 19], jnp.int32)
+    other = x.at[0, 7:].set(9.0).at[1, 19:].set(-9.0)
+    want, want_pages = attn.apply_paged(p, x, pages, table, pos, valid, layer=1)
+    for chunk in (x, other):
+        got, got_pages = attn.apply_paged(p, chunk, pages, table, pos, valid, layer=1,
+                                          interpret=True)
+        np.testing.assert_array_equal(got_pages[:, 1:], want_pages[:, 1:])
+        for s, n in enumerate([7, 19]):
+            np.testing.assert_allclose(got[s, :n], want[s, :n], atol=2e-5 * float(jnp.std(want)))
+
+
+def test_latent_prefill_kernel_never_reads_a_dead_key_tile():
+    """NaN in every row of the table past the live context's last tile:
+    the kernel's output is finite and the loop's (whose steps end at the
+    same tile), at the tile nobody pinned and at tiles of 128."""
+    from rocket_tpu.ops.latent_prefill import mla_prefill
+    from rocket_tpu.ops.paged_attention import write_pages
+
+    attn, p32 = _chunk_layer()
+    # Live: 71 + 32 = 103 rows and 200 + 32 = 232: tiles of 128 end at 256.
+    p, x, pages, table, pos, _ = _chunk_operands(
+        attn, p32, [71, 200], jnp.float32, poison_from=256)
+    clean = _chunk_operands(attn, p32, [71, 200], jnp.float32)[2]
+    q_nope, q_rope, latent = attn._down(p, x, pos)
+    valid = jnp.full(pos.shape, x.shape[1], jnp.int32)
+    gather = lambda pg: write_pages(pg, table, pos, valid, latent, layer=1)[1, table] \
+        .reshape(2, -1, pages.shape[-1])
+    assert bool(jnp.isnan(gather(pages)).any())
+    kw = dict(scale=attn.scale, block_kv=128, heads=4, interpret=True)
+    w = attn._up_weights(p, jnp.float32)
+    got = mla_prefill(q_nope, q_rope, gather(pages), w, pos, **kw)
+    want = mla_prefill(q_nope, q_rope, gather(clean), w, pos, **kw)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(got, want)
+
+
+def test_latent_prefill_falls_back_to_the_loop_at_a_tiny_width():
+    """Heads of 16 + 8 / 16 over a 32-lane latent are no whole lane tiles:
+    ``interpret=True`` or not, the chunk takes the loop (bitwise equal
+    outputs), and the gate says so."""
+    from rocket_tpu.ops.latent_prefill import mla_prefill_supported
+
+    assert mla_prefill_supported(512, 128, 512, 128, 64, 128, 8192, 2)
+    assert mla_prefill_supported(32, 8, 512, 128, 64, 128, 512, 4)
+    assert not mla_prefill_supported(32, 4, 32, 16, 8, 16, 512, 4)
+    assert not mla_prefill_supported(1024, 128, 512, 128, 64, 128, 8192, 2)   # over a query tile
+    assert not mla_prefill_supported(24, 8, 512, 128, 64, 128, 512, 2)       # no whole sublane tile
+    assert not mla_prefill_supported(32, 8, 512, 128, 64, 128, 192 * 3, 4)   # table of odd tiles
+    attn, p32 = _chunk_layer(heads=4, wide=False)
+    p, x, pages, table, pos, _ = _chunk_operands(attn, p32, [40, 0], jnp.float32)
+    valid = jnp.full(pos.shape, x.shape[1], jnp.int32)
+    want, _ = attn.apply_paged(p, x, pages, table, pos, valid, layer=1)
+    got, _ = attn.apply_paged(p, x, pages, table, pos, valid, layer=1, interpret=True)
+    np.testing.assert_array_equal(got, want)
+
+
 # -- the engine end to end -------------------------------------------------------
 
 def test_engine_serves_the_model_and_records_expert_pairs():

@@ -300,6 +300,26 @@ def test_serve_programs_update_the_pool_in_place(
     _assert_pool_in_place(sds, model, sc, "paged_decode")
 
 
+def _docqa_attention_widths():
+    """``TransformerConfig`` fields of the docqa cell's attention (128 heads
+    of 128 + 64 / 128 over a latent of 512 + 64, YaRN, a table of 8192
+    positions) around a small model width, FFN and vocabulary."""
+    from rocket_tpu.nn.attention import LatentAttentionConfig, YarnScaling
+
+    return dict(
+        vocab_size=1024, max_seq_len=8192, dim=1024, num_heads=128,
+        dropout=0.0, activation_dtype="bfloat16", pos_embedding="rope",
+        norm="rmsnorm", norm_eps=1e-6, mlp="swiglu", mlp_hidden=1024,
+        mlp_bias=False, tied_embeddings=False,
+        latent_attention=LatentAttentionConfig(
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128,
+            yarn=YarnScaling(factor=40, original_max_position_embeddings=4096,
+                             mscale=1.0, mscale_all_dim=1.0),
+        ),
+    )
+
+
 def test_latent_serve_programs_update_the_pool_in_place(sds, monkeypatch):
     """The same two programs for a latent-attention, routed-expert model at
     the docqa cell's attention widths (128 heads, latent 512 + 64, 32
@@ -311,28 +331,19 @@ def test_latent_serve_programs_update_the_pool_in_place(sds, monkeypatch):
     import rocket_tpu.nn.moe as moe
     import rocket_tpu.ops.paged_attention as paged
     from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
-    from rocket_tpu.nn.attention import LatentAttentionConfig, YarnScaling
     from rocket_tpu.nn.moe import RoutedExpertsConfig
     from rocket_tpu.serve import ServeConfig
 
     monkeypatch.setattr(paged, "_on_cpu", lambda: False)
     monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     model = TransformerLM(TransformerConfig(
-        vocab_size=1024, max_seq_len=8192, dim=1024, num_layers=2,
-        num_heads=128, dropout=0.0, activation_dtype="bfloat16",
-        pos_embedding="rope", norm="rmsnorm", norm_eps=1e-6, mlp="swiglu",
-        mlp_hidden=1024, mlp_bias=False, tied_embeddings=False,
-        latent_attention=LatentAttentionConfig(
-            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
-            qk_rope_head_dim=64, v_head_dim=128,
-            yarn=YarnScaling(factor=40, original_max_position_embeddings=4096,
-                             mscale=1.0, mscale_all_dim=1.0),
-        ),
+        num_layers=2,
         routed_experts=RoutedExpertsConfig(
             num_experts=256, top_k=8, hidden=256, n_group=8, topk_group=4,
             routed_scaling_factor=2.5, shared_hidden=256, experts_held=(0, 16),
         ),
         first_dense_layers=1,
+        **_docqa_attention_widths(),
     ))
     sc = ServeConfig(max_slots=32, block_len=64, prefill_chunk=512)
     spec = sc.resolve(model.config)[0]
@@ -342,6 +353,47 @@ def test_latent_serve_programs_update_the_pool_in_place(sds, monkeypatch):
     # are dead code: with one routed layer it holds no grouped matmul.)
     for name in ("moe_gmm_gate_up", "moe_gmm_down"):
         assert any(name in k for k in kernels["decode"]), kernels
+
+
+def test_latent_prefill_program_attends_in_the_mla_prefill_kernel(sds, monkeypatch):
+    """The prefill program of a docqa-shaped dense model (a chunk of 512
+    rows against a table of 8192) for the chip: every layer's chunk
+    attention is an ``mla_prefill`` custom-call, the layers share ONE
+    lowering of it, nothing is left of the XLA loop (no ``while``, no
+    (S, H, C, tk) float32 scores), the latent pool is still only scattered
+    into in place, and the decode program keeps ``mla_decode``."""
+    import re
+
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.ops.latent_prefill import mla_prefill_supported
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import abstract_wave_inputs, build_prefill_step
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    layers = 3
+    model = TransformerLM(TransformerConfig(
+        num_layers=layers, **_docqa_attention_widths()))
+    sc = ServeConfig(max_slots=32, block_len=64, prefill_chunk=512)
+    assert mla_prefill_supported(512, 128, 512, 128, 64, 128, 8192, 2)
+    kernels = _assert_pool_in_place(sds, model, sc, "mla_decode")
+    # (The chunk discards its logits and this model routes nothing, so the
+    # LAST layer's attention output is dead code: its kernel call is gone.)
+    assert sum("mla_prefill" in k for k in kernels["prefill"]) == layers - 1, kernels
+    assert not any("mla_prefill" in k for k in kernels["decode"]), kernels
+
+    spec, mb, _, _ = sc.resolve(model.config)
+    _, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(model, spec, max_slots=sc.max_slots,
+                             max_blocks_per_seq=mb, prefill_chunk=sc.prefill_chunk),
+    )
+    stablehlo = jax.jit(build_prefill_step(model)).lower(*prefill_args).as_text()
+    # ONE body for the layers: one function holds the kernel, they call it.
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", stablehlo)) == 1
+    assert len(re.findall(r"call @mla_prefill", stablehlo)) == layers - 1
+    assert "stablehlo.while" not in stablehlo
+    assert "x128x512x512xf32>" not in stablehlo
 
 
 def _assert_carry_stays_on_device(text, slots):
