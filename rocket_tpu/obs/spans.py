@@ -8,7 +8,10 @@ tick and its phases, the train wave and its phases). A span that is ON
   identifiers (``tick=``, ``rid=``, ``seq=``, ``step=``) as stats;
 * is recorded as a :class:`SpanEvent` in the current sink, a
   :class:`SpanRecorder`, with the innermost open span of its thread as
-  ``parent``.
+  ``parent`` and, among its ``ids``, ``cpu_s``: the CPU seconds its thread
+  spent between the span's two instants (``time.thread_time``). CPU near
+  the wall time: the host computed; far under it: the thread waited or was
+  descheduled.
 
 A span is on while a profiler session is open
 (``TraceAnnotation.is_enabled()``) or an enabled
@@ -24,7 +27,11 @@ One sink at a time: the recorder of the installed ``Telemetry`` (whose
 default, which :func:`recorded` snapshots for readers and tests. The
 request tracer's legs (``req/*``) and the compile listener's events
 (``compile/*``) are added to the same sink after the fact
-(:func:`add_span`), whether or not spans are on.
+(:func:`add_span`), whether or not spans are on. The collection listener
+(:func:`install_gc_listener`) records each garbage collection that starts
+while spans are on as a span of its own, ``<first component of the
+interrupted span>/gc`` (``serve/gc`` inside ``serve/tick``, ``host/gc``
+outside every span): the interpreter is held for as long as it lasts.
 
 Two clocks: the recorder keeps ``time.perf_counter()`` instants, the
 profiler's file counts from ``start_trace``. Durations and order carry
@@ -34,6 +41,7 @@ over from one to the other; instants do not.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
@@ -50,6 +58,7 @@ __all__ = [
     "add_span",
     "install",
     "install_compile_listener",
+    "install_gc_listener",
     "load_chrome_trace",
     "recorded",
     "span",
@@ -90,7 +99,9 @@ class SpanRecorder:
             maxlen=self.max_events
         )
         self.dropped = 0
-        self._lock = threading.Lock()
+        # Re-entrant: a garbage collection can start while this thread holds
+        # the lock, and the collection listener records its span here.
+        self._lock = threading.RLock()
         self._ids = itertools.count()
         # tid -> stack of (name, id) for live (unfinished) spans.
         self._open: dict[int, list[tuple]] = {}
@@ -276,7 +287,7 @@ class Span:
     phases) is what ``Telemetry.span`` adds."""
 
     __slots__ = ("name", "ids", "cat", "start", "end", "_sink", "_goodput",
-                 "_annotation", "_id", "_parent")
+                 "_annotation", "_id", "_parent", "_cpu")
     on = True
 
     def __init__(self, name: str, ids: dict, sink: SpanRecorder,
@@ -292,6 +303,7 @@ class Span:
         self._id, self._parent = self._sink.push_open(self.name)
         self._annotation = TraceAnnotation(self.name, **self.ids)
         self._annotation.__enter__()
+        self._cpu = time.thread_time()
         self.start = time.perf_counter()
         if self._goodput is not None:
             self._goodput.push(self.cat, self.start)
@@ -299,6 +311,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.end = time.perf_counter()
+        self.ids["cpu_s"] = time.thread_time() - self._cpu
         if self._goodput is not None:
             self._goodput.pop(self.end)
         self._annotation.__exit__(exc_type, exc, tb)
@@ -367,6 +380,48 @@ def install_compile_listener() -> None:
 
     jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
     _compile_listener = _on_compile_event
+
+
+# -- garbage collections -----------------------------------------------------
+
+#: The collection under way while spans were on when it started:
+#: ``(sink, name, parent, annotation, cpu at start, start)``, else None.
+#: Collections neither nest nor overlap (the interpreter runs one at a time).
+_gc_open: Optional[tuple] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        if _installed is None and not TraceAnnotation.is_enabled():
+            return
+        sink = _sink()
+        stack = sink._open.get(threading.get_ident())
+        outer, parent = stack[-1] if stack else ("host", None)
+        name = outer.split("/", 1)[0] + "/gc"
+        annotation = TraceAnnotation(name, gen=info["generation"])
+        annotation.__enter__()
+        _gc_open = (sink, name, parent, annotation, time.thread_time(),
+                    time.perf_counter())
+    elif _gc_open is not None:
+        end = time.perf_counter()
+        sink, name, parent, annotation, cpu, start = _gc_open
+        cpu = time.thread_time() - cpu
+        _gc_open = None
+        annotation.__exit__(None, None, None)
+        sink.add(name, "gc", start, end - start, parent=parent, ids={
+            "gen": info["generation"], "collected": info["collected"],
+            "cpu_s": cpu,
+        })
+
+
+def install_gc_listener() -> None:
+    """Register the process-wide collection listener, once (the first
+    ``Runtime`` or ``ServeEngine`` does). With spans off it costs one check
+    a collection; on, every collection becomes a ``*/gc`` span whose parent
+    is the span it interrupted, with ``gen``, ``collected`` and ``cpu_s``."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def load_chrome_trace(path: str) -> list[dict]:

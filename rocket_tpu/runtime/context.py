@@ -461,7 +461,10 @@ class Runtime:
             HealthConfig,
             HealthMonitor,
         )
-        from rocket_tpu.obs.spans import install_compile_listener
+        from rocket_tpu.obs.spans import (
+            install_compile_listener,
+            install_gc_listener,
+        )
 
         # Training-health sentinels + flight recorder. Default: off;
         # ROCKET_TPU_HEALTH opts a run in without touching code — "1"
@@ -556,8 +559,10 @@ class Runtime:
         # hands identity to the watchdog and the exporter stamps shards.
         self.telemetry.identity = host_identity(self.process_index)
         # Compile events become compile/* spans in every run, telemetry
-        # or not (process-wide, registered once).
+        # or not; collections become */gc spans while spans are on
+        # (process-wide, registered once).
         install_compile_listener()
+        install_gc_listener()
         self.telemetry.start()
         self.telemetry.start_export(
             export_cfg,

@@ -161,8 +161,10 @@ class ServeEngine:
         cfg = config or ServeConfig()
         spec, mb, num_blocks, waves = cfg.resolve(model.config)
         self.config = cfg
-        # Compile events become compile/* spans (process-wide, once).
+        # Compile events become compile/* spans, collections */gc spans
+        # while spans are on (process-wide, once).
         spans.install_compile_listener()
+        spans.install_gc_listener()
         self.engine = SlotEngine(
             model, params, spec,
             max_slots=cfg.max_slots,

@@ -1,10 +1,17 @@
 """rocket_tpu.obs.spans: the one span primitive, its recorder and sink, the
-compile listener, the legs of a request's first token, and where the spans
-lie in a serve tick and a train wave.
+thread CPU time on every span, the compile and collection listeners, the
+legs of a request's first token, and where the spans lie in a serve tick and
+a train wave.
+
+A garbage collection can start inside any test while spans are on and adds
+a ``*/gc`` span; the assertions on which spans were recorded leave those out
+(:func:`_no_gc`), and those on ``ids`` leave out the ``cpu_s`` every span
+carries (:func:`_ids`).
 
 The profiler session of ``test_span_is_in_the_profilers_trace_with_its_rid``
 is opened inside that test, and it is the only test here that opens one."""
 
+import gc
 import glob
 import threading
 import time
@@ -36,6 +43,16 @@ def sink():
     spans.uninstall(rec)
 
 
+def _no_gc(events):
+    """The spans that are not garbage collections."""
+    return [ev for ev in events if not ev.name.endswith("/gc")]
+
+
+def _ids(ev):
+    """A span's identifiers without the ``cpu_s`` the primitive adds."""
+    return {k: v for k, v in ev.ids.items() if k != "cpu_s"}
+
+
 def _since(mark):
     """The default recorder's spans that started after ``mark``."""
     return [ev for ev in spans.recorded() if ev.start >= mark]
@@ -50,18 +67,59 @@ def test_span_records_parent_ids_and_thread(sink):
             inner.set(tokens=2)
         with spans.span("sibling"):
             pass
-    by_name = {ev.name: ev for ev in sink.events()}
+    by_name = {ev.name: ev for ev in _no_gc(sink.events())}
     assert set(by_name) == {"outer", "inner", "sibling"}
     assert by_name["outer"].parent is None
     assert by_name["inner"].parent == by_name["outer"].id
     assert by_name["sibling"].parent == by_name["outer"].id
-    assert by_name["outer"].ids == {"tick": 7}
-    assert by_name["inner"].ids == {"rid": 3, "tokens": 2}
+    assert _ids(by_name["outer"]) == {"tick": 7}
+    assert _ids(by_name["inner"]) == {"rid": 3, "tokens": 2}
     assert by_name["inner"].tid == threading.get_ident()
     # A child lies inside its parent; the span object shows its instants.
     assert outer.start <= by_name["inner"].start
     assert by_name["sibling"].end <= outer.end
     assert sink.open_spans() == {}
+
+
+def _spin(cpu_seconds):
+    """Compute until this thread has spent ``cpu_seconds`` of CPU."""
+    end = time.thread_time() + cpu_seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_an_on_span_carries_its_threads_cpu_time(sink):
+    """The body's CPU is counted whatever the machine's load; a share near
+    1 needs the core to itself, so the busy span may try a few times."""
+    shares = []
+    for _ in range(5):
+        with spans.span("serve/probe") as sp:
+            _spin(0.02)
+        ev = _no_gc(sink.events())[-1]
+        wall = ev.end - ev.start
+        assert (ev.name, ev.start, ev.end) == ("serve/probe", sp.start, sp.end)
+        assert 0.02 <= ev.ids["cpu_s"] <= wall + 1e-3
+        shares.append(ev.ids["cpu_s"] / wall)
+        if shares[-1] >= 0.8:
+            break
+    assert max(shares) >= 0.8, shares
+    with spans.span("serve/probe"):
+        time.sleep(0.05)
+    ev = _no_gc(sink.events())[-1]
+    assert 0.0 <= ev.ids["cpu_s"] < 0.2 * (ev.end - ev.start)
+
+
+def test_timed_with_spans_off_never_reads_the_thread_clock(monkeypatch):
+    def boom():
+        raise AssertionError("thread_time read with spans off")
+
+    monkeypatch.setattr(spans.time, "thread_time", boom)
+    with spans.timed("serve/dispatch") as t:
+        pass
+    assert not t.on and t.end >= t.start
+    with spans.span("serve/tick") as sp:
+        pass
+    assert sp is spans.OFF
 
 
 def test_recorder_is_a_ring_that_keeps_the_newest_and_counts_the_dropped():
@@ -100,7 +158,7 @@ def test_span_is_in_the_profilers_trace_with_its_rid(tmp_path):
         jax.profiler.stop_trace()
     # Recorded in the default sink, on the recorder's clock ...
     (event,) = [ev for ev in _since(mark) if ev.name == "serve/probe"]
-    assert event.ids == {"rid": 41, "tokens": 5}
+    assert _ids(event) == {"rid": 41, "tokens": 5}
     # ... and in the profiler's own file, with its identifiers as stats.
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
     data = jax.profiler.ProfileData.from_file(path)
@@ -125,10 +183,10 @@ def test_telemetry_span_and_the_bare_primitive_share_one_recorder(tmp_path):
         spans.add_span("req/queue", mark, mark + 0.25, rid=9)
     finally:
         tel.close(str(tmp_path), write=False)
-    events = {ev.name: ev for ev in tel.spans.events()}
+    events = {ev.name: ev for ev in _no_gc(tel.spans.events())}
     assert set(events) == {"run/phase", "bare/inside", "req/queue"}
     assert events["bare/inside"].parent == events["run/phase"].id
-    assert events["run/phase"].cat == "step" and events["run/phase"].ids == {"step": 1}
+    assert events["run/phase"].cat == "step" and _ids(events["run/phase"]) == {"step": 1}
     assert tel.goodput.totals()["step"] > 0.0
     # Never written twice: the default recorder saw none of them.
     assert _since(mark) == []
@@ -140,7 +198,7 @@ def test_disabled_telemetry_hands_span_to_the_bare_primitive(sink):
     tel = Telemetry(enabled=False)
     with tel.span("data/next", cat="data_wait"):
         pass
-    assert [ev.name for ev in sink.events()] == ["data/next"]
+    assert [ev.name for ev in _no_gc(sink.events())] == ["data/next"]
     assert len(tel.spans) == 0
 
 
@@ -173,6 +231,71 @@ def test_compile_listener_adds_spans_and_counts_for_an_enabled_telemetry(tmp_pat
     counters = tel.registry.snapshot()["counters"]
     assert counters["compile/events"] >= 3
     assert any(ev.name == "compile/backend" for ev in tel.spans.events())
+
+
+# -- garbage collections ----------------------------------------------------
+
+
+def _remove_gc_listener():
+    while spans._on_gc in gc.callbacks:
+        gc.callbacks.remove(spans._on_gc)
+
+
+@pytest.mark.parametrize("path, name", [
+    ("serve/tick", "serve/gc"), ("train/wave", "train/gc"), (None, "host/gc"),
+])
+def test_a_collection_is_a_span_named_for_the_path_it_interrupts(sink, path, name):
+    spans.install_gc_listener()
+    if path is None:
+        gc.collect()
+        outer = None
+    else:
+        with spans.span(path, tick=0) as sp:
+            gc.collect()
+        (outer,) = [ev for ev in sink.events() if ev.name == path]
+    # gc.collect() is a collection of generation 2 (under a forced low
+    # threshold the interpreter may start another one here: it lies alike).
+    full = [ev for ev in sink.events() if ev.ids.get("gen") == 2]
+    assert full
+    for held in full:
+        assert held.name == name and held.cat == "gc"
+        assert held.parent == (None if outer is None else outer.id)
+        assert held.ids["collected"] >= 0
+        assert 0.0 <= held.ids["cpu_s"] <= held.end - held.start + 1e-3
+        if outer is not None:
+            assert sp.start <= held.start <= held.end <= sp.end
+    assert sink.open_spans() == {}
+
+
+def test_a_collection_with_spans_off_records_nothing():
+    spans.install_gc_listener()
+    assert spans.span("serve/tick") is spans.OFF
+    mark = time.perf_counter()
+    gc.collect()
+    assert not [ev for ev in _since(mark) if ev.name.endswith("/gc")]
+    assert spans._gc_open is None
+
+
+def test_a_collection_that_began_with_spans_off_is_not_recorded(sink):
+    spans.install_gc_listener()
+    info = {"generation": 7, "collected": 0, "uncollectable": 0}   # no real one's
+    spans.uninstall(sink)
+    spans._on_gc("start", info)
+    spans.install(sink)
+    spans._on_gc("stop", info)
+    assert not [ev for ev in sink.events() if ev.ids.get("gen") == 7]
+
+
+def test_the_engine_and_the_runtime_install_the_gc_listener_once(tiny_lm, tmp_path):
+    for build in (lambda: _serve_some(tiny_lm),
+                  lambda: Runtime(mesh_shape={"data": 8}, seed=0,
+                                  project_dir=str(tmp_path))):
+        _remove_gc_listener()
+        build()
+        build()
+        assert gc.callbacks.count(spans._on_gc) == 1
+    spans.install_gc_listener()
+    assert gc.callbacks.count(spans._on_gc) == 1
 
 
 # -- the legs of a request's first token -------------------------------------
@@ -248,7 +371,7 @@ def test_the_three_legs_sum_to_prefill_s_to_the_float(sink, scenario):
     assert sum(phases.values()) == pytest.approx(record["total_s"], abs=4e-6)
     # The legs went to the sink as req/* spans of this rid, laid end to
     # end from the submit instant, unrounded.
-    legs = {ev.name: ev for ev in sink.events()}
+    legs = {ev.name: ev for ev in _no_gc(sink.events())}
     assert list(legs) == ["req/queue", "req/prefill_wait", "req/prefill_run",
                           "req/first_token", "req/decode"]
     assert all(ev.ids == {"rid": 1} for ev in legs.values())
@@ -309,7 +432,7 @@ def _serve_some(tiny_lm, telemetry=None):
 
 def test_serve_tick_children_lie_inside_their_tick_and_do_not_overlap(tiny_lm, sink):
     engine, rids = _serve_some(tiny_lm)
-    events = sink.events()
+    events = _no_gc(sink.events())
     ticks = [ev for ev in events if ev.name == "serve/tick"]
     assert len(ticks) >= 6
     assert [ev.ids["tick"] for ev in ticks] == list(range(len(ticks)))
@@ -364,7 +487,7 @@ def test_serve_tick_children_lie_inside_their_tick_and_do_not_overlap(tiny_lm, s
               if ev.name == "serve/harvest_wait"}
     for seq, behind in zip(sorted(starts)[1:], inflight[1:]):
         assert (starts[seq] < waited[seq - 1]) == bool(behind), seq
-    grows = [ev.ids for ev in events if ev.name == "serve/grow"]
+    grows = [_ids(ev) for ev in events if ev.name == "serve/grow"]
     assert grows and all(ids == {"evicted": 0, "drained": 0} for ids in grows)
     admitted = sum(ev.ids["admitted"] for ev in events if ev.name == "serve/admit")
     assert admitted == len(rids)
