@@ -26,7 +26,23 @@ from rocket_tpu.nn.attention import MultiHeadAttention
 from rocket_tpu.nn.layers import Dense, Dropout, Embedding, LayerNorm, RMSNorm
 from rocket_tpu.nn.module import Layer, Model, Variables
 
-__all__ = ["TransformerConfig", "TransformerLM", "Block", "next_token_loss", "generate"]
+__all__ = ["AttentionKind", "TransformerConfig", "TransformerLM", "Block",
+           "next_token_loss", "generate"]
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What one kind of attention layer changes of the configuration's
+    attention (a field left None keeps the configuration's): its query
+    heads, its rotary (base, share of each head's lanes, YaRN) and its
+    ``window`` (0: it sees the whole context and caches pages; > 0: it sees
+    the last ``window`` positions and keeps a ring of them a slot)."""
+
+    num_heads: Optional[int] = None
+    rope_base: Optional[float] = None
+    rope_fraction: Optional[float] = None
+    rope_yarn: Optional[Any] = None
+    window: int = 0
 
 #: Memoized jax.checkpoint policies (see TransformerConfig.remat_policy).
 _REMAT_POLICIES: dict = {}
@@ -188,6 +204,16 @@ class TransformerConfig:
     rope_fraction: float = 1.0
     #: RMSNorm weights stored about zero and applied as ``1 + w``.
     norm_zero_centered: bool = False
+    #: One gate scalar a head, ``sigmoid(x w_g,h)``, on the attention's
+    #: output (``nn.attention.MultiHeadAttention(head_gate=True)``).
+    attn_head_gate: bool = False
+    #: The attention kind of each layer, by name (``("full_attention",
+    #: "sliding_attention", ...)``, as a published ``layer_types`` gives it;
+    #: a list longer than ``num_layers`` is read from its start), and what
+    #: each name changes of the attention the fields above describe: an
+    #: :class:`AttentionKind`. Empty = every layer is the one kind.
+    layer_types: tuple = ()
+    attention_kinds: Optional[dict] = None
     #: Label smoothing for ``next_token_loss``: the target distribution is
     #: (1-eps) one-hot + eps uniform. Lives on the CONFIG (not the
     #: objective) so the fused (loss_chunk) and full-logits paths apply the
@@ -301,6 +327,27 @@ class TransformerConfig:
                 "TransformerConfig: attn_layer_period / attn_layer_offset "
                 "without ssm or gdn"
             )
+        if self.layer_types:
+            kinds = self.attention_kinds or {}
+            unknown = set(self.layer_types[:self.num_layers]) - set(kinds)
+            if len(self.layer_types) < self.num_layers or unknown:
+                raise ValueError(
+                    f"TransformerConfig: layer_types must name an "
+                    f"attention_kinds entry for each of {self.num_layers} "
+                    f"layers (unknown: {sorted(unknown)})")
+            windows = {self.attention_kind(i).window for i in range(self.num_layers)}
+            if len(windows - {0}) > 1:
+                raise ValueError(
+                    f"TransformerConfig: one window a model, not {sorted(windows - {0})}")
+            if self.window_layers and (
+                    self.state_mixer is not None or self.latent_attention is not None
+                    or self.scan_layers or self.pipeline_axis):
+                raise ValueError(
+                    "TransformerConfig: window layers keep a ring a slot beside "
+                    "the paged K/V of multi-head attention: no ssm, gdn, "
+                    "latent_attention, scan_layers or pipeline_axis")
+        elif self.attention_kinds:
+            raise ValueError("TransformerConfig: attention_kinds without layer_types")
         if self.latent_attention is not None and self.pos_embedding != "rope":
             raise ValueError(
                 "TransformerConfig: latent_attention rotates its own "
@@ -371,19 +418,44 @@ class TransformerConfig:
         """How many layers carry a per-slot state."""
         return sum(self.is_state_layer(i) for i in range(self.num_layers))
 
+    def attention_kind(self, layer_idx: int) -> AttentionKind:
+        """Layer ``layer_idx``'s :class:`AttentionKind` (``layer_types``
+        read once, here; a model without it has one plain kind)."""
+        if not self.layer_types:
+            return AttentionKind()
+        return self.attention_kinds[self.layer_types[layer_idx]]
+
+    @property
+    def window_layers(self) -> int:
+        """How many layers attend a window (and keep a ring a slot)."""
+        return sum(self.attention_kind(i).window > 0
+                   for i in range(self.num_layers))
+
+    @property
+    def window(self) -> int:
+        """The rows a window layer's ring holds a slot (0: no window)."""
+        return max((self.attention_kind(i).window
+                    for i in range(self.num_layers)), default=0)
+
     @property
     def cache_layers(self) -> int:
         """How many layers cache pages: the layer rows of the serving
         pool. An attention layer's ``layer=`` coordinate in the pool is its
-        index among these."""
-        return self.num_layers - self.state_layers
+        index among these. A window layer keeps a ring instead."""
+        return self.num_layers - self.state_layers - self.window_layers
 
     @property
     def slot_state_shapes(self) -> tuple:
         """What ONE slot carries beside its pages, for every array of the
         per-slot state: ``((state layers, per-slot shape, dtype), ...)`` —
         empty for a model whose every layer caches pages. The one
-        description ``serve/kv_pool.py`` sizes the state arrays from."""
+        description ``serve/kv_pool.py`` sizes the state arrays from. The
+        window layers' rings are two such arrays, K and V, each ``(window,
+        Hkv * head_dim)`` a slot in the activation dtype."""
+        if self.window_layers:
+            ring = (self.window, self.kv_pool_lanes[0])
+            dtype = self.activation_dtype or "float32"
+            return ((self.window_layers, ring, dtype),) * 2
         if not self.state_layers:
             return ()
         return tuple(
@@ -456,6 +528,9 @@ class Block(Layer):
         self.ln1 = c.make_norm(c.dim)
         self.latent = c.latent_attention is not None
         self.attn = self.mixer = None
+        kind = c.attention_kind(layer_idx)
+        #: The rows this layer's attention sees (0: the whole context).
+        self.window = kind.window
         if c.is_state_layer(layer_idx):
             self.mixer = c.state_mixer.make_mixer(
                 c.dim, norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
@@ -469,15 +544,21 @@ class Block(Layer):
                 norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
             )
         else:
+            pick = lambda own, default: default if own is None else own
             self.attn = MultiHeadAttention(
-                c.dim, c.num_heads, num_kv_heads=c.num_kv_heads,
+                c.dim, pick(kind.num_heads, c.num_heads),
+                num_kv_heads=c.num_kv_heads,
                 causal=c.causal, dropout=c.dropout, use_bias=c.attn_bias,
                 impl=c.attention_impl,
                 seq_axis=c.seq_axis, rope=c.pos_embedding == "rope",
-                rope_base=c.rope_base, head_dim=c.head_dim, gate=c.attn_gate,
-                qk_norm=c.qk_norm, rope_fraction=c.rope_fraction,
+                rope_base=pick(kind.rope_base, c.rope_base),
+                head_dim=c.head_dim, gate=c.attn_gate,
+                qk_norm=c.qk_norm,
+                rope_fraction=pick(kind.rope_fraction, c.rope_fraction),
                 norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
                 norm_zero_centered=c.norm_zero_centered,
+                head_gate=c.attn_head_gate, rope_yarn=kind.rope_yarn,
+                window=kind.window,
             )
         self.ln2 = c.make_norm(c.dim)
         self.routed = None
@@ -749,6 +830,21 @@ class Block(Layer):
         y, counts = self._ffn_half(params, x + h, valid)
         return y, tuple(pages), counts
 
+    def apply_window(self, params, x, rings, positions, valid, slots=None,
+                     layer=0):
+        """:meth:`apply_paged` for a layer with a window: ``rings`` the
+        WHOLE ``(k_ring, v_ring)`` arrays (``MultiHeadAttention.
+        apply_window``), read and written at ``(layer, slots)`` — ``layer``
+        this block's index among the window layers. Returns ``(y, rings',
+        counts)``."""
+        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
+        h, *rings = self.attn.apply_window(
+            params["attn"], h, *rings, positions, valid, slots=slots,
+            layer=layer,
+        )
+        y, counts = self._ffn_half(params, x + h, valid)
+        return y, tuple(rings), counts
+
     def apply_state(self, params, x, state, positions, valid, slots=None,
                     layer=0):
         """:meth:`apply_paged` for a state layer: ``state`` the WHOLE
@@ -1015,6 +1111,13 @@ class TransformerLM(Model):
             for i, block in enumerate(self.blocks):
                 if block.mixer is not None:
                     x, state, counts = block.apply_state(
+                        p["blocks"][str(i)], x, state, positions, valid,
+                        slots, layer=stateful,
+                    )
+                    stateful += 1
+                elif block.window:
+                    # The rings are the per-slot arrays of a window model.
+                    x, state, counts = block.apply_window(
                         p["blocks"][str(i)], x, state, positions, valid,
                         slots, layer=stateful,
                     )

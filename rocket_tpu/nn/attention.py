@@ -16,6 +16,7 @@ for TPU:
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional
@@ -181,6 +182,15 @@ class YarnScaling:
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
 
+    @property
+    def trig_scale(self) -> float:
+        """What cos and sin are multiplied by (a block's
+        ``attention_factor``): ``mscale(factor, mscale) / mscale(factor,
+        mscale_all_dim)`` (:func:`yarn_mscale`; ``0.1 ln(factor) + 1`` at
+        the defaults)."""
+        return yarn_mscale(self.factor, self.mscale) / yarn_mscale(
+            self.factor, self.mscale_all_dim)
+
 
 @dataclass(frozen=True)
 class LatentAttentionConfig:
@@ -303,10 +313,13 @@ def grouped_dot_product_attention(
     k: jax.Array,
     v: jax.Array,
     causal: bool = True,
+    window: int = 0,
 ) -> jax.Array:
     """GQA attention: q (B, H, Tq, D) against k/v (B, Hkv, Tk, D) where
     Hkv divides H — each kv head serves a group of H/Hkv query heads via a
-    grouped einsum (no materialized repeat of K/V). Float32 softmax."""
+    grouped einsum (no materialized repeat of K/V). Float32 softmax.
+    ``window`` > 0 (causal only): query ``i`` sees key ``j`` iff ``i -
+    window < j <= i``."""
     b, h, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[-2]
     g = h // h_kv
@@ -317,6 +330,8 @@ def grouped_dot_product_attention(
     ) * scale
     if causal:
         mask = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q - window)
         logits = jnp.where(mask, logits, -jnp.inf)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqm,bkmd->bkgqd", weights.astype(v.dtype), v)
@@ -350,6 +365,16 @@ class MultiHeadAttention(Layer):
     untouched). A layer with any of them (:attr:`extended`) runs the XLA
     path in :meth:`apply` and the paged path in serving; it has no dense
     cache (:meth:`apply_cached` raises) and no overlapped TP path.
+
+    Three more of the same kind (Laguna's), off by default and inert:
+    ``head_gate`` (ONE gate scalar a head, ``sigmoid(x w_g,h)``, its ``H``
+    columns behind ``v`` in the fused projection, ``[q | k | v | g]``, so
+    that every head's lanes stay on whole 128-lane tiles), ``rope_yarn``
+    (a :class:`YarnScaling`: the rotated lanes turn at
+    :func:`yarn_inv_freq` and cos and sin are multiplied by its
+    ``trig_scale``) and ``window`` (query ``i`` sees key ``j`` iff
+    ``i - window < j <= i``; in serving the layer reads a ring of
+    ``window`` rows a slot, :meth:`apply_window`, not the paged pool).
     """
 
     def __init__(
@@ -370,7 +395,16 @@ class MultiHeadAttention(Layer):
         rope_fraction: float = 1.0,
         norm_eps: float = 1e-6,
         norm_zero_centered: bool = False,
+        head_gate: bool = False,
+        rope_yarn: Optional["YarnScaling"] = None,
+        window: int = 0,
     ):
+        if gate and head_gate:
+            raise ValueError(
+                "MultiHeadAttention: gate (elementwise) and head_gate are "
+                "two kinds of output gate; give one")
+        if window and not causal:
+            raise ValueError("MultiHeadAttention: a window is causal")
         if head_dim is None and features % num_heads != 0:
             raise ValueError(
                 f"MultiHeadAttention: features {features} not divisible by "
@@ -400,18 +434,27 @@ class MultiHeadAttention(Layer):
         #: such a layer runs the XLA path in :meth:`apply`, the paged path
         #: in serving, and has no dense cache and no overlapped TP path.
         self.extended = bool(
-            own_width or gate or qk_norm or self.rope_dim != head_dim)
+            own_width or gate or qk_norm or self.rope_dim != head_dim
+            or head_gate or rope_yarn is not None or window)
         if self.extended and impl == "ring":
             raise ValueError(
                 "MultiHeadAttention: impl='ring' takes none of head_dim, "
-                "gate, qk_norm, rope_fraction")
+                "gate, qk_norm, rope_fraction, head_gate, rope_yarn, window")
         self.rope = rope
         self.rope_base = rope_base
+        #: The rotated lanes' frequencies and the factor on cos and sin
+        #: (None and 1: the plain ``base^(-i/half)``).
+        self.inv_freq, self.trig_scale = None, 1.0
+        if rope_yarn is not None:
+            self.inv_freq = yarn_inv_freq(self.rope_dim, rope_base, rope_yarn)
+            self.trig_scale = rope_yarn.trig_scale
         self.features = features
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.gate = gate
+        self.head_gate = head_gate
+        self.window = int(window)
         self.causal = causal
         self.dropout = dropout
         self.impl = impl
@@ -419,10 +462,11 @@ class MultiHeadAttention(Layer):
         self._ring_mesh = None  # pinned at first ring trace
         self._flash_mesh = None  # pinned at first multi-device flash trace
         # Columns ``[q | gate | k | v]`` (``gate`` only where asked for),
-        # each head by head.
+        # each head by head; a head gate's ``H`` columns behind them.
         self.qkv = Dense(
             features,
-            ((2 if gate else 1) * num_heads + 2 * num_kv_heads) * self.head_dim,
+            ((2 if gate else 1) * num_heads + 2 * num_kv_heads) * self.head_dim
+            + (num_heads if head_gate else 0),
             use_bias=use_bias,
         )
         self.proj = Dense(
@@ -454,15 +498,17 @@ class MultiHeadAttention(Layer):
         """The front of :meth:`apply_paged` and of a layer with the
         gated-attention options (:attr:`extended`): ``x`` (B, T, D)
         -> ``q`` (B, T, H, Dh), ``k``, ``v`` (B, T, Hkv, Dh) and ``gate``
-        (B, T, H * Dh) or None — q and k through their per-head norms and
-        rotated over their first ``rope_dim`` lanes at ``positions[b] ..
-        positions[b] + T``."""
+        (B, T, H * Dh), or (B, T, H) for a head gate, or None — q and k
+        through their per-head norms and rotated over their first
+        ``rope_dim`` lanes at ``positions[b] .. positions[b] + T``."""
         b, t, _ = x.shape
         fused, _ = self.qkv.apply({"params": params["qkv"], "state": {}}, x)
         d = self.head_dim
         hw, kvw = self.num_heads * d, self.num_kv_heads * d
-        q = fused[..., :hw].reshape(b, t, self.num_heads, d)
         gate = None
+        if self.head_gate:
+            gate, fused = fused[..., -self.num_heads:], fused[..., :-self.num_heads]
+        q = fused[..., :hw].reshape(b, t, self.num_heads, d)
         if self.gate:
             gate, fused = fused[..., hw:2 * hw], fused[..., hw:]
         k = fused[..., hw:hw + kvw].reshape(b, t, self.num_kv_heads, d)
@@ -473,19 +519,24 @@ class MultiHeadAttention(Layer):
             q, k = norm("q_norm", q), norm("k_norm", k)
         if self.rope:
             r = self.rope_dim
+            turn = functools.partial(
+                apply_rope_offsets, offsets=positions, base=self.rope_base,
+                inv_freq=self.inv_freq, trig_scale=self.trig_scale)
 
             def rotate(a):
                 if r == d:
-                    return apply_rope_offsets(a, positions, self.rope_base)
-                turned = apply_rope_offsets(a[..., :r], positions, self.rope_base)
-                return jnp.concatenate([turned, a[..., r:]], axis=-1)
+                    return turn(a)
+                return jnp.concatenate([turn(a[..., :r]), a[..., r:]], axis=-1)
 
             q, k = rotate(q), rotate(k)
         return q, k, v, gate
 
     def _gated_out(self, params, out, gate):
-        """``proj(out * sigmoid(gate))`` — ``out`` (B, T, H * Dh)."""
+        """``proj(out * sigmoid(gate))`` — ``out`` (B, T, H * Dh); a head
+        gate's (B, T, H) scalars each scale their head's ``Dh`` lanes."""
         if gate is not None:
+            if self.head_gate:
+                gate = jnp.repeat(gate, self.head_dim, axis=-1)
             out = (out.astype(jnp.float32)
                    * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
         return self.proj.apply({"params": params["proj"], "state": {}}, out)[0]
@@ -699,7 +750,8 @@ class MultiHeadAttention(Layer):
         if self.extended:
             q, k, v, gate = self._project(p, x, jnp.zeros((b,), jnp.int32))
             out = grouped_dot_product_attention(
-                *(jnp.moveaxis(a, 1, 2) for a in (q, k, v)), causal=self.causal)
+                *(jnp.moveaxis(a, 1, 2) for a in (q, k, v)), causal=self.causal,
+                window=self.window)
             out = self._attn_dropout(jnp.moveaxis(out, 1, 2), mode, rng)
             out = out.reshape(b, t, self.num_heads * self.head_dim)
             return self._gated_out(p, out, gate), variables["state"]
@@ -875,6 +927,24 @@ class MultiHeadAttention(Layer):
         )
         return self._gated_out(params, out, gate), k_pages, v_pages
 
+    def apply_window(self, params, x, k_ring, v_ring, positions, valid,
+                     slots=None, layer=0):
+        """:meth:`apply_paged` for a layer with a ``window``: its K/V live
+        in a RING of ``window`` rows a slot, ``k_ring``/``v_ring`` ``(window
+        layers, max_slots, window, Hkv*D)`` — the whole arrays, in and out,
+        written in place at ``(layer, slot, position mod window)``
+        (``ops.paged_attention.window_attention``). ``slots`` (S,) names
+        the slot of each row of ``x`` (None: row ``s`` is slot ``s``, the
+        decode wave). Returns ``(out, k_ring', v_ring')``."""
+        from rocket_tpu.ops.paged_attention import window_attention
+
+        q2, k2, v2, gate = self._project(params, x, positions)
+        out, k_ring, v_ring = window_attention(
+            q2, k2, v2, k_ring, v_ring, positions, valid, slots=slots,
+            layer=layer,
+        )
+        return self._gated_out(params, out, gate), k_ring, v_ring
+
     def __repr__(self):
         kv = (
             f", kv={self.num_kv_heads}"
@@ -948,7 +1018,7 @@ class LatentAttention(Layer):
             self.inv_freq = yarn_inv_freq(self.rope, rope_base, yarn)
             m = yarn_mscale(yarn.factor, yarn.mscale_all_dim)
             self.scale *= m * m
-            self.trig_scale = yarn_mscale(yarn.factor, yarn.mscale) / m
+            self.trig_scale = yarn.trig_scale
 
     def init_params(self, key):
         names = ("q_a", "q_norm", "q_b", "kv_a", "kv_norm", "kv_b", "proj")

@@ -46,6 +46,11 @@ Two device-side implementations share one signature:
   cost 0.12 us a step: 99 % of a call at the chat cell's load; PERF.md,
   PR 30.)
 
+A sliding-window layer keeps no pages: :func:`window_attention` reads and
+writes a RING of ``window`` rows a slot (position ``p`` at row ``p mod
+window``), its decode wave through the same kernel body, named
+``window_decode``, over the ring as one page a slot.
+
 Implementation choice and the ``block_kv`` tile height resolve through
 the ``paged_decode`` tune table (``rocket_tpu.tune``) — ``impl`` is a
 real structural search axis (the tuner can measure the XLA path beating
@@ -87,6 +92,7 @@ __all__ = [
     "paged_attention",
     "paged_gather",
     "paged_decode_supported",
+    "window_attention",
 ]
 
 _NEG_INF = -1e30
@@ -653,3 +659,105 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
     else:
         out = _attend_xla(q, k_pages, v_pages, block_table, positions, layer)
     return out, k_pages, v_pages
+
+
+def _ring_write(ring, slot_ids, rows, keep, new, *, layer):
+    """Rows ``new`` ``(S, C, ...)`` into ``ring`` ``(Lw, max_slots, W,
+    lanes)`` at ``(layer, slot_ids[s], rows[s, i])`` where ``keep[s, i]``;
+    the others are dropped (an index past the ring), so a slot that does
+    not run keeps its ring bitwise. One scatter of whole rows, in place
+    where the ring is donated."""
+    s, c = rows.shape
+    rows = jnp.where(keep, rows, ring.shape[2])
+    return ring.at[layer, slot_ids[:, None], rows].set(
+        new.astype(ring.dtype).reshape(s, c, -1), mode="drop")
+
+
+def window_attention(q, k_new, v_new, k_ring, v_ring, positions, valid, *,
+                     slots=None, layer=0, interpret: Optional[bool] = None):
+    """One chunk of sliding-window GQA attention against a RING: query row
+    ``i`` of slot ``s`` (global position ``positions[s] + i``) sees the
+    keys of positions ``p`` with ``positions[s] + i - W < p <= positions[s]
+    + i``.
+
+    ``q`` ``(S, C, Hq, D)``; ``k_new``/``v_new`` ``(S, C, Hkv, D)`` (RoPE
+    already applied); ``k_ring``/``v_ring`` ``(Lw, max_slots, W, Hkv*D)``:
+    the row of position ``p`` of a slot lies at ring row ``p mod W`` of
+    layer ``layer``, and nothing but position says which rows are live, so
+    a ring needs no reset and no allocator. ``slots`` ``(S,)`` int32 names
+    the slot of each row (None: row ``s`` is slot ``s``, the decode wave);
+    ``valid`` as in :func:`paged_attention`.
+
+    * **Decode** (C = 1) where the fused kernel can run (a TPU, or
+      ``interpret=True``): the new row is written first, then the slot's
+      rows ``0 .. min(position, W - 1)`` are all visible — every one of
+      them inside the window, and keys carry their rotation, so ring order
+      does not matter — through :func:`_paged_decode_pallas` over the ring
+      viewed as ONE page of ``W`` rows a slot, under the Pallas name
+      ``window_decode``. Elsewhere a decode row is a chunk of one.
+    * **Chunk** (C > 1, or a decode row off the kernel): ``[ring as it was
+      before the chunk | the chunk's
+      own rows]`` under the band mask (a ring row's position is the
+      latest ``p < positions[s]`` with ``p = row mod W``, live where ``p >=
+      0``), one K/V head at a time so that the scores stay ``(C, W + C)``
+      a head group; then the chunk's last ``min(valid, W)`` rows are
+      written (an earlier row's ring row is a later row's).
+
+    Returns ``(out (S, C, Hq*D), k_ring', v_ring')``. Padded query rows
+    produce well-defined garbage (a row sees itself) the callers ignore."""
+    s, c, hq, d = q.shape
+    _, _, w, lanes = k_ring.shape
+    h_kv = lanes // d
+    g = hq // h_kv
+    slot_ids = jnp.arange(s, dtype=jnp.int32) if slots is None else slots
+    q_pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    j = jnp.arange(c, dtype=jnp.int32)[None, :]
+    keep = (j < valid[:, None]) & (j >= valid[:, None] - w)
+
+    def write(k_ring, v_ring):
+        rows = jnp.mod(q_pos, w)
+        return (_ring_write(k_ring, slot_ids, rows, keep, k_new, layer=layer),
+                _ring_write(v_ring, slot_ids, rows, keep, v_new, layer=layer))
+
+    itemsize = jnp.dtype(k_ring.dtype).itemsize
+    on_cpu = _on_cpu()
+    if c == 1 and paged_decode_supported(w, d, itemsize, lanes=lanes) and (
+            not on_cpu or interpret):
+        k_ring, v_ring = write(k_ring, v_ring)
+        out = _paged_decode_pallas(
+            q[:, 0], k_ring, v_ring,
+            slot_ids[:, None], jnp.minimum(positions, w - 1), valid, layer=layer,
+            block_kv=min(w, _default_block_kv(w, itemsize, 2 * lanes)),
+            interpret=on_cpu or bool(interpret), name="window_decode",
+        )
+        return out.reshape(s, 1, hq * d), k_ring, v_ring
+
+    scale = 1.0 / math.sqrt(d)
+    r = jnp.arange(w, dtype=jnp.int32)[None, :]
+    before = positions[:, None] - 1
+    ring_pos = before - jnp.mod(before - r, w)                     # (S, W)
+    key_pos = jnp.concatenate([ring_pos, q_pos], axis=1)          # (S, W + C)
+    key_ok = jnp.concatenate([ring_pos >= 0, jnp.ones_like(q_pos, bool)], axis=1)
+    band = key_ok[:, None, :] & (key_pos[:, None, :] <= q_pos[:, :, None]) & (
+        key_pos[:, None, :] > q_pos[:, :, None] - w)               # (S, C, W + C)
+
+    def keys(ring, new):
+        old = ring[layer, slot_ids].reshape(s, w, h_kv, d)
+        both = jnp.concatenate([old, new.astype(ring.dtype)], axis=1)
+        return jnp.moveaxis(both, 2, 0)                           # (Hkv, S, W + C, D)
+
+    def one_head(xs):
+        qh, kh, vh = xs                    # (S, C, G, D), (S, W + C, D) x 2
+        logits = jnp.einsum(
+            "scgd,std->sgct", qh, kh, preferred_element_type=jnp.float32
+        ) * scale
+        logits = jnp.where(band[:, None], logits, -jnp.inf)
+        weights = jax.nn.softmax(logits, axis=-1)
+        return jnp.einsum("sgct,std->scgd", weights.astype(vh.dtype), vh)
+
+    out = jax.lax.map(one_head, (
+        jnp.moveaxis(q.reshape(s, c, h_kv, g, d), 2, 0),
+        keys(k_ring, k_new), keys(v_ring, v_new)))                # (Hkv, S, C, G, D)
+    out = jnp.moveaxis(out, 0, 2).reshape(s, c, hq * d)
+    k_ring, v_ring = write(k_ring, v_ring)
+    return out, k_ring, v_ring

@@ -96,6 +96,7 @@ class ServeConfig:
             num_layers=mc.cache_layers,
             slot_state=tuple(mc.slot_state_shapes),
             max_slots=self.max_slots,
+            window=mc.window,
             num_blocks=num_blocks,
             block_len=self.block_len,
             # What a layer caches per token is the model's to declare: K

@@ -32,6 +32,16 @@ The pool reads the shapes and nothing else of the mixer. Nothing on the host res
 state from zeros wherever its chunk starts at position 0, which is where a
 new request, a reused slot and an evicted request's re-prefill all start.
 
+**A ring by slot for the window layers.** A layer that attends only the
+last ``window`` positions (``TransformerConfig.attention_kinds``) caches no
+pages either: its K and V rows live in two more per-slot arrays,
+``(window layers, max_slots, window, Hkv*D)``, declared through the same
+``slot_state_shapes`` — position ``p`` at ring row ``p mod window``, no
+allocator traffic, not counted by ``cache_layers``. Nothing resets a ring:
+which of its rows are live follows from the position alone
+(``ops.paged_attention.window_attention``), so a reused slot and a
+re-prefill read nothing stale. A model without window layers has no ring.
+
 Block 0 is RESERVED as the trash sink: masked writes (prompt padding,
 inactive slots) land there and unmapped block-table entries point at it,
 which is what lets one fixed-shape compiled step serve every admission
@@ -72,6 +82,11 @@ class KVPoolSpec:
     lanes: tuple = ()
     slot_state: tuple = ()
     max_slots: int = 0
+    #: Rows of a window layer's ring a slot (``TransformerConfig.window``;
+    #: 0: the model has no window layer). The rings are two of the
+    #: per-slot arrays; the scheduler reads this to say how many rows a
+    #: wave's window layers attend.
+    window: int = 0
 
     def __post_init__(self):
         if self.num_blocks < 2:

@@ -142,6 +142,8 @@ class Scheduler:
         s = engine.max_slots
         mb = engine.max_blocks_per_seq
         self.block_len = engine.spec.block_len
+        #: Rows a window layer's ring holds a slot (0: no window layer).
+        self.window = engine.spec.window
         self.max_context = mb * self.block_len
         # Host mirrors of the wave inputs — fixed shape + dtype forever.
         self.block_table = np.zeros((s, mb), np.int32)
@@ -257,10 +259,11 @@ class Scheduler:
         if run.any():
             # The counter serve/decode_pages: how much of the block table
             # this wave's running slots hold, which is all the decode
-            # kernel walks (``live`` pages of ``table`` entries), and
-            # ``sample``, the branch its sampling takes on the device. The
-            # host's view at dispatch: a slot that finishes in flight still
-            # counts.
+            # kernel walks (``live`` pages of ``table`` entries),
+            # ``sample``, the branch its sampling takes on the device, and
+            # for a model with window layers ``window_rows``, the ring rows
+            # each of them attends. The host's view at dispatch: a slot
+            # that finishes in flight still counts.
             with span("serve/decode_pages", tick=self.ticks) as sp:
                 if sp.on:
                     sp.set(
@@ -270,6 +273,9 @@ class Scheduler:
                         sample=SAMPLE_BRANCHES[int(sample_branch(
                             self.temp, self.top_k, self.top_p, run))],
                     )
+                    if self.window:
+                        sp.set(window_rows=int(np.minimum(
+                            self.lengths[run] + 1, self.window).sum()))
             # The dispatched ``fresh`` is never written again (the device
             # may still read it): the mirror moves on to a new array.
             fresh, self.fresh = self.fresh, self.fresh & ~run

@@ -737,3 +737,96 @@ def test_linear_moe_serve_programs_update_pages_and_state_in_place(sds, monkeypa
             name, memory.temp_size_in_bytes)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
         assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
+
+
+def test_window_moe_serve_programs_update_pages_and_rings_in_place(sds, monkeypatch):
+    """The engine's two programs for full and sliding-window attention layers
+    with a routed feed-forward at the codeagent cell's widths (d 2048; 48
+    query heads in a full layer, 64 in a sliding one, over 8 K/V heads of
+    128; a window of 512; YaRN over 64 lanes and a gate a head; 256 experts
+    scored, 64 of width 512 held, 8 chosen; 64 slots x 16384 positions in
+    blocks of 64, chunks of 1024; one full and one sliding layer and a small
+    vocabulary, so that it compiles in seconds): the decode program holds
+    ``paged_decode`` for the full layer, ``window_decode`` for the sliding
+    one and the grouped matmuls; the four donated arrays — K and V pages by
+    block, K and V rings by slot — are aliased input to output, and nothing
+    but a program's own update of them (a row scatter) produces an array of
+    their types: no copy."""
+    import re
+
+    import rocket_tpu.nn.moe as moe
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import AttentionKind, TransformerConfig, TransformerLM
+    from rocket_tpu.nn.attention import YarnScaling
+    from rocket_tpu.nn.moe import RoutedExpertsConfig
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    yarn = YarnScaling(factor=64, original_max_position_embeddings=4096, beta_fast=64,
+                       beta_slow=1)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=16384, dim=2048, num_layers=2,
+        num_heads=48, num_kv_heads=8, head_dim=128, dropout=0.0,
+        tied_embeddings=False, activation_dtype="bfloat16", pos_embedding="rope",
+        norm="rmsnorm", norm_eps=1e-6, attn_bias=False, attn_head_gate=True,
+        layer_types=("full", "sliding"), attention_kinds={
+            "full": AttentionKind(num_heads=48, rope_base=5e5, rope_fraction=0.5,
+                                  rope_yarn=yarn),
+            "sliding": AttentionKind(num_heads=64, rope_base=1e4, window=512)},
+        routed_experts=RoutedExpertsConfig(
+            num_experts=256, top_k=8, hidden=512, routed_scaling_factor=2.5,
+            shared_hidden=512, scoring="softmax", experts_held=(0, 64)),
+    ))
+    sc = ServeConfig(max_slots=64, block_len=64, prefill_chunk=1024,
+                     max_model_len=16384)
+    spec, mb, _, waves = sc.resolve(model.config)
+    assert spec.pages_shapes == ((1, 16385, 64, 1024),) * 2
+    assert spec.state_shapes == (((1, 64, 512, 1024), "bfloat16"),) * 2
+    donated = ["bf16[1,16385,64,1024]", "bf16[1,64,512,1024]"]
+    decode_args, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    programs = {
+        "decode": (build_decode_wave(model, waves=waves), decode_args,
+                   DECODE_DONATE, ("paged_decode", "window_decode", "moe_gmm_gate_up",
+                                   "moe_gmm_down")),
+        "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE,
+                    ("moe_gmm_gate_up",)),
+    }
+    for name, (fn, args, donate, wanted) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        text = compiled.as_text()
+        kernels = _kernel_instructions(text)
+        for kernel in wanted:
+            assert any(kernel in k for k in kernels), (name, kernel, kernels)
+        if name == "decode":
+            _assert_carry_stays_on_device(text, sc.max_slots)
+        made = [
+            (op, inst) for op, inst, _, line in _materialised(text)
+            if any(t in line.split(" = ")[1].split("(")[0] for t in donated)
+            and op not in ("parameter", "tuple", "get-tuple-element", "bitcast",
+                           "while", "custom-call", "scatter")
+            and not (op == "fusion" and re.search(r"/scatter\"", line))
+        ]
+        assert not made, (name, made)
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= spec.pool_bytes, (
+            name, memory.alias_size_in_bytes, spec.pool_bytes)
+        # Temporaries: a chunk's activations and a tile of its scores (a
+        # full layer's 2,048-row tile at 48 heads is 403 MB; ``made`` above
+        # is what says no page or ring array is copied), never a page array.
+        assert memory.temp_size_in_bytes < 1 << 30, (name, memory.temp_size_in_bytes)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
