@@ -55,10 +55,10 @@ Implementation choice and the ``block_kv`` tile height resolve through
 the ``paged_decode`` tune table (``rocket_tpu.tune``) — ``impl`` is a
 real structural search axis (the tuner can measure the XLA path beating
 the kernel on a shape and pin it). With nothing pinned the choice is a
-function of what the call can observe: the kernel for C=1 decode on a
-TPU wherever :func:`paged_decode_supported` holds, the XLA path for
-prefill chunks, unsupported pool geometries and on the CPU (bitwise
-identical to an untuned checkout — asserted in tests); the tile height
+function of what the call can observe: on a TPU the kernel for C=1
+decode where :func:`paged_decode_supported` holds and ``kv_prefill`` for
+a chunk against a long table; the XLA path for other chunks, other pool
+geometries and on the CPU (bitwise an untuned checkout's); the tile height
 from the page length and the row's width (:func:`_default_block_kv`).
 ``ROCKET_TPU_PAGED_DECODE`` (``pallas``/``xla``) force-overrides the
 table. A PINNED ``pallas`` (argument, table or environment) that cannot
@@ -498,7 +498,7 @@ def _attend_chunk_live(q, k_pages, v_pages, block_table, positions, valid,
     softmax, over the tiles that hold a live row only (a loop whose trip
     count is a traced value: ``max(positions + valid)`` rows), so neither
     the whole table's context nor its ``(C, table)`` scores materialise.
-    Same numbers as the one-shot form up to the order of the sums."""
+    The one-shot numbers up to the sums' order; a TPU runs ``kv_prefill``."""
     s, c, hq, d = q.shape
     h_kv = k_pages.shape[3] // d
     g = hq // h_kv
@@ -563,10 +563,10 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
 
     ``impl``/``block_kv`` pin the implementation explicitly (the tuner's
     candidate runs); left ``None`` they resolve through the
-    ``paged_decode`` tune table, defaulting to the fused pallas kernel
-    for C=1 decode on TPU where :func:`paged_decode_supported` holds and
-    the XLA path everywhere else. A pinned ``"pallas"`` that cannot run
-    (C > 1, unsupported pool geometry) raises ``ValueError``; on a CPU
+    ``paged_decode`` tune table (C=1), defaulting on a TPU to a kernel
+    (C=1 decode, or ``ops.kv_prefill`` for a chunk against a long table)
+    and the XLA path everywhere else. A pinned ``"pallas"`` that cannot
+    run (a short table, unsupported geometry) raises ``ValueError``; on a CPU
     host it runs interpreted. ``interpret=True`` runs the kernel
     interpreted on any backend (CPU parity tests).
 
@@ -588,8 +588,8 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         )
     itemsize = jnp.dtype(k_pages.dtype).itemsize
     on_cpu = _on_cpu()
-    kernel_can_run = c == 1 and paged_decode_supported(
-        bl, d, itemsize, lanes=h_kv * d)
+    kernel_can_run = paged_decode_supported(bl, d, itemsize, lanes=h_kv * d) \
+        and (c == 1 or _chunk_kernel_fits(s, c, hq, h_kv, d, bl, mb, itemsize))
     if (impl is None or block_kv is None) and c == 1:
         # Tunable surface (tune kernel "paged_decode"): impl is a REAL
         # structural axis (fused pallas kernel vs XLA gather) and
@@ -628,9 +628,9 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         raise ValueError(
             f"paged_attention: impl='pallas' cannot run here (C={c}, "
             f"block_len={bl}, head_dim={d}, lanes={h_kv * d}, "
-            f"itemsize={itemsize}) — the fused kernel is C=1 decode only "
-            "and needs paged_decode_supported(block_len, head_dim, "
-            "itemsize, lanes=Hkv*D); pin impl='xla' for this shape"
+            f"itemsize={itemsize}) — the kernels are C=1 decode under "
+            "paged_decode_supported and long-table chunks under "
+            "kv_prefill_supported; pin impl='xla' for this shape"
         )
 
     k_pages, v_pages = write_kv_pages(
@@ -638,7 +638,7 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
         layer=layer,
     )
 
-    if impl == "pallas":
+    if impl == "pallas" and c == 1:
         if block_kv % _SUBLANE.get(itemsize, 8) or (
             bl % block_kv and block_kv % bl
         ):
@@ -653,12 +653,34 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, block_table,
             block_kv=int(block_kv), interpret=on_cpu or bool(interpret),
         ).reshape(s, 1, hq * d)
         return out, k_pages, v_pages
-    if c > 1 and s * hq * c * mb * bl > _CHUNK_SCORES_MAX:
+    if impl == "xla" and _long_chunk(s, c, hq, mb, bl):
         out = _attend_chunk_live(
             q, k_pages, v_pages, block_table, positions, valid, layer)
-    else:
+    elif impl == "xla":
         out = _attend_xla(q, k_pages, v_pages, block_table, positions, layer)
+    else:
+        from rocket_tpu.ops.kv_prefill import kv_prefill
+
+        out = kv_prefill(
+            q, k_pages, v_pages, block_table, positions, valid, layer,
+            interpret=on_cpu or bool(interpret),
+        )
     return out, k_pages, v_pages
+
+
+def _long_chunk(s, c, hq, mb, bl) -> bool:
+    """Whether a chunk's one-shot scores would pass ``_CHUNK_SCORES_MAX``,
+    so that its attention walks the live context."""
+    return c > 1 and s * hq * c * mb * bl > _CHUNK_SCORES_MAX
+
+
+def _chunk_kernel_fits(s, c, hq, h_kv, d, bl, mb, itemsize) -> bool:
+    """The chunk kernel's gate: the chunk walks the live context and
+    ``ops.kv_prefill`` takes its shapes."""
+    from rocket_tpu.ops.kv_prefill import kv_prefill_supported
+
+    return _long_chunk(s, c, hq, mb, bl) and kv_prefill_supported(
+        c, hq, h_kv, d, bl, mb * bl, itemsize)
 
 
 def _ring_write(ring, slot_ids, rows, keep, new, *, layer):

@@ -830,3 +830,110 @@ def test_window_moe_serve_programs_update_pages_and_rings_in_place(sds, monkeypa
         assert memory.temp_size_in_bytes < 1 << 30, (name, memory.temp_size_in_bytes)
         aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
         assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
+
+
+# -- the K/V pool's long-table chunk attention is one kernel -----------------
+
+
+def _kv_chunk_model(cell):
+    """A dense model with a cell's caching attention widths and its table:
+    codeagent's 48 query heads over 8 K/V heads of 128 in full layers beside
+    sliding ones (YaRN over 64 lanes, a gate a head), longchat's 16 over 2
+    of 256 (gated, q/k norms, rotary over 64 lanes); 16,384 positions,
+    chunks of 1,024. Small FFN and vocabulary, so that it compiles in
+    seconds."""
+    from rocket_tpu.models.transformer import AttentionKind, TransformerConfig, TransformerLM
+    from rocket_tpu.nn.attention import YarnScaling
+
+    common = dict(vocab_size=1024, max_seq_len=16384, dim=2048, dropout=0.0,
+                  tied_embeddings=False, activation_dtype="bfloat16", pos_embedding="rope",
+                  norm="rmsnorm", norm_eps=1e-6, attn_bias=False, mlp="swiglu",
+                  mlp_hidden=512, mlp_bias=False)
+    if cell == "codeagent":
+        yarn = YarnScaling(factor=64, original_max_position_embeddings=4096, beta_fast=64,
+                           beta_slow=1)
+        config = TransformerConfig(
+            num_layers=4, num_heads=48, num_kv_heads=8, head_dim=128, attn_head_gate=True,
+            layer_types=("full", "sliding", "full", "sliding"), attention_kinds={
+                "full": AttentionKind(num_heads=48, rope_base=5e5, rope_fraction=0.5,
+                                      rope_yarn=yarn),
+                "sliding": AttentionKind(num_heads=64, rope_base=1e4, window=512)},
+            **common)
+        return TransformerLM(config), (1, 8, 6)
+    config = TransformerConfig(
+        num_layers=3, num_heads=16, num_kv_heads=2, head_dim=256, attn_gate=True,
+        qk_norm=True, rope_base=1e7, rope_fraction=0.25, **common)
+    return TransformerLM(config), (1, 2, 8)
+
+
+@pytest.mark.parametrize("cell", ["codeagent", "longchat"])
+def test_kv_prefill_program_attends_in_the_kv_prefill_kernel(sds, monkeypatch, cell):
+    """The prefill program of a cell-shaped model for the chip: every live
+    full-attention layer's chunk attention is a ``kv_prefill`` custom-call
+    (the last layer's output is dead code in a chunk, which discards its
+    logits), the layers share ONE lowering of it, no loop of float32 ``(S,
+    Hkv, G, C, tile)`` scores is left, and the pages are still only
+    scattered into in place."""
+    import re
+
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.ops.kv_prefill import kv_prefill_supported
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import PREFILL_DONATE, abstract_wave_inputs, build_prefill_step
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    model, (s, h_kv, g) = _kv_chunk_model(cell)
+    d = model.config.head_dim
+    sc = ServeConfig(max_slots=8, block_len=64, prefill_chunk=1024, max_model_len=16384)
+    spec, mb, _, _ = sc.resolve(model.config)
+    assert kv_prefill_supported(1024, h_kv * g, h_kv, d, 64, 16384, 2)
+    _, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+                             prefill_chunk=sc.prefill_chunk),
+    )
+    lowered = jax.jit(build_prefill_step(model), donate_argnums=PREFILL_DONATE).lower(
+        *prefill_args)
+    stablehlo = lowered.as_text()
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", stablehlo)) == 1
+    assert len(re.findall(r"call @kv_prefill", stablehlo)) == 2
+    assert f"{s}x{h_kv}x{g}x1024x2048xf32>" not in stablehlo
+    text = lowered.compile().as_text()
+    assert sum("kv_prefill" in k for k in _kernel_instructions(text)) == 2, text[:300]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert aliases and aliases.group(1).count("alias") == len(spec.arrays), text[:300]
+
+
+@pytest.mark.parametrize("cell", ["chat-busy", "reason"])
+def test_short_table_prefill_programs_hold_no_kv_prefill(sds, monkeypatch, cell):
+    """The chat-busy and reason cells' chunks (20 heads x 64 against 1,024
+    positions, chunks of 128; 20 over 1 K/V head of 128 against 4,096,
+    chunks of 512) make fewer one-shot scores than the long-table walk's
+    threshold, so their prefill programs attend in XLA as before: no
+    ``kv_prefill`` in them."""
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import abstract_wave_inputs, build_prefill_step
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    if cell == "chat-busy":
+        model = TransformerLM(TransformerConfig(
+            vocab_size=1024, max_seq_len=1024, dim=1280, num_layers=2, num_heads=20,
+            dropout=0.0, activation_dtype="bfloat16"))
+        sc = ServeConfig(max_slots=32, block_len=16, prefill_chunk=128)
+    else:
+        model = TransformerLM(TransformerConfig(
+            vocab_size=1024, max_seq_len=4096, dim=2560, num_layers=2, num_heads=20,
+            num_kv_heads=1, head_dim=128, dropout=0.0, activation_dtype="bfloat16",
+            pos_embedding="none", norm="rmsnorm", attn_bias=False))
+        sc = ServeConfig(max_slots=64, block_len=64, prefill_chunk=512, max_model_len=4096)
+    spec, mb, _, _ = sc.resolve(model.config)
+    assert not paged._long_chunk(1, sc.prefill_chunk, 20, mb, sc.block_len)
+    _, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+                             prefill_chunk=sc.prefill_chunk),
+    )
+    stablehlo = jax.jit(build_prefill_step(model)).lower(*prefill_args).as_text()
+    assert "kv_prefill" not in stablehlo and "tpu_custom_call" not in stablehlo
