@@ -32,17 +32,29 @@ __all__ = ["AttentionKind", "TransformerConfig", "TransformerLM", "Block",
 
 @dataclass(frozen=True)
 class AttentionKind:
-    """What one kind of attention layer changes of the configuration's
-    attention (a field left None keeps the configuration's): its query
-    heads, its rotary (base, share of each head's lanes, YaRN) and its
+    """What one kind of layer changes of the configuration's attention (a
+    field left None keeps the configuration's): its query heads, its rotary
+    (``rope`` False: none; base, share of each head's lanes, YaRN) and its
     ``window`` (0: it sees the whole context and caches pages; > 0: it sees
-    the last ``window`` positions and keeps a ring of them a slot)."""
+    the last ``window`` positions and keeps a ring of them a slot). A
+    ``state`` kind is no attention at all: the configuration's state mixer
+    (``ssm``, ``gdn`` or ``lightning``), which carries a state a slot. A
+    ``sparse`` kind caches pages and attends the blocks it picks through
+    the configuration's ``sparse_attention``."""
 
     num_heads: Optional[int] = None
     rope_base: Optional[float] = None
     rope_fraction: Optional[float] = None
     rope_yarn: Optional[Any] = None
     window: int = 0
+    rope: Optional[bool] = None
+    state: bool = False
+    sparse: bool = False
+
+
+#: The kind of a state layer of a model described by the period-and-offset
+#: rule (no ``layer_types``).
+_STATE_KIND = AttentionKind(state=True)
 
 #: Memoized jax.checkpoint policies (see TransformerConfig.remat_policy).
 _REMAT_POLICIES: dict = {}
@@ -214,6 +226,21 @@ class TransformerConfig:
     #: :class:`AttentionKind`. Empty = every layer is the one kind.
     layer_types: tuple = ()
     attention_kinds: Optional[dict] = None
+    #: Lightning-attention mixers (``nn.lightning.LightningAttention``) as
+    #: the state layers, in place of ``ssm`` or ``gdn``: a
+    #: ``LightningConfig``. Which layers they are is ``layer_types``' to say
+    #: (kinds with ``state``).
+    lightning: Optional[Any] = None
+    #: Block-sparse attention (``ops.paged_attention.SparseAttentionConfig``)
+    #: for the kinds with ``sparse``: a compressed-key cache a slot beside
+    #: the pages (:meth:`slot_state_shapes`).
+    sparse_attention: Optional[Any] = None
+    #: muP scalings (MiniCPM's): the embedding times ``embed_scale``, each
+    #: residual branch times ``residual_scale`` and the last hidden state
+    #: over ``logit_divisor`` before the head. 1 = off.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
     #: Label smoothing for ``next_token_loss``: the target distribution is
     #: (1-eps) one-hot + eps uniform. Lives on the CONFIG (not the
     #: objective) so the fused (loss_chunk) and full-logits paths apply the
@@ -294,10 +321,10 @@ class TransformerConfig:
             raise ValueError(
                 "TransformerConfig: first_dense_layers without routed_experts"
             )
-        if self.ssm is not None and self.gdn is not None:
+        if sum(m is not None for m in (self.ssm, self.gdn, self.lightning)) > 1:
             raise ValueError(
-                "TransformerConfig: ssm and gdn are two kinds of state "
-                "layer; give one")
+                "TransformerConfig: ssm, gdn and lightning are kinds of state "
+                "layer, and a model holds no two kinds of state layer; give one")
         if self.norm_zero_centered and self.norm != "rmsnorm":
             raise ValueError(
                 "TransformerConfig: norm_zero_centered is RMSNorm's")
@@ -335,6 +362,19 @@ class TransformerConfig:
                     f"TransformerConfig: layer_types must name an "
                     f"attention_kinds entry for each of {self.num_layers} "
                     f"layers (unknown: {sorted(unknown)})")
+            used = [kinds[t] for t in self.layer_types[:self.num_layers]]
+            if any(k.state for k in used) and (
+                    self.state_mixer is None or self.attn_layer_period):
+                raise ValueError(
+                    "TransformerConfig: a state kind needs a state mixer (ssm, "
+                    "gdn or lightning), and layer_types in place of "
+                    "attn_layer_period")
+            if any(k.sparse for k in used) and (
+                    self.sparse_attention is None or self.latent_attention is not None
+                    or self.scan_layers or self.pipeline_axis):
+                raise ValueError(
+                    "TransformerConfig: a sparse kind needs sparse_attention, "
+                    "multi-head attention and a Python loop of layers")
             windows = {self.attention_kind(i).window for i in range(self.num_layers)}
             if len(windows - {0}) > 1:
                 raise ValueError(
@@ -388,9 +428,12 @@ class TransformerConfig:
     @property
     def state_mixer(self):
         """The state layers' mixer configuration, of whichever kind is
-        given (``ssm`` or ``gdn``), or None: it declares what a slot
-        carries (``state_shapes``) and builds the layer (``make_mixer``)."""
-        return self.ssm if self.ssm is not None else self.gdn
+        given (``ssm``, ``gdn`` or ``lightning``), or None: it declares
+        what a slot carries (``state_shapes``) and builds the layer
+        (``make_mixer``)."""
+        if self.ssm is not None:
+            return self.ssm
+        return self.gdn if self.gdn is not None else self.lightning
 
     @property
     def kv_pool_lanes(self) -> tuple:
@@ -406,12 +449,9 @@ class TransformerConfig:
         return (lanes, lanes)
 
     def is_state_layer(self, layer_idx: int) -> bool:
-        """Whether layer ``layer_idx`` is a state-space mixer (else it is
-        attention and caches pages)."""
-        if self.state_mixer is None:
-            return False
-        period = self.attn_layer_period
-        return not (period and layer_idx % period == self.attn_layer_offset)
+        """Whether layer ``layer_idx`` is a state mixer (else it is
+        attention and caches pages or a ring): its kind's ``state``."""
+        return self.state_mixer is not None and self.attention_kind(layer_idx).state
 
     @property
     def state_layers(self) -> int:
@@ -419,11 +459,24 @@ class TransformerConfig:
         return sum(self.is_state_layer(i) for i in range(self.num_layers))
 
     def attention_kind(self, layer_idx: int) -> AttentionKind:
-        """Layer ``layer_idx``'s :class:`AttentionKind` (``layer_types``
-        read once, here; a model without it has one plain kind)."""
-        if not self.layer_types:
-            return AttentionKind()
-        return self.attention_kinds[self.layer_types[layer_idx]]
+        """Layer ``layer_idx``'s :class:`AttentionKind`: ``layer_types``
+        read once, here. A model without it has one plain kind and, with a
+        state mixer, the state kind wherever the period-and-offset rule
+        (Jamba's: attention where ``i % attn_layer_period ==
+        attn_layer_offset``; period 0: nowhere) puts no attention."""
+        if self.layer_types:
+            return self.attention_kinds[self.layer_types[layer_idx]]
+        period = self.attn_layer_period
+        if self.state_mixer is not None and not (
+                period and layer_idx % period == self.attn_layer_offset):
+            return _STATE_KIND
+        return AttentionKind()
+
+    @property
+    def sparse_layers(self) -> int:
+        """How many layers attend the blocks they pick (and keep
+        compressed keys a slot)."""
+        return sum(self.attention_kind(i).sparse for i in range(self.num_layers))
 
     @property
     def window_layers(self) -> int:
@@ -456,6 +509,16 @@ class TransformerConfig:
             ring = (self.window, self.kv_pool_lanes[0])
             dtype = self.activation_dtype or "float32"
             return ((self.window_layers, ring, dtype),) * 2
+        if self.sparse_layers:
+            # Behind the mixers' state, the sparse layers' compressed keys:
+            # a row of Hkv * head_dim lanes every kernel_stride positions.
+            dtype = self.activation_dtype or "float32"
+            units = self.sparse_attention.units(self.max_seq_len)
+            mixers = () if not self.state_layers else tuple(
+                (self.state_layers, shape, kind) for shape, kind in
+                self.state_mixer.state_shapes(dtype))
+            return mixers + (
+                (self.sparse_layers, (units, self.kv_pool_lanes[0]), dtype),)
         if not self.state_layers:
             return ()
         return tuple(
@@ -531,9 +594,16 @@ class Block(Layer):
         kind = c.attention_kind(layer_idx)
         #: The rows this layer's attention sees (0: the whole context).
         self.window = kind.window
+        #: This layer attends the blocks it picks (``sparse_attention``).
+        self.sparse = kind.sparse
+        #: The muP factor on each residual branch (1: none).
+        self.branch_scale = c.residual_scale
         if c.is_state_layer(layer_idx):
+            # A lightning mixer's decay depends on which layer it is.
+            where = {} if c.lightning is None else {"layer": layer_idx}
             self.mixer = c.state_mixer.make_mixer(
                 c.dim, norm_eps=1e-6 if c.norm_eps is None else c.norm_eps,
+                **where,
             )
         elif self.latent:
             from rocket_tpu.nn.attention import LatentAttention
@@ -550,7 +620,8 @@ class Block(Layer):
                 num_kv_heads=c.num_kv_heads,
                 causal=c.causal, dropout=c.dropout, use_bias=c.attn_bias,
                 impl=c.attention_impl,
-                seq_axis=c.seq_axis, rope=c.pos_embedding == "rope",
+                seq_axis=c.seq_axis,
+                rope=pick(kind.rope, c.pos_embedding == "rope"),
                 rope_base=pick(kind.rope_base, c.rope_base),
                 head_dim=c.head_dim, gate=c.attn_gate,
                 qk_norm=c.qk_norm,
@@ -597,6 +668,7 @@ class Block(Layer):
                 self.fc_in = dense(c.dim, hidden)
             self.fc_out = dense(hidden, c.dim)
         self.mlp_type = c.mlp
+        self.sparse_cfg = c.sparse_attention
         self.dropout = Dropout(c.dropout) if c.dropout else None
         # GPT-2: residual projections scaled by 1/sqrt(2*num_layers).
         self._resid_scale = (2 * c.num_layers) ** -0.5
@@ -675,7 +747,7 @@ class Block(Layer):
         h = checkpoint_name(h, "attn_out")
         if self.dropout is not None:
             h, _ = self.dropout.apply({"params": {}, "state": {}}, h, mode=mode, rng=rngs[1])
-        x = x + h
+        x = x + self._scaled(h)
 
         h, _ = self.ln2.apply({"params": p["ln2"], "state": {}}, x)
         aux = None
@@ -698,8 +770,12 @@ class Block(Layer):
             out_state = dict(variables["state"])
             out_state["aux_loss"] = aux["aux_loss"]
             out_state["frac_dropped"] = aux["frac_dropped"]
-            return x + h, out_state
-        return x + h, variables["state"]
+            return x + self._scaled(h), out_state
+        return x + self._scaled(h), variables["state"]
+
+    def _scaled(self, h):
+        """A residual branch times the muP factor (none at 1)."""
+        return h if self.branch_scale == 1.0 else h * self.branch_scale
 
     def _block_attn_config(self, x):
         """The ``block_attn`` structural config when the fused
@@ -780,6 +856,10 @@ class Block(Layer):
                 )
             return out
         h, _ = self.ln1.apply({"params": p["ln1"], "state": {}}, x)
+        if self.sparse:
+            raise NotImplementedError(
+                "Block: block-sparse attention runs against the paged pool "
+                "(TransformerLM.paged_step) only")
         if self.mixer is not None:
             return self.mixer.apply(
                 {"params": p["mixer"], "state": {}}, h, mode=mode
@@ -858,8 +938,27 @@ class Block(Layer):
             params["mixer"], h, state, positions, valid, layer=layer,
             slots=slots,
         )
-        y, counts = self._ffn_half(params, x + h, valid)
+        y, counts = self._ffn_half(params, x + self._scaled(h), valid)
         return y, state, counts
+
+    def apply_sparse(self, params, x, pages, state, block_table, positions,
+                     valid, slots=None, layer=0):
+        """:meth:`apply_paged` for a block-sparse layer: its K/V rows go to
+        the pages at its ``layer`` coordinate, and its compressed keys to
+        the LAST of the per-slot ``state`` arrays at ``(layer, slots)``
+        (``ops.paged_attention.sparse_attention``). No rotary unless its
+        kind asks. Returns ``(y, pages', state', counts)``."""
+        from rocket_tpu.ops.paged_attention import sparse_attention
+
+        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
+        q, k, v, gate = self.attn._project(params["attn"], h, positions)
+        out, k_pages, v_pages, kc = sparse_attention(
+            q, k, v, *pages, state[-1], block_table, positions, valid,
+            slots=slots, layer=layer, cfg=self.sparse_cfg,
+        )
+        h = self.attn._gated_out(params["attn"], out, gate)
+        y, counts = self._ffn_half(params, x + self._scaled(h), valid)
+        return y, (k_pages, v_pages), tuple(state[:-1]) + (kc,), counts
 
     def _ffn_half(self, params, x, valid):
         """ln2 + the FFN of a paged or stateful chunk ``x`` (S, C, D)."""
@@ -867,7 +966,7 @@ class Block(Layer):
         # Padding rows and idle slots are no tokens: they route nowhere.
         real = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :] < valid[:, None]
         h, counts = self._ffn_eval(params, h, token_mask=real)
-        return x + h, counts
+        return x + self._scaled(h), counts
 
     def _mlp_tp_spec(self, h):
         """Overlap spec when the MLP can take the collective-matmul
@@ -1080,6 +1179,8 @@ class TransformerLM(Model):
         s, c = tokens.shape
         pages = tuple(pages)
         x = jnp.take(p["wte"]["table"], tokens, axis=0)
+        if self.config.embed_scale != 1.0:
+            x = x * self.config.embed_scale
         if self.wpe is not None:
             pos_ids = jnp.clip(
                 positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :],
@@ -1122,6 +1223,12 @@ class TransformerLM(Model):
                         slots, layer=stateful,
                     )
                     stateful += 1
+                elif block.sparse:
+                    x, pages, state, counts = block.apply_sparse(
+                        p["blocks"][str(i)], x, pages, state, block_table,
+                        positions, valid, slots, layer=cached,
+                    )
+                    cached += 1
                 else:
                     x, pages, counts = block.apply_paged(
                         p["blocks"][str(i)], x, pages, block_table,
@@ -1135,6 +1242,8 @@ class TransformerLM(Model):
 
         x = x[:, -1:]  # only the last position's logits are consumed
         x, _ = self.ln_f.apply({"params": p["ln_f"], "state": {}}, x)
+        if self.config.logit_divisor != 1.0:
+            x = x / self.config.logit_divisor
         if self.head is not None:
             logits, _ = self.head.apply({"params": p["head"], "state": {}}, x)
         else:
@@ -1396,6 +1505,8 @@ class TransformerLM(Model):
             x = jnp.take(p["wte"]["table"], tokens, axis=0)
             if self.wpe is not None:
                 x = x + p["wpe"]["table"][:t]
+        if self.config.embed_scale != 1.0:
+            x = x * self.config.embed_scale
         if self.config.activation_dtype is not None:
             x = x.astype(self.config.activation_dtype)
         if self.drop is not None:
@@ -1452,6 +1563,8 @@ class TransformerLM(Model):
                     dropped_total = dropped_total + bstate["frac_dropped"]
 
         x, _ = self.ln_f.apply({"params": p["ln_f"], "state": {}}, x)
+        if self.config.logit_divisor != 1.0:
+            x = x / self.config.logit_divisor
         out = dict(batch)
         if self.config.label_smoothing and mode == "train":
             # Train-only: eval loss stays plain CE, comparable to

@@ -783,3 +783,476 @@ def window_attention(q, k_new, v_new, k_ring, v_ring, positions, valid, *,
     out = jnp.moveaxis(out, 0, 2).reshape(s, c, hq * d)
     k_ring, v_ring = write(k_ring, v_ring)
     return out, k_ring, v_ring
+
+
+# -- block-sparse attention over the pool (InfLLM v2, MiniCPM4) ---------------
+#
+# A sparse layer caches its K/V rows in the pool like any other; what it
+# adds is a COMPRESSED-KEY cache a slot (one row of ``Hkv * D`` lanes for
+# every ``kernel_stride`` positions: the mean of ``kernel_size`` keys), which
+# a query past ``dense_len`` scores to choose ``topk`` pages per K/V head.
+# The selection runs in XLA; the attention over the chosen pages is the paged
+# decode kernel walking each (slot, K/V head)'s own page list
+# (``sparse_decode``) and, for a prefill chunk, a walk of the live context
+# under a (row, page) mask.
+
+import dataclasses  # noqa: E402 -- here: a line added above would move the source locations the decode kernel's lowered body carries
+
+__all__ += [
+    "SparseAttentionConfig",
+    "write_compressed_keys",
+    "select_pages",
+    "sparse_attention",
+]
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseAttentionConfig:
+    """Block-sparse attention (MiniCPM4's ``sparse_config``): keys are
+    pooled by ``kernel_size`` at a ``kernel_stride``, scored against each
+    query, and the ``topk`` blocks of ``block_size`` positions with the
+    highest scores are attended, block ``0 .. init_blocks - 1`` and every
+    block holding one of the last ``window_size`` positions always. A query
+    at a position under ``dense_len`` attends every position before it."""
+
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+
+    def units(self, max_len: int) -> int:
+        """Compressed-key rows a slot of ``max_len`` positions holds."""
+        return -(-max_len // self.kernel_stride)
+
+    @property
+    def list_len(self) -> int:
+        """Entries of a decode row's page list: the ``topk`` of a sparse
+        query, or every page of a dense one."""
+        return max(self.topk, -(-self.dense_len // self.block_size))
+
+
+def write_compressed_keys(kc, k_pages, block_table, positions, valid, *,
+                          rows: int, layer, slots, cfg: SparseAttentionConfig):
+    """The compressed keys that complete in a chunk of ``rows`` rows, into
+    ``kc`` ``(Ls, max_slots, units, lanes)`` at ``(layer, slots[s], j)``:
+    unit ``j`` is the mean, in float32, of the keys of positions ``j *
+    stride .. j * stride + kernel - 1`` and completes with the last of them.
+    The chunk's K rows are already in ``k_pages`` (``write_kv_pages``), the
+    earlier ones a unit straddles are read from there too. A unit that does
+    not complete in the chunk is not written."""
+    stride, size = cfg.kernel_stride, cfg.kernel_size
+    bl = k_pages.shape[2]
+    mb = block_table.shape[1]
+    n = -(-rows // stride) + 1
+    first = jnp.maximum(-((size - 1 - positions) // stride), 0)      # ceil
+    j = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]     # (S, n)
+    end = j * stride + size - 1
+    done = (end >= positions[:, None]) & (end < (positions + valid)[:, None])
+    pos = j[..., None] * stride + jnp.arange(size, dtype=jnp.int32)  # (S, n, K)
+    page = jnp.take_along_axis(
+        block_table, jnp.clip(pos // bl, 0, mb - 1).reshape(pos.shape[0], -1),
+        axis=1).reshape(pos.shape)
+    keys = k_pages[layer, page, pos % bl].astype(jnp.float32)        # (S, n, K, lanes)
+    mean = jnp.mean(keys, axis=2).astype(kc.dtype)
+    units = jnp.where(done, j, kc.shape[2])
+    return kc.at[layer, slots[:, None], units].set(mean, mode="drop")
+
+
+def _block_scores(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
+    """``(S, C, Hkv, blocks)`` float32: each query row's score of every
+    block — per K/V head ``g`` the softmax over the complete units (``j *
+    stride + kernel - 1 <= pos``) of ``q_h . Kc_g / sqrt(D)``, summed over
+    the group's query heads, then the most of it over the units that
+    overlap the block; -1 where none does. ``q`` (S, C, Hq, D); ``kc_rows``
+    (S, units, Hkv * D); ``pos`` (S, C)."""
+    s, c, hq, d = q.shape
+    g = hq // h_kv
+    u = kc_rows.shape[1]
+    stride, size, bsz = cfg.kernel_stride, cfg.kernel_size, cfg.block_size
+    kcs = kc_rows.reshape(s, u, h_kv, d)
+    q5 = q.reshape(s, c, h_kv, g, d)
+    complete = (jnp.arange(u, dtype=jnp.int32) * stride + size - 1)[None, None, :] \
+        <= pos[..., None]                                             # (S, C, U)
+    scale = 1.0 / math.sqrt(d)
+
+    def head(i, acc):
+        qh = jax.lax.dynamic_index_in_dim(q5, i, axis=3, keepdims=False)
+        logits = jnp.einsum("sckd,sukd->scku", qh, kcs,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(complete[:, :, None, :], logits, _NEG_INF)
+        return acc + jax.nn.softmax(logits, axis=-1)
+
+    c_sum = jax.lax.fori_loop(0, g, head, jnp.zeros((s, c, h_kv, u), jnp.float32))
+    c_sum = jnp.where(complete[:, :, None, :], c_sum, -1.0)
+    per = bsz // stride
+    nb = u // per
+    lo, hi = -((size - 1) // stride), (bsz - 1) // stride
+    padded = jnp.pad(c_sum, ((0, 0), (0, 0), (0, 0), (-lo, hi)), constant_values=-1.0)
+    r = None
+    for o in range(lo, hi + 1):
+        take = jax.lax.slice_in_dim(padded, o - lo, o - lo + nb * per, stride=per, axis=3)
+        r = take if r is None else jnp.maximum(r, take)
+    return r
+
+
+def select_pages(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
+    """The blocks each query row attends: ``(top (S, C, Hkv, topk) block
+    ids, sparse (S, C) bool)`` — ``top`` the ``topk`` best by
+    :func:`_block_scores` among the blocks that start at or before the row
+    (blocks ``< init_blocks`` and those holding a position in ``(pos -
+    window_size, pos]`` first, ties to the lower id), ``sparse`` whether the
+    row is at or past ``dense_len`` (else it attends every block)."""
+    nb = kc_rows.shape[1] // (cfg.block_size // cfg.kernel_stride)
+    start = jnp.arange(nb, dtype=jnp.int32) * cfg.block_size
+    sparse = pos >= cfg.dense_len
+
+    def scored():
+        r = _block_scores(q, kc_rows, pos, h_kv=h_kv, cfg=cfg)
+        p = pos[..., None, None]
+        forced = (jnp.arange(nb) < cfg.init_blocks) | (
+            start + cfg.block_size - 1 > p - cfg.window_size)
+        r = jnp.where(forced, 1e30, r)
+        r = jnp.where(start <= p, r, -1e30)
+        return jax.lax.top_k(r, cfg.topk)[1].astype(jnp.int32)
+
+    s, c = pos.shape
+    top = jax.lax.cond(
+        jnp.any(sparse), scored,
+        lambda: jnp.zeros((s, c, h_kv, cfg.topk), jnp.int32))
+    return top, sparse
+
+
+def _sparse_decode_kernel(layer_ref, table_ref, ids_ref, count_ref, pos_ref,
+                          q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                          m_ref, l_ref, acc_ref, *, per, bl, mb, nl, h_kv,
+                          d, scale):
+    """One (slot, K/V head) of a sparse decode wave: ``_decode_kernel``'s
+    double-buffered walk over the head's OWN page list (``ids``, logical
+    block ids, ``count`` of them) in tiles of ``per`` pages, each page's
+    ``D`` lanes of this head copied from where the table says it lies. A
+    key is seen where its page is on the list and its position is at most
+    the slot's; a slot that does not run has ``count`` 0 and folds
+    nothing."""
+    i, h = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    row = i * h_kv + h
+    n = count_ref[row]
+    pos = pos_ref[i]
+    n_tiles = pl.cdiv(n, per)
+    lane = h * d
+    g = q_ref.shape[2]
+    width = per * bl
+
+    def entry(t, c):
+        return ids_ref[row * nl + jnp.minimum(t * per + c, nl - 1)]
+
+    def copies(t, buf):
+        out = []
+        for c in range(per):
+            page = table_ref[i * mb + jnp.minimum(entry(t, c), mb - 1)]
+            for m, (hbm, vmem) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                out.append((t * per + c < n, pltpu.make_async_copy(
+                    hbm.at[layer, page, :, pl.ds(lane, d)],
+                    vmem.at[buf, pl.ds(c * bl, bl)],
+                    sems.at[buf, m],
+                )))
+        return out
+
+    def start(t, buf):
+        for live, copy in copies(t, buf):
+            pl.when(live)(copy.start)
+
+    def wait(t, buf):
+        for live, copy in copies(t, buf):
+            pl.when(live)(copy.wait)
+
+    @pl.when((i == 0) & (h == 0))
+    def _clear():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        start(0, 0)
+
+    def tile(t, carry):
+        buf = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _next():
+            start(t + 1, 1 - buf)
+
+        wait(t, buf)
+        col = jax.lax.broadcasted_iota(jnp.int32, (g, width), 1)
+        key_pos = jnp.full((g, width), -1, jnp.int32)
+        for c in range(per):
+            base = jnp.where(t * per + c < n, entry(t, c) * bl - c * bl, -width - 1)
+            here = (col >= c * bl) & (col < (c + 1) * bl)
+            key_pos = jnp.where(here, col + base, key_pos)
+        seen = (key_pos >= 0) & (key_pos <= pos)
+        q = q_ref[0, 0]                                   # (g, D)
+        k = k_buf[buf]                                    # (width, D)
+        v = v_buf[buf]
+        s_ij = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale
+        s_ij = jnp.where(seen, s_ij, _NEG_INF)
+        m_prev = m_ref[:, 0:1]
+        l_prev = l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_ij, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(s_ij - m_new), 0.0)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile, None)
+    denom = jnp.where(n_tiles > 0, l_ref[:, 0:1], 1.0)
+    o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+#: Context rows a tile of ``sparse_decode`` folds at once (whole pages).
+_SPARSE_TILE_ROWS = 512
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sparse_decode_pallas(q, k_pages, v_pages, block_table, ids, count,
+                          positions, layer, *, interpret: bool):
+    """The fused walk of a decode wave over each (slot, K/V head)'s page
+    list, under the Pallas name ``sparse_decode``: ``q`` (S, Hq, D);
+    ``ids`` (S, Hkv, NL) logical block ids, the first ``count`` (S, Hkv)
+    of them attended. Returns (S, Hq, D); a row with ``count`` 0 is
+    zeros."""
+    s, hq, d = q.shape
+    _, _, bl, lanes = k_pages.shape
+    h_kv = lanes // d
+    g = hq // h_kv
+    mb = block_table.shape[1]
+    nl = ids.shape[2]
+    per = max(1, min(nl, _SPARSE_TILE_ROWS // bl))
+
+    def head_map(i, h, *prefetched):
+        del prefetched
+        return (i, h, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s, h_kv),
+        in_specs=[pl.BlockSpec((1, 1, g, d), head_map),
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, 1, g, d), head_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, per * bl, d), k_pages.dtype),
+            pltpu.VMEM((2, per * bl, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((g, 128), jnp.float32),   # running max (lane-bcast)
+            pltpu.VMEM((g, 128), jnp.float32),   # running denom
+            pltpu.VMEM((g, d), jnp.float32),     # unnormalized accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _sparse_decode_kernel, per=per, bl=bl, mb=mb, nl=nl, h_kv=h_kv,
+            d=d, scale=1.0 / math.sqrt(d),
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, h_kv, g, d), q.dtype),
+        # One step after another: the tile buffers are cleared at the first.
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="sparse_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_table.reshape(-1).astype(jnp.int32),
+      ids.reshape(-1).astype(jnp.int32), count.reshape(-1).astype(jnp.int32),
+      jnp.asarray(positions, jnp.int32),
+      q.reshape(s, h_kv, g, d), k_pages, v_pages)
+    return out.reshape(s, hq, d)
+
+
+def _sparse_decode_xla(q, k_pages, v_pages, block_table, ids, count,
+                       positions, layer):
+    """:func:`_sparse_decode_pallas` gathered and attended in XLA: each
+    (slot, K/V head)'s listed pages, the same keys seen."""
+    s, hq, d = q.shape
+    _, _, bl, lanes = k_pages.shape
+    h_kv = lanes // d
+    g = hq // h_kv
+    mb = block_table.shape[1]
+    nl = ids.shape[2]
+    pages = jnp.take_along_axis(
+        block_table, jnp.clip(ids, 0, mb - 1).reshape(s, -1), axis=1
+    ).reshape(s, h_kv, nl)
+
+    def ctx(pool):
+        rows = pool[layer, pages].reshape(s, h_kv, nl * bl, h_kv, d)
+        return jnp.take_along_axis(
+            rows, jnp.arange(h_kv)[None, :, None, None, None], axis=3)[:, :, :, 0]
+
+    k_ctx, v_ctx = ctx(k_pages), ctx(v_pages)                    # (S, Hkv, T, D)
+    key_pos = (ids[..., None] * bl + jnp.arange(bl)).reshape(s, h_kv, -1)
+    listed = (jnp.arange(nl)[None, None, :] < count[..., None])
+    listed = jnp.repeat(listed, bl, axis=2)
+    seen = listed & (key_pos <= positions[:, None, None])
+    logits = jnp.einsum("skgd,sktd->skgt", q.reshape(s, h_kv, g, d), k_ctx,
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    logits = jnp.where(seen[:, :, None], logits, -jnp.inf)
+    weights = jax.nn.softmax(logits, axis=-1)
+    weights = jnp.where(jnp.any(seen, -1)[:, :, None, None], weights, 0.0)
+    out = jnp.einsum("skgt,sktd->skgd", weights.astype(v_ctx.dtype), v_ctx)
+    return out.reshape(s, hq, d)
+
+
+def _sparse_chunk_xla(q, k_pages, v_pages, block_table, positions, valid,
+                      layer, pick):
+    """:func:`_attend_chunk_live` under a (row, block) mask: ``pick`` (S,
+    Hkv, C, blocks) bool — a query row sees the keys at or before it in
+    the blocks its K/V head picked. The walk skips no tile; each tile's
+    scores are float32 ``(S, Hkv, G, C, tile)``."""
+    s, c, hq, d = q.shape
+    h_kv = k_pages.shape[3] // d
+    g = hq // h_kv
+    bl = k_pages.shape[2]
+    mb = block_table.shape[1]
+    per = max(n for n in range(1, mb + 1)
+              if mb % n == 0 and n * bl <= max(_CHUNK_TILE_ROWS, bl))
+    tile = per * bl
+    scale = 1.0 / math.sqrt(d)
+    q5 = q.reshape(s, c, h_kv, g, d)
+    q_pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    live = jnp.max(positions + jnp.maximum(valid, 1))
+    f32 = jnp.float32
+
+    def body(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(block_table, j * per, per, axis=1)
+        k_ctx = k_pages[layer, ids].reshape(s, tile, h_kv, d)
+        v_ctx = v_pages[layer, ids].reshape(s, tile, h_kv, d)
+        logits = jnp.einsum(
+            "sckgd,stkd->skgct", q5, k_ctx, preferred_element_type=f32
+        ) * scale
+        key_pos = j * tile + jnp.arange(tile, dtype=jnp.int32)
+        causal = key_pos[None, None, :] <= q_pos[:, :, None]          # (S, C, T)
+        chosen = jnp.repeat(
+            jax.lax.dynamic_slice_in_dim(pick, j * per, per, axis=3), bl, axis=3)
+        mask = causal[:, None] & chosen                               # (S, Hkv, C, T)
+        logits = jnp.where(mask[:, :, None], logits, -jnp.inf)
+        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
+        safe = jnp.where(jnp.isfinite(m2), m2, 0.0)
+        w = jnp.exp(logits - safe[..., None])
+        fade = jnp.where(jnp.isfinite(m), jnp.exp(m - safe), 0.0)
+        l = l * fade + jnp.sum(w, axis=-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "skgct,stkd->skgcd", w.astype(v_ctx.dtype), v_ctx,
+            preferred_element_type=f32)
+        return m2, l, acc
+
+    shape = (s, h_kv, g, c)
+    m, l, acc = jax.lax.fori_loop(
+        0, -(-live // tile), body,
+        (jnp.full(shape, -jnp.inf, f32), jnp.zeros(shape, f32),
+         jnp.zeros(shape + (d,), f32)))
+    out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    return jnp.moveaxis(out, 3, 1).reshape(s, c, hq * d)
+
+
+def sparse_attention(q, k_new, v_new, k_pages, v_pages, kc, block_table,
+                     positions, valid, *, slots=None, layer=0,
+                     cfg: SparseAttentionConfig,
+                     interpret: Optional[bool] = None):
+    """One chunk of block-sparse GQA attention against the paged pool.
+
+    ``q`` ``(S, C, Hq, D)``; ``k_new``/``v_new`` ``(S, C, Hkv, D)``; pool,
+    table, positions, valid and ``layer`` as in :func:`paged_attention`;
+    ``kc`` ``(sparse layers, max_slots, units, Hkv * D)`` the compressed
+    keys, read and written at ``(layer, slots[s])`` (``slots`` None: row
+    ``s`` is slot ``s``, the decode wave). The chunk's K/V rows are written
+    first, then the compressed keys that complete in it
+    (:func:`write_compressed_keys`), then every query row picks its pages
+    (:func:`select_pages`) and attends the keys at or before it on them.
+
+    * **Decode** (C = 1): each (slot, K/V head)'s page list — its ``topk``
+      picks in order of position, or every page below ``dense_len`` — is
+      walked by the fused kernel ``sparse_decode`` where it runs (a TPU, or
+      ``interpret=True``), else gathered and attended in XLA.
+    * **Chunk**: the picks as a (row, page) mask over a walk of the live
+      context: the Pallas kernel ``sparse_prefill``
+      (``ops/sparse_prefill.py``) where it runs and takes the shapes, which
+      copies no page that no row picked; else :func:`_sparse_chunk_xla`.
+
+    Returns ``(out (S, C, Hq*D), k_pages', v_pages', kc')``."""
+    s, c, hq, d = q.shape
+    h_kv = k_new.shape[2]
+    bl = k_pages.shape[2]
+    if bl != cfg.block_size:
+        raise ValueError(
+            f"sparse_attention: a page ({bl} rows) must be a selection block "
+            f"({cfg.block_size})")
+    slot_ids = jnp.arange(s, dtype=jnp.int32) if slots is None else slots
+    k_pages, v_pages = write_kv_pages(
+        k_pages, v_pages, block_table, positions, valid, k_new, v_new,
+        layer=layer)
+    kc = write_compressed_keys(kc, k_pages, block_table, positions, valid,
+                               rows=c, layer=layer, slots=slot_ids, cfg=cfg)
+    if slots is None:
+        kc_rows = kc[layer]
+    else:
+        # One slot's rows, sliced and copied on their own: a gather of them
+        # had the compiler relayout the whole cache first.
+        kc_rows = jax.lax.optimization_barrier(kc[layer, slot_ids])
+    pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    top, sparse = select_pages(q, kc_rows, pos, h_kv=h_kv, cfg=cfg)
+    if c == 1:
+        nl = cfg.list_len
+        picked = jnp.pad(jnp.sort(top[:, 0], axis=-1),
+                         ((0, 0), (0, 0), (0, nl - cfg.topk)))
+        every = jnp.broadcast_to(jnp.arange(nl, dtype=jnp.int32), picked.shape)
+        ids = jnp.where(sparse[:, 0, None, None], picked, every)
+        live = jnp.minimum(positions // bl + 1, nl)
+        count = jnp.where(sparse[:, 0], cfg.topk, live)
+        count = jnp.where(valid > 0, count, 0)[:, None] * jnp.ones((1, h_kv), jnp.int32)
+        itemsize = jnp.dtype(k_pages.dtype).itemsize
+        on_cpu = _on_cpu()
+        if paged_decode_supported(bl, d, itemsize, lanes=h_kv * d) and d % 128 == 0 \
+                and (not on_cpu or interpret):
+            out = _sparse_decode_pallas(
+                q[:, 0], k_pages, v_pages, block_table, ids, count, positions,
+                layer, interpret=on_cpu or bool(interpret))
+        else:
+            out = _sparse_decode_xla(q[:, 0], k_pages, v_pages, block_table,
+                                     ids, count, positions, layer)
+        return out.reshape(s, 1, hq * d), k_pages, v_pages, kc
+    nb = block_table.shape[1]
+    start = jnp.arange(nb, dtype=jnp.int32) * bl
+    rows = s * c * h_kv
+    chosen = jnp.zeros((rows, nb), bool).at[
+        jnp.arange(rows)[:, None], top.reshape(rows, -1)].set(True, mode="drop")
+    chosen = chosen.reshape(s, c, h_kv, nb)
+    every = (start[None, None, :] <= pos[..., None])[:, :, None, :]
+    pick = jnp.moveaxis(jnp.where(sparse[..., None, None], chosen, every), 2, 1)
+    from rocket_tpu.ops.sparse_prefill import sparse_prefill, sparse_prefill_supported
+
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
+    on_cpu = _on_cpu()
+    if sparse_prefill_supported(c, hq, h_kv, d, bl, nb * bl, itemsize) and (
+            not on_cpu or interpret):
+        out = sparse_prefill(q, k_pages, v_pages, block_table, positions, valid,
+                             pick, layer, interpret=on_cpu or bool(interpret))
+    else:
+        out = _sparse_chunk_xla(q, k_pages, v_pages, block_table, positions,
+                                valid, layer, pick)
+    return out, k_pages, v_pages, kc

@@ -97,6 +97,7 @@ class ServeConfig:
             slot_state=tuple(mc.slot_state_shapes),
             max_slots=self.max_slots,
             window=mc.window,
+            sparse=mc.sparse_attention if mc.sparse_layers else None,
             num_blocks=num_blocks,
             block_len=self.block_len,
             # What a layer caches per token is the model's to declare: K

@@ -32,6 +32,15 @@ The pool reads the shapes and nothing else of the mixer. Nothing on the host res
 state from zeros wherever its chunk starts at position 0, which is where a
 new request, a reused slot and an evicted request's re-prefill all start.
 
+**Compressed keys by slot for the sparse layers.** A layer that attends
+the blocks it picks (``TransformerConfig.sparse_attention``) caches its
+K/V rows in the pages like any other, and behind the mixers' state one
+more per-slot array holds its compressed keys, ``(sparse layers,
+max_slots, max_seq_len / kernel_stride, Hkv*D)``: a row every
+``kernel_stride`` positions, written when the ``kernel_size`` keys it
+pools are all in the pages. Nothing resets it either: a row is read only
+once a position at or past its last key has been written again.
+
 **A ring by slot for the window layers.** A layer that attends only the
 last ``window`` positions (``TransformerConfig.attention_kinds``) caches no
 pages either: its K and V rows live in two more per-slot arrays,
@@ -87,6 +96,11 @@ class KVPoolSpec:
     #: per-slot arrays; the scheduler reads this to say how many rows a
     #: wave's window layers attend.
     window: int = 0
+    #: The sparse layers' selection (``TransformerConfig.
+    #: sparse_attention``; None: the model has no sparse layer). Their
+    #: compressed keys are the last per-slot array; the scheduler reads
+    #: this to say how many pages a wave's sparse layers read.
+    sparse: Optional[object] = None
 
     def __post_init__(self):
         if self.num_blocks < 2:

@@ -144,6 +144,8 @@ class Scheduler:
         self.block_len = engine.spec.block_len
         #: Rows a window layer's ring holds a slot (0: no window layer).
         self.window = engine.spec.window
+        #: The sparse layers' selection (None: no sparse layer).
+        self.sparse = engine.spec.sparse
         self.max_context = mb * self.block_len
         # Host mirrors of the wave inputs — fixed shape + dtype forever.
         self.block_table = np.zeros((s, mb), np.int32)
@@ -276,6 +278,8 @@ class Scheduler:
                     if self.window:
                         sp.set(window_rows=int(np.minimum(
                             self.lengths[run] + 1, self.window).sum()))
+                    if self.sparse is not None:
+                        sp.set(**self._sparse_pages(self.lengths[run]))
             # The dispatched ``fresh`` is never written again (the device
             # may still read it): the mirror moves on to a new array.
             fresh, self.fresh = self.fresh, self.fresh & ~run
@@ -382,6 +386,8 @@ class Scheduler:
             chunk = np.pad(chunk, (0, c - valid))
         with timed("serve/prefill_enqueue", rid=st.req.id, start=start,
                    valid=valid) as sp:
+            if sp.on and self.sparse is not None:
+                sp.set(**self._sparse_pages(np.arange(start, start + valid)))
             self.engine.prefill(
                 self.block_table[slot:slot + 1],
                 chunk[None, :].astype(np.int32),
@@ -396,6 +402,18 @@ class Scheduler:
             # completion): the span's end, read at the one place whether
             # or not spans are on.
             self.tracer.on_prefill(st.req.id, sp.end, start, valid)
+
+    def _sparse_pages(self, positions) -> dict:
+        """What the sparse layers' queries at ``positions`` read: per K/V
+        head and query, ``sparse_pages`` the pages attended (``topk`` of
+        the live ones past ``dense_len``, all below) and ``sparse_live``
+        the pages held, each summed over queries and K/V heads."""
+        cfg, heads = self.sparse, self.engine.spec.num_kv_heads
+        live = np.asarray(positions, np.int64) // cfg.block_size + 1
+        read = np.where(np.asarray(positions) >= cfg.dense_len,
+                        np.minimum(live, cfg.topk), live)
+        return {"sparse_pages": int(read.sum()) * heads,
+                "sparse_live": int(live.sum()) * heads}
 
     def _last_row(self, slot: int) -> int:
         """The highest row the NEXT dispatch may write for ``slot``: its

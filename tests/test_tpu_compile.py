@@ -937,3 +937,105 @@ def test_short_table_prefill_programs_hold_no_kv_prefill(sds, monkeypatch, cell)
     )
     stablehlo = jax.jit(build_prefill_step(model)).lower(*prefill_args).as_text()
     assert "kv_prefill" not in stablehlo and "tpu_custom_call" not in stablehlo
+
+
+# -- lightning state and block-sparse attention: four kernels ----------------
+
+
+def test_sala_serve_programs_hold_the_four_kernels_and_update_in_place(sds, monkeypatch):
+    """The engine's two programs for a block-sparse layer and a lightning
+    layer at the MiniCPM-SALA cell's widths (d 4096; 32 query heads over 2
+    K/V heads of 128, no rotary, an elementwise gate; 32 lightning heads of
+    128; 32 slots x 65536 positions in blocks of 64, chunks of 1024; the
+    sparse layer first, so that its attention feeds the lightning state,
+    and a small vocabulary, so that it compiles in seconds): the decode
+    program holds ``sparse_decode`` and ``lightning_step``, the prefill
+    program ``sparse_prefill`` and ``lightning_chunk``; the four donated
+    arrays — K and V pages by block, the rank-5 lightning state and the
+    compressed keys by slot — are aliased input to output, and nothing but
+    a program's own update of them (the kernel that owns ``S``, a row
+    scatter) produces an array of their types: no copy."""
+    import re
+
+    import rocket_tpu.nn.lightning as lightning
+    import rocket_tpu.ops.paged_attention as paged
+    from rocket_tpu.models.transformer import AttentionKind, TransformerConfig, TransformerLM
+    from rocket_tpu.nn.lightning import LightningConfig
+    from rocket_tpu.serve import ServeConfig
+    from rocket_tpu.serve.engine import (
+        DECODE_DONATE,
+        PREFILL_DONATE,
+        abstract_wave_inputs,
+        build_decode_wave,
+        build_prefill_step,
+    )
+
+    monkeypatch.setattr(paged, "_on_cpu", lambda: False)
+    monkeypatch.setattr(lightning, "_on_cpu", lambda: False)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=1024, max_seq_len=65536, dim=4096, num_layers=2, num_heads=32,
+        num_kv_heads=2, head_dim=128, dropout=0.0, tied_embeddings=False,
+        activation_dtype="bfloat16", pos_embedding="rope", norm="rmsnorm",
+        norm_eps=1e-6, mlp="swiglu", mlp_hidden=16384, mlp_bias=False,
+        attn_bias=False, attn_gate=True, qk_norm=True,
+        layer_types=("minicpm4", "lightning-attn"), attention_kinds={
+            "minicpm4": AttentionKind(rope=False, sparse=True),
+            "lightning-attn": AttentionKind(state=True)},
+        lightning=LightningConfig(32, 128, published_layers=32, first_layer=16),
+        sparse_attention=paged.SparseAttentionConfig(),
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5, logit_divisor=16.0,
+    ))
+    sc = ServeConfig(max_slots=32, block_len=64, prefill_chunk=1024,
+                     max_model_len=65536)
+    spec, mb, _, waves = sc.resolve(model.config)
+    assert spec.pages_shapes == ((1, 32769, 64, 256),) * 2
+    assert spec.state_shapes == (((1, 32, 32, 128, 128), "float32"),
+                                 ((1, 32, 4096, 256), "bfloat16"))
+    donated = ["bf16[1,32769,64,256]", "f32[1,32,32,128,128]", "bf16[1,32,4096,256]"]
+    decode_args, prefill_args = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        abstract_wave_inputs(
+            model, spec, max_slots=sc.max_slots, max_blocks_per_seq=mb,
+            prefill_chunk=sc.prefill_chunk,
+        ),
+    )
+    programs = {
+        "decode": (build_decode_wave(model, waves=waves), decode_args,
+                   DECODE_DONATE, ("sparse_decode", "lightning_step")),
+        "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE,
+                    ("sparse_prefill", "lightning_chunk")),
+    }
+    for name, (fn, args, donate, wanted) in programs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        text = compiled.as_text()
+        kernels = _kernel_instructions(text)
+        for kernel in wanted:
+            assert sum(kernel in k for k in kernels) == 1, (name, kernel, kernels)
+        if name == "decode":
+            _assert_carry_stays_on_device(text, sc.max_slots)
+        materialised = _materialised(text)
+        moves = {inst for op, inst, _, line in materialised
+                 if op == "copy-start" and "S(1)" in line}
+        made = [
+            (op, inst) for op, inst, _, line in materialised
+            if any(t in line.split(" = ")[1].split("(")[0] for t in donated)
+            and op not in ("parameter", "tuple", "get-tuple-element", "bitcast",
+                           "while", "custom-call", "dynamic-update-slice",
+                           "scatter", "conditional")
+            and not (op == "copy-start" and inst in moves)
+            and not (op == "copy-done" and any(
+                f"copy-done(%{m})" in line or f"copy-done({m})" in line
+                for m in moves))
+            and not (op == "fusion" and re.search(
+                r"/scatter\"|dynamic_update_slice|dynamic-update-slice", line))
+        ]
+        assert not made, (name, made)
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= spec.pool_bytes, (
+            name, memory.alias_size_in_bytes, spec.pool_bytes)
+        # Temporaries: a chunk's activations and its selection's scores,
+        # never a copy of a page array (1 GB) or of a state array.
+        assert memory.temp_size_in_bytes < 2 * spec.state_bytes, (
+            name, memory.temp_size_in_bytes)
+        aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert aliases and aliases.group(1).count("alias") == 4, (name, text[:300])
