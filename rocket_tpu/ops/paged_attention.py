@@ -861,17 +861,18 @@ def write_compressed_keys(kc, k_pages, block_table, positions, valid, *,
     return kc.at[layer, slots[:, None], units].set(mean, mode="drop")
 
 
-def _block_scores(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
-    """``(S, C, Hkv, blocks)`` float32: each query row's score of every
-    block — per K/V head ``g`` the softmax over the complete units (``j *
+def _unit_scores(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
+    """``(S, C, Hkv, units)`` float32: each query row's score of every
+    unit — per K/V head ``g`` the softmax over the complete units (``j *
     stride + kernel - 1 <= pos``) of ``q_h . Kc_g / sqrt(D)``, summed over
-    the group's query heads, then the most of it over the units that
-    overlap the block; -1 where none does. ``q`` (S, C, Hq, D); ``kc_rows``
-    (S, units, Hkv * D); ``pos`` (S, C)."""
+    the group's query heads; -1 where the unit is incomplete. ``q`` (S, C,
+    Hq, D); ``kc_rows`` (S, units, Hkv * D); ``pos`` (S, C). A loop over
+    the group's heads in XLA: the CPU's path and the specification of
+    the kernel ``select_scores`` (``ops/select_scores.py``)."""
     s, c, hq, d = q.shape
     g = hq // h_kv
     u = kc_rows.shape[1]
-    stride, size, bsz = cfg.kernel_stride, cfg.kernel_size, cfg.block_size
+    stride, size = cfg.kernel_stride, cfg.kernel_size
     kcs = kc_rows.reshape(s, u, h_kv, d)
     q5 = q.reshape(s, c, h_kv, g, d)
     complete = (jnp.arange(u, dtype=jnp.int32) * stride + size - 1)[None, None, :] \
@@ -886,7 +887,38 @@ def _block_scores(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
         return acc + jax.nn.softmax(logits, axis=-1)
 
     c_sum = jax.lax.fori_loop(0, g, head, jnp.zeros((s, c, h_kv, u), jnp.float32))
-    c_sum = jnp.where(complete[:, :, None, :], c_sum, -1.0)
+    return jnp.where(complete[:, :, None, :], c_sum, -1.0)
+
+
+def _block_scores(q, kc, pos, *, h_kv: int, cfg: SparseAttentionConfig,
+                  layer, slots, valid, interpret: Optional[bool]):
+    """``(S, C, Hkv, blocks)`` float32: each query row's score of every
+    block — the most of its unit scores over the units that overlap the
+    block; -1 where none is complete. The unit scores come from the
+    kernel ``select_scores`` where it runs (a TPU, or ``interpret=True``)
+    and takes the shapes, else from :func:`_unit_scores`. ``kc`` (layers,
+    max_slots, units, Hkv * D) read at ``(layer, slots[s])``, all slots
+    in order where ``slots`` is None."""
+    from rocket_tpu.ops.select_scores import select_scores, select_scores_supported
+
+    s, c, hq, d = q.shape
+    u = kc.shape[2]
+    stride, size, bsz = cfg.kernel_stride, cfg.kernel_size, cfg.block_size
+    on_cpu = _on_cpu()
+    if select_scores_supported(c, hq, h_kv, d, u) and (not on_cpu or interpret):
+        slot_ids = jnp.arange(s, dtype=jnp.int32) if slots is None else slots
+        live = jnp.ones((s,), jnp.int32) if valid is None else valid
+        c_sum = select_scores(q, kc, layer, slot_ids, pos[:, 0], live,
+                              stride=stride, size=size,
+                              interpret=on_cpu or bool(interpret))
+    else:
+        if slots is None:
+            kc_rows = kc[layer]
+        else:
+            # One slot's rows, sliced and copied on their own: a gather of
+            # them had the compiler relayout the whole cache first.
+            kc_rows = jax.lax.optimization_barrier(kc[layer, slots])
+        c_sum = _unit_scores(q, kc_rows, pos, h_kv=h_kv, cfg=cfg)
     per = bsz // stride
     nb = u // per
     lo, hi = -((size - 1) // stride), (bsz - 1) // stride
@@ -898,19 +930,26 @@ def _block_scores(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
     return r
 
 
-def select_pages(q, kc_rows, pos, *, h_kv: int, cfg: SparseAttentionConfig):
+def select_pages(q, kc, pos, *, h_kv: int, cfg: SparseAttentionConfig,
+                 layer=0, slots=None, valid=None,
+                 interpret: Optional[bool] = None):
     """The blocks each query row attends: ``(top (S, C, Hkv, topk) block
     ids, sparse (S, C) bool)`` — ``top`` the ``topk`` best by
     :func:`_block_scores` among the blocks that start at or before the row
     (blocks ``< init_blocks`` and those holding a position in ``(pos -
     window_size, pos]`` first, ties to the lower id), ``sparse`` whether the
-    row is at or past ``dense_len`` (else it attends every block)."""
-    nb = kc_rows.shape[1] // (cfg.block_size // cfg.kernel_stride)
+    row is at or past ``dense_len`` (else it attends every block). ``kc``
+    (layers, max_slots, units, Hkv * D), row ``s`` reading slot
+    ``slots[s]`` of ``layer`` (all slots in order where ``slots`` is None);
+    a slot row whose ``valid`` is 0 does not run, and its ``top`` is
+    never read."""
+    nb = kc.shape[2] // (cfg.block_size // cfg.kernel_stride)
     start = jnp.arange(nb, dtype=jnp.int32) * cfg.block_size
     sparse = pos >= cfg.dense_len
 
     def scored():
-        r = _block_scores(q, kc_rows, pos, h_kv=h_kv, cfg=cfg)
+        r = _block_scores(q, kc, pos, h_kv=h_kv, cfg=cfg, layer=layer,
+                          slots=slots, valid=valid, interpret=interpret)
         p = pos[..., None, None]
         forced = (jnp.arange(nb) < cfg.init_blocks) | (
             start + cfg.block_size - 1 > p - cfg.window_size)
@@ -1208,14 +1247,9 @@ def sparse_attention(q, k_new, v_new, k_pages, v_pages, kc, block_table,
         layer=layer)
     kc = write_compressed_keys(kc, k_pages, block_table, positions, valid,
                                rows=c, layer=layer, slots=slot_ids, cfg=cfg)
-    if slots is None:
-        kc_rows = kc[layer]
-    else:
-        # One slot's rows, sliced and copied on their own: a gather of them
-        # had the compiler relayout the whole cache first.
-        kc_rows = jax.lax.optimization_barrier(kc[layer, slot_ids])
     pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    top, sparse = select_pages(q, kc_rows, pos, h_kv=h_kv, cfg=cfg)
+    top, sparse = select_pages(q, kc, pos, h_kv=h_kv, cfg=cfg, layer=layer,
+                               slots=slots, valid=valid, interpret=interpret)
     if c == 1:
         nl = cfg.list_len
         picked = jnp.pad(jnp.sort(top[:, 0], axis=-1),

@@ -29,6 +29,7 @@ from rocket_tpu.nn.lightning import (
     lightning_step,
 )
 from rocket_tpu.ops import paged_attention as pa
+from rocket_tpu.ops.select_scores import select_scores, select_scores_supported
 from rocket_tpu.ops.sparse_prefill import sparse_prefill, sparse_prefill_supported
 from rocket_tpu.serve import ServeConfig, ServeEngine
 
@@ -230,42 +231,107 @@ def test_the_selection_is_the_references_page_sets():
     """Queries at positions under ``dense_len``, at it and past it, with
     the compressed keys of a random sequence: each row's ``topk`` blocks
     (the first, the window's, the best scored) are exactly the reference's,
-    and a row under ``dense_len`` is marked dense."""
-    t, h_kv, g, d = 320, 2, 2, 32
-    rng = np.random.default_rng(2)
-    keys = rng.standard_normal((t, h_kv * d)).astype(np.float32)
-    pages, table = _pool_with_keys(keys)
-    units = t // SC.kernel_stride
-    kc = jnp.zeros((1, 1, units, h_kv * d), jnp.float32)
-    kc = pa.write_compressed_keys(kc, pages, table, jnp.asarray([0], jnp.int32),
-                                  jnp.asarray([t], jnp.int32), rows=t, layer=0,
-                                  slots=jnp.asarray([0], jnp.int32), cfg=SC)
+    and a row under ``dense_len`` is marked dense — through the XLA loop
+    over heads (heads of 32) and through the kernel ``select_scores``,
+    interpreted (heads of 128 against a cache of 128 units, the last 49
+    never written), every row reading slot 0 of the cache."""
+    t, h_kv, g = 320, 2, 2
     positions = np.array([5, 100, 127, 128, 129, 200, 255, 319], np.int32)
-    q = rng.standard_normal((len(positions), h_kv * g, d)).astype(np.float32) * 2
-    with jax.default_matmul_precision("highest"):
-        top, sparse = pa.select_pages(
-            jnp.asarray(q)[:, None], jnp.broadcast_to(kc[0, 0], (len(positions), units, h_kv * d)),
-            jnp.asarray(positions)[:, None], h_kv=h_kv, cfg=SC)
-    cfg = dict(TINY, num_key_value_heads=h_kv, num_attention_heads=h_kv * g, head_dim=d)
-    pick, margin = ref.selection(jnp.asarray(q), ref.compressed_keys(
-        jnp.asarray(keys).reshape(t, h_kv, d), cfg), jnp.asarray(positions), cfg)
-    pick = np.asarray(pick)
-    np.testing.assert_array_equal(np.asarray(sparse)[:, 0], positions >= DENSE)
-    for i, p in enumerate(positions):
-        for head in range(h_kv):
-            want = set(np.flatnonzero(pick[i, head]))
-            if p < DENSE:
-                assert want == set(range(p // SC.block_size + 1))
-                continue
-            got = set(np.asarray(top)[i, 0, head].tolist())
-            assert got == want, (p, head, got, want)
-            assert 0 in got and p // SC.block_size in got
-            assert max(got) * SC.block_size <= p
-    # No margin where a row attends densely; past it, the 4th pick is forced
-    # (block 0 and the window's 3) unless the window holds fewer blocks.
-    margin = np.asarray(margin)
-    assert np.isinf(margin[positions < DENSE]).all()
-    assert np.isfinite(margin[positions == 319]).all()
+    for d, units, interpret in ((32, t // SC.kernel_stride, None), (128, 128, True)):
+        assert select_scores_supported(1, h_kv * g, h_kv, d, units) == bool(interpret)
+        rng = np.random.default_rng(2)
+        keys = rng.standard_normal((t, h_kv * d)).astype(np.float32)
+        pages, table = _pool_with_keys(keys)
+        kc = jnp.zeros((1, 1, units, h_kv * d), jnp.float32)
+        kc = pa.write_compressed_keys(kc, pages, table, jnp.asarray([0], jnp.int32),
+                                      jnp.asarray([t], jnp.int32), rows=t, layer=0,
+                                      slots=jnp.asarray([0], jnp.int32), cfg=SC)
+        q = rng.standard_normal((len(positions), h_kv * g, d)).astype(np.float32) * 2
+        with jax.default_matmul_precision("highest"):
+            top, sparse = pa.select_pages(
+                jnp.asarray(q)[:, None], kc, jnp.asarray(positions)[:, None], h_kv=h_kv,
+                cfg=SC, slots=jnp.zeros(len(positions), jnp.int32), interpret=interpret)
+        cfg = dict(TINY, num_key_value_heads=h_kv, num_attention_heads=h_kv * g, head_dim=d)
+        pick, margin = ref.selection(jnp.asarray(q), ref.compressed_keys(
+            jnp.asarray(keys).reshape(t, h_kv, d), cfg), jnp.asarray(positions), cfg)
+        pick = np.asarray(pick)
+        np.testing.assert_array_equal(np.asarray(sparse)[:, 0], positions >= DENSE)
+        for i, p in enumerate(positions):
+            for head in range(h_kv):
+                want = set(np.flatnonzero(pick[i, head]))
+                if p < DENSE:
+                    assert want == set(range(p // SC.block_size + 1))
+                    continue
+                got = set(np.asarray(top)[i, 0, head].tolist())
+                assert got == want, (d, p, head, got, want)
+                assert 0 in got and p // SC.block_size in got
+                assert max(got) * SC.block_size <= p
+        # No margin where a row attends densely; past it, the 4th pick is
+        # forced (block 0 and the window's 3) unless the window holds fewer
+        # blocks.
+        margin = np.asarray(margin)
+        assert np.isinf(margin[positions < DENSE]).all()
+        assert np.isfinite(margin[positions == 319]).all()
+
+
+_SCORE_CASES = {
+    # slots past dense_len, under it, one that does not run, one at 3 (no
+    # complete unit)
+    "wave": dict(c=1, positions=[300, 90, 200, 3], valid=[1, 1, 0, 1],
+                 slots=None, tile=256, rows=None),
+    # a chunk of 32 rows from 112 in blocks of 8: rows under dense_len and
+    # past it; the second slot row's last 12 rows are padding
+    "chunk_across_dense_len": dict(c=32, positions=[112, 400], valid=[32, 20],
+                                   slots=[2, 0], tile=128, rows=8),
+    # the first chunk of a prompt: its first 7 rows complete no unit
+    "no_complete_unit": dict(c=16, positions=[0], valid=[16], slots=[1], tile=128,
+                             rows=None),
+    # 200 live units: a whole tile of 128 and 72 of the next
+    "ragged_live": dict(c=1, positions=[806, 806], valid=[1, 1], slots=[3, 1],
+                        tile=128, rows=None),
+    # a block of 8 rows whose first row completes 126 units and whose last
+    # 128: the first tile is whole for some of its rows only
+    "tile_edge_in_a_block": dict(c=16, positions=[508], valid=[16], slots=[2],
+                                 tile=128, rows=8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCORE_CASES))
+def test_the_select_scores_kernel_is_the_loop_over_heads(case):
+    """The unit scores ``c_sum`` of the kernel ``select_scores``
+    (interpreted: 4 heads over 2 K/V heads of 128, 256 units of a
+    4-slot, 2-layer cache read at layer 1) against ``_unit_scores``' loop
+    over heads, float32; a slot row that does not run reads -1."""
+    spec = _SCORE_CASES[case]
+    c, h_kv, g, d, units = spec["c"], 2, 2, 128, 256
+    positions = jnp.asarray(spec["positions"], jnp.int32)
+    valid = jnp.asarray(spec["valid"], jnp.int32)
+    s = len(spec["positions"])
+    slots = jnp.arange(s, dtype=jnp.int32) if spec["slots"] is None \
+        else jnp.asarray(spec["slots"], jnp.int32)
+    assert select_scores_supported(c, h_kv * g, h_kv, d, units)
+    rng = np.random.default_rng(7)
+    kc = jnp.asarray(rng.standard_normal((2, 4, units, h_kv * d)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((s, c, h_kv * g, d)), jnp.float32)
+    pos = positions[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    got = np.asarray(select_scores(
+        q, kc, 1, slots, positions, valid, stride=SC.kernel_stride,
+        size=SC.kernel_size, tile=spec["tile"], rows=spec["rows"], interpret=True))
+    want = np.asarray(pa._unit_scores(q, kc[1, slots], pos, h_kv=h_kv, cfg=SC))
+    assert got.shape == want.shape == (s, c, h_kv, units)
+    runs = np.asarray(valid) > 0
+    np.testing.assert_allclose(got[runs], want[runs], rtol=1e-5, atol=1e-5)
+    assert (got[~runs] == -1).all()
+    assert np.isfinite(got).all()
+    complete = (np.arange(units) * SC.kernel_stride + SC.kernel_size - 1)[None, None] \
+        <= np.asarray(pos)[..., None]                                  # (S, C, U)
+    complete = np.broadcast_to(complete[:, :, None], got.shape)
+    assert (got[runs][~complete[runs]] == -1).all()
+    # Each row's complete units hold a softmax a head, summed over g heads.
+    sums = np.where(complete, got, 0).sum(-1)[runs]
+    has = complete.any(-1)[runs]
+    assert has.any() and not has.all() if case == "no_complete_unit" else has.any()
+    np.testing.assert_allclose(sums[has], g, rtol=1e-5)
 
 
 def test_sparse_decode_is_dense_attention_over_the_listed_pages():

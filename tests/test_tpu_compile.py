@@ -939,7 +939,43 @@ def test_short_table_prefill_programs_hold_no_kv_prefill(sds, monkeypatch, cell)
     assert "kv_prefill" not in stablehlo and "tpu_custom_call" not in stablehlo
 
 
-# -- lightning state and block-sparse attention: four kernels ----------------
+# -- lightning state and block-sparse attention: five kernels ----------------
+
+
+def _conditionals_reaching(text, kernel):
+    """For each ``conditional`` of a compiled program whose branches reach
+    a custom call named ``kernel``: the bodies of every computation its
+    branches reach (calls, fusions and loops followed)."""
+    import re
+
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+    bodies = {k: "\n".join(v) for k, v in bodies.items()}
+
+    def reached(roots):
+        seen, todo = set(), list(roots)
+        while todo:
+            n = todo.pop()
+            if n in bodies and n not in seen:
+                seen.add(n)
+                todo += re.findall(r"%([\w.\-]+)", bodies[n])
+        return [bodies[n] for n in seen]
+
+    out = []
+    for body in bodies.values():
+        for branches in re.findall(
+                r" conditional\([^\n]*branch_computations=\{([^}]*)\}", body):
+            inside = reached(re.findall(r"%?([\w.\-]+)", branches))
+            if any(re.search(rf"%{kernel}[\w.]* = [^\n]*custom-call\(", b)
+                   for b in inside):
+                out.append(inside)
+    return out
 
 
 def test_sala_serve_programs_hold_the_four_kernels_and_update_in_place(sds, monkeypatch):
@@ -950,8 +986,10 @@ def test_sala_serve_programs_hold_the_four_kernels_and_update_in_place(sds, monk
     sparse layer first, so that its attention feeds the lightning state,
     and a small vocabulary, so that it compiles in seconds): the decode
     program holds ``sparse_decode`` and ``lightning_step``, the prefill
-    program ``sparse_prefill`` and ``lightning_chunk``; the four donated
-    arrays — K and V pages by block, the rank-5 lightning state and the
+    program ``sparse_prefill`` and ``lightning_chunk``, and each one
+    ``select_scores`` inside the page choice's conditional, which holds no
+    ``while`` (the XLA loop over a group's heads is the CPU's); the four
+    donated arrays — K and V pages by block, the rank-5 lightning state and the
     compressed keys by slot — are aliased input to output, and nothing but
     a program's own update of them (the kernel that owns ``S``, a row
     scatter) produces an array of their types: no copy."""
@@ -1001,9 +1039,9 @@ def test_sala_serve_programs_hold_the_four_kernels_and_update_in_place(sds, monk
     )
     programs = {
         "decode": (build_decode_wave(model, waves=waves), decode_args,
-                   DECODE_DONATE, ("sparse_decode", "lightning_step")),
+                   DECODE_DONATE, ("sparse_decode", "lightning_step", "select_scores")),
         "prefill": (build_prefill_step(model), prefill_args, PREFILL_DONATE,
-                    ("sparse_prefill", "lightning_chunk")),
+                    ("sparse_prefill", "lightning_chunk", "select_scores")),
     }
     for name, (fn, args, donate, wanted) in programs.items():
         compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
@@ -1011,6 +1049,9 @@ def test_sala_serve_programs_hold_the_four_kernels_and_update_in_place(sds, monk
         kernels = _kernel_instructions(text)
         for kernel in wanted:
             assert sum(kernel in k for k in kernels) == 1, (name, kernel, kernels)
+        choice = _conditionals_reaching(text, "select_scores")
+        assert len(choice) == 1, (name, choice)
+        assert not any(re.search(r" while\(", body) for body in choice[0]), name
         if name == "decode":
             _assert_carry_stays_on_device(text, sc.max_slots)
         materialised = _materialised(text)
